@@ -15,8 +15,8 @@ head — the exact fn `generate`'s scan body runs) for f32 and int8kv
 variants and reads XLA's cost model (`compiled.cost_analysis()`s
 "bytes accessed"), alongside the analytic traffic model
 (weights + kv_cache_nbytes). Run on any backend; the TPU numbers are
-the ones that matter and get appended to the pre-registered table in
-BASELINE.md when a healthy window runs this.
+the ones that matter (none recorded yet — PERF.md is where a chip run
+of this goes).
 
 CAVEAT on the cost-model column: XLA charges every
 dynamic_update_slice as a full-array write at cost-analysis time —

@@ -22,19 +22,18 @@ from __future__ import annotations
 import jax as _jax
 
 from .config import get_config as _get_config, configure  # noqa: F401
+from .config import use_compile_cache as _use_compile_cache
 
 if _get_config().enable_x64:
     # The reference's core column types are Double/Long
     # (datatypes.scala:265-267); x64 makes those exact end-to-end.
     _jax.config.update("jax_enable_x64", True)
 
-if _get_config().compilation_cache_dir:
-    # persistent executable cache: a fresh process deserializes compiled
-    # XLA programs instead of paying the 20-40s TPU compile again
-    _jax.config.update(
-        "jax_compilation_cache_dir", _get_config().compilation_cache_dir
-    )
-    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+# persistent executable cache: a fresh process deserializes compiled XLA
+# programs instead of paying the 20-40s TPU compile again. One resolver
+# (config.resolve_compile_cache_dir) places it; off unless the
+# environment names a directory.
+_use_compile_cache()
 
 from . import dtypes  # noqa: E402,F401
 from .shape import Shape, Unknown  # noqa: E402,F401

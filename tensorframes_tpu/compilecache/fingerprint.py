@@ -29,7 +29,7 @@ artifact:
   cleanly), ``XLA_FLAGS``, jax version, entry kind (block/vmap/fn),
   donation and hoist flags, the straggler-kernel selection state
   (:func:`tensorframes_tpu.kernels.fingerprint_token` — pallas
-  enabled/kill-switched, force hook, interpreter mode), and the store
+  enabled/switched off, force hook, interpreter mode), and the store
   format version.
 
 ``TFG108`` (analysis/rules.py) calls :func:`program_fingerprint` twice
@@ -60,7 +60,11 @@ import numpy as np
 #: v4: the verified-lift state joined the env component (ISSUE 18 —
 #: a ``TFTPU_LIFT`` flip or a synthesis-rule bump swaps a lifted
 #: program for a callback one; the two must never share a key).
-FORMAT_VERSION = 4
+#: v5: entries record the executable's device ids and load onto exactly
+#: those (PR 21 — jax 0.9's ``deserialize_and_load`` otherwise loads
+#: onto EVERY local device, and a one-device executable then refuses
+#: its one-shard arguments on a multi-device host).
+FORMAT_VERSION = 5
 
 __all__ = [
     "FORMAT_VERSION",
@@ -185,7 +189,7 @@ def _env_parts(kind: str, donate: bool, hoisted: bool) -> Dict[str, object]:
     return {
         "format": FORMAT_VERSION,
         # kernel-selection state: pallas on/off (config switch AND the
-        # runtime Mosaic kill-switch), the force hook, and interpreter
+        # manual disable_pallas() switch), the force hook, and interpreter
         # mode — any flip invalidates every key, because the lowering
         # the cost model picks is baked into the traced program
         "kernels": _kernels.fingerprint_token(),

@@ -116,12 +116,13 @@ _STORES: Dict[Tuple[str, int], Optional["CompileCacheStore"]] = {}
 def _encode_skeleton(obj) -> object:
     """Pytree container skeleton → JSON-able form. Leaves become the
     marker 0; dict (str keys) / list / tuple / namedtuple / None
-    containers are supported — anything else raises and the entry is
-    not stored. Namedtuples (optax optimizer states — the generic
-    ``aot_jit`` entry serializes whole train steps) record their
-    importable class path and are reconstructed at load; a class that
-    no longer imports degrades to a fresh compile like any other
-    defect."""
+    containers and the package's ``QuantizedTensor`` node are supported
+    — any other pytree node reads as a leaf, fails the round-trip check
+    in :meth:`CompileCacheStore.put`, and the entry is not stored.
+    Namedtuples (optax optimizer states — the generic ``aot_jit`` entry
+    serializes whole train steps) record their importable class path
+    and are reconstructed at load; a class that no longer imports
+    degrades to a fresh compile like any other defect."""
     if isinstance(obj, dict):
         if not all(isinstance(k, str) for k in obj):
             raise TypeError("non-string dict keys in pytree")
@@ -141,6 +142,13 @@ def _encode_skeleton(obj) -> object:
         return {"t": "l", "v": [_encode_skeleton(x) for x in obj]}
     if obj is None:
         return {"t": "n"}
+    from ..ops.quantize import QuantizedTensor
+
+    if isinstance(obj, QuantizedTensor):
+        # the package's own pytree node: int8-quantized weight trees
+        # are what the serving decode engine's executables take
+        return {"t": "qt", "v": [_encode_skeleton(obj.q),
+                                 _encode_skeleton(obj.scale)]}
     return 0  # leaf
 
 
@@ -172,6 +180,10 @@ def _decode_skeleton(enc) -> object:
         return [_decode_skeleton(v) for v in enc["v"]]
     if t == "n":
         return None
+    if t == "qt":
+        from ..ops.quantize import QuantizedTensor
+
+        return QuantizedTensor(*(_decode_skeleton(v) for v in enc["v"]))
     raise ValueError(f"unknown skeleton tag {t!r}")
 
 
@@ -307,10 +319,17 @@ class CompileCacheStore:
                 deserialize_and_load,
             )
 
+            import jax
+
+            # load onto the devices the executable was compiled for:
+            # the default is every local device, and a one-device
+            # executable then demands one argument shard per device
+            local = {int(d.id): d for d in jax.local_devices()}
             loaded = deserialize_and_load(
                 payload,
                 _skeleton_to_treedef(header["in_skel"]),
                 _skeleton_to_treedef(header["out_skel"]),
+                execution_devices=[local[i] for i in header["device_ids"]],
             )
         except Exception as e:
             # structurally sound but not loadable here (runtime drift,
@@ -376,6 +395,10 @@ class CompileCacheStore:
                 "payload_crc32": zlib.crc32(payload),
                 "in_skel": in_skel,
                 "out_skel": out_skel,
+                "device_ids": [
+                    int(d.id) for d in
+                    compiled.runtime_executable().local_devices()
+                ],
             })
             hbytes = json.dumps(header, sort_keys=True).encode("utf-8")
             blob = (_MAGIC + struct.pack("<I", FORMAT_VERSION)

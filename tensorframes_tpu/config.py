@@ -32,6 +32,46 @@ def _env_float(name: str, default: float) -> float:
     return default if v in (None, "") else float(v)
 
 
+#: The fixed, git-ignored compile-cache directory of a checkout — what
+#: the entry points that run on the chip (``chip_smoke.py``,
+#: ``bench.py``, ``serving.replica_main``) use when the environment
+#: names none. The path is part of jax's cache key, so it must not move
+#: between runs: never a temporary directory.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".tftpu_cache",
+)
+
+
+def resolve_compile_cache_dir(entry_point: bool = False) -> str:
+    """THE compile-cache resolver: ``JAX_COMPILATION_CACHE_DIR`` if
+    set (jax reads it itself — the package then only hangs its own
+    layers under it and never calls ``jax.config.update`` for the
+    cache); else ``TFTPU_COMPILE_CACHE``; else, for an entry point,
+    :data:`CHECKOUT_CACHE_DIR`; else ``""`` (plain library import stays
+    cache-off, so tier-1's compile-count tests keep their meaning)."""
+    for var in ("JAX_COMPILATION_CACHE_DIR", "TFTPU_COMPILE_CACHE"):
+        if os.environ.get(var):
+            return os.environ[var]
+    return CHECKOUT_CACHE_DIR if entry_point else ""
+
+
+def use_compile_cache(entry_point: bool = False) -> str:
+    """Point the package's cache layers (``<dir>/aot``, ``planstats``,
+    ``results``) at the resolved directory and, unless the environment
+    already placed jax's own cache (``JAX_COMPILATION_CACHE_DIR``),
+    point jax's persistent cache there too. Called at package import
+    and by the chip entry points (``entry_point=True``) before they
+    compile anything. Returns the directory (``""`` = cache off)."""
+    path = resolve_compile_cache_dir(entry_point)
+    _config.compilation_cache_dir = path
+    if path and not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 @dataclasses.dataclass
 class Config:
     # Enable float64/int64 end-to-end (the reference's Double/Long columns).
@@ -75,11 +115,15 @@ class Config:
     # the big model programs take 20-40s; with a cache dir set, later
     # processes deserialize the executable instead of recompiling
     # (empty = disabled). Two layers share the knob: jax's builtin
-    # HLO-keyed cache (wired at import) writes the root, and the AOT
-    # executable store (tensorframes_tpu/compilecache — consulted
-    # BEFORE lowering, so a hit skips HLO generation and XLA entirely)
-    # lives under <dir>/aot.
-    compilation_cache_dir: str = os.environ.get("TFTPU_COMPILE_CACHE", "")
+    # HLO-keyed cache writes the root, and the AOT executable store
+    # (tensorframes_tpu/compilecache — consulted BEFORE lowering, so a
+    # hit skips HLO generation and XLA entirely) lives under <dir>/aot.
+    # Resolved by resolve_compile_cache_dir(): JAX_COMPILATION_CACHE_DIR,
+    # else TFTPU_COMPILE_CACHE, else (chip entry points only) the
+    # checkout's fixed directory.
+    compilation_cache_dir: str = dataclasses.field(
+        default_factory=resolve_compile_cache_dir
+    )
     # Byte bound of the AOT executable store (<cache dir>/aot): least-
     # recently-used entries are evicted past it. 0 disables eviction.
     compile_cache_max_bytes: int = _env_int(
@@ -108,15 +152,15 @@ class Config:
     # Route quantized 2-D matmuls through the pallas int8 kernel
     # (in-kernel dequant: weights stream HBM→VMEM as int8
     # unconditionally, ops/quantize.matmul_pallas_int8). OFF until a
-    # real-TPU window shows it beating the XLA structural fusion —
-    # dev/tpu_smoke.py prints the adjudicating comparison.
+    # chip cell shows it beating the XLA structural fusion
+    # (chip_smoke.py compiles it once and reports the outcome).
     pallas_int8_matmul: bool = _env_bool("TFTPU_PALLAS_INT8_MM", False)
     # Master switch for the straggler pallas kernels (tensorframes_tpu/
     # kernels: paged int8-KV decode attention, fused segment reduce,
     # ragged gather). TFTPU_PALLAS=0 removes them from every cost-model
     # decision — the CI smoke proves the XLA/host lowerings alone keep
-    # every suite green. Distinct from the runtime kill-switch
-    # (ops/segment.disable_pallas), which trips on a Mosaic failure.
+    # every suite green. Distinct from the in-process manual switch
+    # (ops/segment.disable_pallas); nothing throws either automatically.
     pallas_kernels: bool = _env_bool("TFTPU_PALLAS", True)
     # Force-select the straggler kernels even on CPU backends (the
     # pallas interpreter runs them — slow, but the full wiring from
@@ -133,8 +177,8 @@ class Config:
     plan_fusion: bool = _env_bool("TFTPU_FUSION", True)
     # Adaptive query optimizer (tensorframes_tpu/plan: aggregate
     # pushdown below joins, multi-join reordering, and feedback
-    # re-optimization from the per-plan stats sidecar under
-    # TFTPU_COMPILE_CACHE). TFTPU_REOPT=0 is the escape hatch back to
+    # re-optimization from the per-plan stats sidecar under the
+    # compile-cache directory). TFTPU_REOPT=0 is the escape hatch back to
     # the PR 7 static cost model: no plan rewrite, no reordering, no
     # stats recording or consultation — bit-identical results either
     # way (the optimizer exists purely for speed; every rewrite is

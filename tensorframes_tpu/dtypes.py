@@ -110,9 +110,9 @@ def demotion_active() -> bool:
     if cfg == "always":
         return True
     if cfg:
-        import jax
+        from .utils.backend import is_tpu_backend
 
-        return jax.default_backend() == "tpu"
+        return is_tpu_backend()
     return False
 
 

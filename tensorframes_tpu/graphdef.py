@@ -45,7 +45,7 @@ from . import dtypes as dt
 from .ops.windows import same_pool_counts
 from .program import Program, TensorSpec, analyze_program
 from .shape import Shape, Unknown
-from .utils import get_logger
+from .utils import get_logger, is_tpu_backend
 
 logger = get_logger(__name__)
 
@@ -970,7 +970,7 @@ def _resolve_compute_dtype(compute_dtype):
         return compute_dtype
     import jax
 
-    resolved = "bfloat16" if jax.default_backend() != "cpu" else None
+    resolved = "bfloat16" if is_tpu_backend() else None
     if resolved == "bfloat16":
         # precision drift must be traceable: "auto" silently changing
         # imported-graph numerics vs TF is worth one log line per

@@ -58,7 +58,7 @@ def _gather_fn_for(length: int, dtype_name: str, interpret: bool):
             num_scalar_prefetch=1,
             grid=(g,),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # flat stays HBM
+                pl.BlockSpec(memory_space=pl.ANY),  # flat stays HBM
             ],
             out_specs=pl.BlockSpec(
                 (1, length), lambda r, starts: (r, r - r)

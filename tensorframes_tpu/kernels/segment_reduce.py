@@ -4,33 +4,37 @@ strategy beside the jitted scatter and the host ``np.bincount``
 ``plan/rules.decide_segment_reduce``.
 
 One pallas dispatch computes EVERY (column, op) fetch of a keyed
-``aggregate``: the grid walks row tiles sequentially and accumulates
-per-segment partials into the same output block —
+``aggregate``. Rows ride the **lanes**: the id column and every value
+column enter as ``[rows / 128, 128]`` (a 1-D column costs its own
+bytes, not a 128x lane pad), the grid walks row tiles sequentially,
+and each (column, op) keeps a ``[segments, 128]`` accumulator resident
+in VMEM — lane ``l`` of segment ``s`` holds the partial over rows
+``r ≡ l (mod 128)`` whose id is ``s``. One grid step, per 128 rows:
+compare the id row against a segment iota down the sublanes
+(membership), then select-and-accumulate each value row:
 
-* ``sum``/``mean`` of floats: the one-hot MXU contraction (the PR 7
-  trick — ``[tile, segments]`` membership one-hot against the value
-  tile as a dense f32 matmul, ``precision=HIGHEST``);
-* ``sum``/``mean`` of ints/bools: the same one-hot contraction with an
-  **int32 accumulator** (``preferred_element_type=int32`` — exact
-  associative arithmetic, bit-identical to the scatter by
-  construction);
-* ``min``/``max``: a masked VPU reduction over the
-  ``[tile, segments, d]`` broadcast (order-free, so also exactly the
-  scatter's bits); the row tile shrinks adaptively so that broadcast
-  stays VMEM-bounded, and :func:`eligible` refuses shapes where it
-  cannot.
+* ``sum``/``mean``: ``acc += where(member, value, 0)`` — float32
+  accumulate for floats, **int32** for ints/bools (exact associative
+  arithmetic, bit-identical to the scatter by construction);
+* ``min``/``max``: ``acc = min(acc, where(member, value, identity))``
+  (order-free, so also exactly the scatter's bits).
 
-Mean division and final dtype casts happen OUTSIDE the kernel with the
-jitted path's formula (``(s / c).astype(v.dtype)``; the count table is
-i32-exact). Bit-identity is gated two ways: against
+Only 32-bit compare/select/add/min on the VPU — no MXU contraction
+(Mosaic has no int32 matmul, and the earlier ``[tile, segments, d]``
+masked broadcast needed lane↔sublane relayouts it refused; PR 21) and
+no dynamic indexing. Narrower dtypes widen to the accumulator dtype
+OUTSIDE the kernel and narrow back after. A 2-D column ``[n, d]``
+(``d <= MAX_INNER``) is ``d`` row streams.
+
+The lane reduction, mean division and final casts happen outside the
+kernel with the jitted path's formula (``(s / c).astype(v.dtype)``;
+the count table is i32-exact). Bit-identity is gated two ways: against
 :func:`segment_reduce_reference` — the same tiled computation in plain
 jnp, exact by construction for every op/dtype — and against the XLA
 scatter for the order-free classes (min/max, integer sums).
 
-Sorted-or-not segment ids; padded rows carry id ``num_segments`` and
-match nothing real (a padded row can land in a padded SEGMENT slot,
-which the final slice discards). Runs on the pallas CPU interpreter
-when no Mosaic toolchain serves the backend
+Sorted-or-not segment ids; padded rows carry id ``-1`` and match no
+segment. Runs on the pallas CPU interpreter when the backend is CPU
 (:func:`tensorframes_tpu.kernels.interpret_mode`).
 """
 
@@ -46,12 +50,18 @@ from jax import lax
 
 from . import build_timer, note_dispatch
 
-#: default rows per grid step (sublane-aligned); shrinks for min/max
-_TILE_ROWS = 256
-#: past this, the one-hot wastes more FLOPs than the scatter costs
+_LANES = 128
+#: sublane rows (of 128 lanes each) per grid step
+_TILE_R = 8
+#: rows consumed per grid step; row counts pad up to a multiple of it
+_ROW_QUANTUM = _TILE_R * _LANES
+#: past this, the resident accumulators outgrow VMEM
 MAX_SEGMENTS = 4096
-#: element budget for the [tile, segments, d] min/max broadcast
-_MASK_BUDGET = 1 << 20
+#: widest 2-D column served (each inner column is its own row stream)
+MAX_INNER = 8
+#: accumulators the kernel may keep resident (Pallas double-buffers the
+#: output blocks; a v5e core has 128 MiB of VMEM)
+_VMEM_BUDGET = 64 << 20
 
 _FLOAT_OK = ("float32", "bfloat16")
 _INT_OK = ("int32", "int16", "int8", "uint8", "bool")
@@ -82,144 +92,119 @@ def _col_meta(ops_key, val_cols) -> Tuple[Tuple[str, str, int, int, str], ...]:
     return tuple(meta)
 
 
-def _tile_rows(meta, num_segments: int) -> int:
-    """Row-tile size: the default unless a min/max column's masked
-    broadcast would blow the element budget, in which case shrink
-    (never below the 8-row sublane floor — :func:`eligible` refuses
-    shapes that would still not fit there)."""
+def _acc_bytes(meta, num_segments: int) -> int:
+    """VMEM the resident accumulators take (double-buffered)."""
     s_pad = _round_up(max(num_segments, 1), 8)
-    tile = _TILE_ROWS
-    for _, _, d, _, op in meta:
-        if op in ("reduce_min", "reduce_max"):
-            d_pad = _round_up(d, 128)
-            while tile > 8 and tile * s_pad * d_pad > _MASK_BUDGET:
-                tile //= 2
-    return tile
+    n_acc = sum(d for _, _, d, _, _ in meta)
+    if any(op == "reduce_mean" for *_, op in meta):
+        n_acc += 1
+    return 2 * n_acc * s_pad * _LANES * 4
 
 
 def eligible(ops_key, val_cols, num_segments: int) -> bool:
     """True when the fused pallas kernel can serve this keyed
-    reduction exactly: bounded segment count, 1-D/2-D values, float32/
-    bfloat16 (f32 accumulate) or ≤32-bit int/bool (i32 accumulate —
-    wider ints could overflow the exact accumulator), and a min/max
-    broadcast that fits the tile budget."""
+    reduction exactly: bounded segment count, 1-D or narrow 2-D
+    values, float32/bfloat16 (f32 accumulate) or ≤32-bit int/bool (i32
+    accumulate — wider ints could overflow the exact accumulator), and
+    accumulators that fit the VMEM budget."""
     if not 0 < num_segments <= MAX_SEGMENTS:
         return False
     for x, op in ops_key:
         if op not in _OPS:
             return False
         v = val_cols[x]
-        if getattr(v, "ndim", None) not in (1, 2):
+        ndim = getattr(v, "ndim", None)
+        if ndim not in (1, 2):
+            return False
+        if ndim == 2 and not 0 < int(v.shape[1]) <= MAX_INNER:
             return False
         if _dtype_name(v) not in _FLOAT_OK + _INT_OK:
             return False
-    meta = _col_meta(ops_key, val_cols)
-    tile = _tile_rows(meta, num_segments)
-    s_pad = _round_up(num_segments, 8)
-    return not any(
-        tile * s_pad * _round_up(d, 128) > _MASK_BUDGET
-        for _, _, d, _, op in meta
-        if op in ("reduce_min", "reduce_max")
-    )
+    return _acc_bytes(
+        _col_meta(ops_key, val_cols), num_segments
+    ) <= _VMEM_BUDGET
 
 
 def _acc_dtype(dtype_name: str):
-    """(accumulator dtype, is_float) for a sum/mean column."""
-    if dtype_name in _FLOAT_OK:
-        return jnp.float32, True
-    return jnp.int32, False
+    """Accumulator dtype of a column: f32 for floats, i32 otherwise."""
+    return jnp.float32 if dtype_name in _FLOAT_OK else jnp.int32
 
 
 def _minmax_identity(dtype_name: str, op: str):
+    """The reduction identity of the column's OWN dtype, held in the
+    accumulator dtype — an empty segment then narrows back to exactly
+    the scatter's answer (``iinfo(int8).max``, not int32's). A numpy
+    scalar, so the kernel body inlines it as a literal (a jax array
+    would be a captured constant, which pallas_call refuses)."""
     if dtype_name in _FLOAT_OK:
-        return jnp.asarray(
-            jnp.inf if op == "reduce_min" else -jnp.inf,
-            _np_to_jnp_dtype(dtype_name),
-        )
+        return np.float32(np.inf if op == "reduce_min" else -np.inf)
     if dtype_name == "bool":
-        return jnp.asarray(op == "reduce_min", jnp.bool_)
+        return np.int32(op == "reduce_min")
     info = np.iinfo(np.dtype(dtype_name))
-    return jnp.asarray(
-        info.max if op == "reduce_min" else info.min,
-        np.dtype(dtype_name),
-    )
+    return np.int32(info.max if op == "reduce_min" else info.min)
 
 
-def _tile_partial(op: str, dtype_name: str, seg: jnp.ndarray,
-                  vals: jnp.ndarray, s_pad: int):
-    """One tile's per-segment partial — THE shared math of the kernel
-    body and the plain-jnp reference emulation (bit-identity between
-    them is by construction: same ops, same order, same dtypes).
-    ``seg`` [tile] int32, ``vals`` [tile, d_pad]."""
-    tile = seg.shape[0]
-    seg_iota = lax.broadcasted_iota(jnp.int32, (tile, s_pad), 1)
-    member = seg[:, None] == seg_iota                      # [tile, s_pad]
+def _member(seg_row: jnp.ndarray, s_pad: int) -> jnp.ndarray:
+    """[s_pad, 128] membership of one id row ([1, 128] int32): lane
+    ``l`` of segment ``s`` is true when row ``l`` belongs to ``s``."""
+    seg_iota = lax.broadcasted_iota(jnp.int32, (s_pad, _LANES), 0)
+    return seg_iota == seg_row
+
+
+def _fold_row(op: str, dtype_name: str, acc: jnp.ndarray,
+              member: jnp.ndarray, row: jnp.ndarray) -> jnp.ndarray:
+    """Fold one value row ([1, 128], accumulator dtype) into a
+    [s_pad, 128] accumulator — THE shared math of the kernel body and
+    the plain-jnp reference emulation (bit-identity between them is by
+    construction: same ops, same order, same dtypes)."""
     if op in ("reduce_sum", "reduce_mean"):
-        acc, is_float = _acc_dtype(dtype_name)
-        kw = {"precision": lax.Precision.HIGHEST} if is_float else {}
-        return lax.dot_general(
-            member.astype(acc),
-            vals.astype(acc),
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=acc,
-            **kw,
-        )
-    ident = _minmax_identity(dtype_name, op)
-    masked = jnp.where(member[:, :, None], vals[:, None, :], ident)
-    red = jnp.min if op == "reduce_min" else jnp.max
-    return red(masked, axis=0)                             # [s_pad, d_pad]
+        return acc + jnp.where(member, row, row.dtype.type(0))
+    comb = jnp.minimum if op == "reduce_min" else jnp.maximum
+    return comb(acc, jnp.where(member, row, _minmax_identity(dtype_name, op)))
 
 
-def _count_partial(seg: jnp.ndarray, s_pad: int) -> jnp.ndarray:
-    """Per-segment row counts for one tile (i32-exact; every lane of
-    the [s_pad, 128] table carries the same count — lane 0 is read)."""
-    tile = seg.shape[0]
-    seg_iota = lax.broadcasted_iota(jnp.int32, (tile, s_pad), 1)
-    member = (seg[:, None] == seg_iota).astype(jnp.int32)
-    return lax.dot_general(
-        member,
-        jnp.ones((tile, 128), jnp.int32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+def _init_acc(op: str, dtype_name: str, shape) -> jnp.ndarray:
+    if op in ("reduce_min", "reduce_max"):
+        return jnp.full(shape, _minmax_identity(dtype_name, op))
+    return jnp.zeros(shape, _acc_dtype(dtype_name))
 
 
-def _pad_inputs(meta, num_segments, val_cols, seg_ids, tile):
-    """Tile-pad the feed: segs [n_pad, 1] (padding rows → id ==
-    num_segments), each column [n_pad, d_pad]."""
+def _pad_inputs(meta, val_cols, seg_ids):
+    """Lay the feed out rows-on-lanes: ids ``[R, 128]`` int32 (padding
+    rows → ``-1``), each column ``[d, R, 128]`` in its accumulator
+    dtype, ``R`` a multiple of the row tile."""
     seg_ids = jnp.asarray(np.asarray(seg_ids)).astype(jnp.int32)
     n = int(seg_ids.shape[0])
-    n_pad = _round_up(max(n, 1), tile)
-    segs = jnp.full((n_pad, 1), num_segments, jnp.int32)
-    if n:
-        segs = segs.at[:n, 0].set(seg_ids)
+    n_pad = _round_up(max(n, 1), _ROW_QUANTUM)
+    segs = jnp.pad(seg_ids, (0, n_pad - n), constant_values=-1)
+    segs = segs.reshape(n_pad // _LANES, _LANES)
     padded = {}
     for x, dtype_name, d, ndim, _ in meta:
-        v = jnp.asarray(val_cols[x])
+        v = jnp.asarray(val_cols[x]).astype(_acc_dtype(dtype_name))
         v2 = v[:, None] if ndim == 1 else v
-        d_pad = _round_up(d, 128)
-        buf = jnp.zeros((n_pad, d_pad), v2.dtype)
-        if n:
-            buf = buf.at[:n, :d].set(v2)
-        padded[x] = buf
-    return segs, padded, n_pad
+        v2 = jnp.pad(v2, ((0, n_pad - n), (0, 0)))
+        padded[x] = v2.T.reshape(d, n_pad // _LANES, _LANES)
+    return segs, padded
 
 
 def _finalize(meta, num_segments, partials, counts):
-    """Slice away padding and apply the jitted path's mean/cast
-    formula: ``s.astype(v.dtype)`` for sums, ``(s / c).astype(v.dtype)``
-    for means. Returns 2-D [K, d] columns (callers restore 1-D)."""
+    """Reduce the lanes, slice away padding and apply the jitted
+    path's mean/cast formula: ``s.astype(v.dtype)`` for sums,
+    ``(s / c).astype(v.dtype)`` for means. Returns 2-D [K, d] columns
+    (callers restore 1-D)."""
     out = {}
     for x, dtype_name, d, _, op in meta:
         dt = _np_to_jnp_dtype(dtype_name)
-        p = partials[x][:num_segments, :d]
-        if op in ("reduce_min", "reduce_max"):
-            out[x] = p
+        p = partials[x][:, :num_segments]           # [d, K, 128]
+        if op == "reduce_min":
+            out[x] = p.min(axis=-1).T.astype(dt)
+        elif op == "reduce_max":
+            out[x] = p.max(axis=-1).T.astype(dt)
         elif op == "reduce_sum":
-            out[x] = p.astype(dt)
+            out[x] = p.sum(axis=-1).T.astype(dt)
         else:  # reduce_mean
-            s = p.astype(dt)
-            c = counts[:num_segments, :1].astype(dt)
+            s = p.sum(axis=-1).T.astype(dt)
+            c = counts[:num_segments].sum(axis=-1)[:, None].astype(dt)
             out[x] = (s / c).astype(dt)
     return out
 
@@ -240,7 +225,6 @@ def _pallas_fn_for(meta, num_segments: int, interpret: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    tile = _tile_rows(meta, num_segments)
     s_pad = _round_up(num_segments, 8)
     need_counts = any(op == "reduce_mean" for *_, op in meta)
     n_cols = len(meta)
@@ -249,72 +233,59 @@ def _pallas_fn_for(meta, num_segments: int, interpret: bool):
         val_refs = refs[:n_cols]
         out_refs = refs[n_cols:2 * n_cols]
         cnt_ref = refs[2 * n_cols] if need_counts else None
-        first = pl.program_id(0) == 0
-        seg = seg_ref[:, 0]
-        for (x, dtype_name, d, ndim, op), v_ref, o_ref in zip(
-            meta, val_refs, out_refs
-        ):
-            part = _tile_partial(op, dtype_name, seg, v_ref[:], s_pad)
-            if op in ("reduce_min", "reduce_max"):
-                ident = _minmax_identity(dtype_name, op)
 
-                @pl.when(first)
-                def _init(o_ref=o_ref, ident=ident):
-                    o_ref[:] = jnp.full(
-                        o_ref.shape, ident, o_ref.dtype
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            for (_, dtype_name, _, _, op), o_ref in zip(meta, out_refs):
+                o_ref[...] = _init_acc(op, dtype_name, o_ref.shape)
+            if cnt_ref is not None:
+                cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
+
+        for t in range(_TILE_R):
+            member = _member(seg_ref[t:t + 1, :], s_pad)
+            for (_, dtype_name, d, _, op), v_ref, o_ref in zip(
+                meta, val_refs, out_refs
+            ):
+                for c in range(d):
+                    o_ref[c] = _fold_row(
+                        op, dtype_name, o_ref[c], member,
+                        v_ref[c, t:t + 1, :],
                     )
-
-                comb = jnp.minimum if op == "reduce_min" else jnp.maximum
-                o_ref[:] = comb(o_ref[:], part)
-            else:
-                @pl.when(first)
-                def _init(o_ref=o_ref):
-                    o_ref[:] = jnp.zeros_like(o_ref)
-
-                o_ref[:] += part
-        if cnt_ref is not None:
-            @pl.when(first)
-            def _init_c():
-                cnt_ref[:] = jnp.zeros_like(cnt_ref)
-
-            cnt_ref[:] += _count_partial(seg, s_pad)
+            if cnt_ref is not None:
+                cnt_ref[...] += member.astype(jnp.int32)
 
     @jax.jit
     def run(segs, vals):
-        n_pad = segs.shape[0]
-        grid = (n_pad // tile,)
+        grid = (segs.shape[0] // _TILE_R,)
         # every index-map component derives from the grid index: this
         # package enables x64 at import, under which a literal 0
         # traces i64 beside the i32 grid index and Mosaic fails to
         # legalize the mixed-type func.return (the ops/segment.py
         # lesson); ``i - i`` is an i32 zero
-        in_specs = [pl.BlockSpec((tile, 1), lambda i: (i, i - i),
+        in_specs = [pl.BlockSpec((_TILE_R, _LANES), lambda i: (i, i - i),
                                  memory_space=pltpu.VMEM)]
         out_shapes = []
         out_specs = []
         ins = [segs]
         for x, dtype_name, d, ndim, op in meta:
-            d_pad = _round_up(d, 128)
             in_specs.append(pl.BlockSpec(
-                (tile, d_pad), lambda i: (i, i - i),
+                (d, _TILE_R, _LANES), lambda i: (i - i, i, i - i),
                 memory_space=pltpu.VMEM,
             ))
             ins.append(vals[x])
-            if op in ("reduce_min", "reduce_max"):
-                out_dt = _np_to_jnp_dtype(dtype_name)
-            else:
-                out_dt = _acc_dtype(dtype_name)[0]
-            out_shapes.append(jax.ShapeDtypeStruct((s_pad, d_pad), out_dt))
+            out_shapes.append(jax.ShapeDtypeStruct(
+                (d, s_pad, _LANES), _acc_dtype(dtype_name)
+            ))
             out_specs.append(pl.BlockSpec(
-                (s_pad, d_pad), lambda i: (i - i, i - i),
+                (d, s_pad, _LANES), lambda i: (i - i, i - i, i - i),
                 memory_space=pltpu.VMEM,
             ))
         if need_counts:
             out_shapes.append(
-                jax.ShapeDtypeStruct((s_pad, 128), jnp.int32)
+                jax.ShapeDtypeStruct((s_pad, _LANES), jnp.int32)
             )
             out_specs.append(pl.BlockSpec(
-                (s_pad, 128), lambda i: (i - i, i - i),
+                (s_pad, _LANES), lambda i: (i - i, i - i),
                 memory_space=pltpu.VMEM,
             ))
         outs = pl.pallas_call(
@@ -323,7 +294,16 @@ def _pallas_fn_for(meta, num_segments: int, interpret: bool):
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shapes,
+            # the accumulators stay resident across the whole grid;
+            # state the scoped-VMEM need instead of inheriting the
+            # 16 MiB default that a 4096-segment table would exceed
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_acc_bytes(meta, num_segments)
+                + (16 << 20),
+            ),
             interpret=interpret,
+            name="segment_reduce",
         )(*ins)
         partials = {meta[k][0]: outs[k] for k in range(n_cols)}
         counts = outs[n_cols] if need_counts else None
@@ -346,12 +326,9 @@ def segment_reduce_pallas(
     if interpret is None:
         interpret = interpret_mode()
     meta = _col_meta(ops_key, val_cols)
-    tile = _tile_rows(meta, num_segments)
     with build_timer():
         fn = _pallas_fn_for(meta, num_segments, bool(interpret))
-    segs, padded, _ = _pad_inputs(
-        meta, num_segments, val_cols, seg_ids, tile
-    )
+    segs, padded = _pad_inputs(meta, val_cols, seg_ids)
     note_dispatch("segment_reduce", bool(interpret))
     return _unpad(meta, fn(segs, padded))
 
@@ -360,41 +337,31 @@ def segment_reduce_reference(
     ops_key, num_segments: int, val_cols, seg_ids,
 ) -> Dict[str, np.ndarray]:
     """Plain-jnp emulation of the kernel's exact tiled computation —
-    the bit-identity oracle (same per-tile math via
-    :func:`_tile_partial`, same sequential tile order, same finalize
-    formula; no pallas anywhere). Tests and the in-bench gate assert
-    ``segment_reduce_pallas == segment_reduce_reference`` bitwise."""
+    the bit-identity oracle (same per-row fold via :func:`_fold_row`,
+    same sequential row order, same finalize formula; no pallas
+    anywhere). Tests assert ``segment_reduce_pallas ==
+    segment_reduce_reference`` bitwise."""
     meta = _col_meta(ops_key, val_cols)
-    tile = _tile_rows(meta, num_segments)
     s_pad = _round_up(num_segments, 8)
-    segs, padded, n_pad = _pad_inputs(
-        meta, num_segments, val_cols, seg_ids, tile
-    )
-    seg_flat = segs[:, 0]
+    segs, padded = _pad_inputs(meta, val_cols, seg_ids)
     need_counts = any(op == "reduce_mean" for *_, op in meta)
-    partials: Dict[str, jnp.ndarray] = {}
-    counts = None
-    for t in range(n_pad // tile):
-        seg_t = seg_flat[t * tile:(t + 1) * tile]
-        for x, dtype_name, d, ndim, op in meta:
-            v_t = padded[x][t * tile:(t + 1) * tile]
-            part = _tile_partial(op, dtype_name, seg_t, v_t, s_pad)
-            if x not in partials:
-                if op in ("reduce_min", "reduce_max"):
-                    partials[x] = jnp.full(
-                        part.shape, _minmax_identity(dtype_name, op),
-                        part.dtype,
-                    )
-                else:
-                    partials[x] = jnp.zeros_like(part)
-            if op in ("reduce_min", "reduce_max"):
-                comb = jnp.minimum if op == "reduce_min" else jnp.maximum
-                partials[x] = comb(partials[x], part)
-            else:
-                partials[x] = partials[x] + part
+    partials = {
+        x: [_init_acc(op, dtype_name, (s_pad, _LANES)) for _ in range(d)]
+        for x, dtype_name, d, _, op in meta
+    }
+    counts = jnp.zeros((s_pad, _LANES), jnp.int32)
+    for r in range(int(segs.shape[0])):
+        member = _member(segs[r:r + 1, :], s_pad)
+        for x, dtype_name, d, _, op in meta:
+            for c in range(d):
+                partials[x][c] = _fold_row(
+                    op, dtype_name, partials[x][c], member,
+                    padded[x][c, r:r + 1, :],
+                )
         if need_counts:
-            cp = _count_partial(seg_t, s_pad)
-            counts = cp if counts is None else counts + cp
-    return _unpad(
-        meta, _finalize(meta, num_segments, partials, counts)
-    )
+            counts = counts + member.astype(jnp.int32)
+    return _unpad(meta, _finalize(
+        meta, num_segments,
+        {x: jnp.stack(p) for x, p in partials.items()},
+        counts if need_counts else None,
+    ))

@@ -8,14 +8,17 @@ as numpy arrays zero-copy, then `jax.device_put` to HBM), and one native
 pass materializes result rows from column buffers.
 
 The extension is compiled on demand from the bundled source with g++ (no
-pybind11 — plain CPython C API) and cached next to this file; anything that
-fails — no compiler, unsupported platform, exotic cell types — falls back
-to the pure-Python path transparently. ``TFS_TPU_DISABLE_NATIVE=1``
-disables it outright.
+pybind11 — plain CPython C API) and cached next to this file, with the
+source's sha256 recorded beside it: the ``.so`` is reused only while that
+hash matches ``rowpack.cpp`` (mtimes do not survive a copy of the tree).
+Without a compiler the pure-Python path serves; :func:`status` says which
+of ``built | loaded | unavailable`` happened, and ``chip_smoke.py`` fails
+on ``unavailable``. ``TFS_TPU_DISABLE_NATIVE=1`` disables it outright.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -38,6 +41,7 @@ _DTYPE_CODES = {
 _lock = threading.Lock()
 _mod = None
 _load_attempted = False
+_status = "unavailable"
 
 
 def _source_path() -> str:
@@ -48,8 +52,28 @@ def _so_path() -> str:
     return os.path.join(os.path.dirname(__file__), "_rowpack.so")
 
 
+def _hash_path() -> str:
+    return _so_path() + ".sha256"
+
+
+def _source_hash() -> str:
+    with open(_source_path(), "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _so_is_current() -> bool:
+    """The cached ``.so`` was built from exactly this ``rowpack.cpp``."""
+    try:
+        with open(_hash_path()) as f:
+            recorded = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(_so_path()) and recorded == _source_hash()
+
+
 def _build() -> bool:
-    """Compile rowpack.cpp → _rowpack.so with g++. Returns success."""
+    """Compile rowpack.cpp → _rowpack.so with g++ and record the
+    source hash beside it. Returns success."""
     include = sysconfig.get_paths()["include"]
     # build to a temp path and os.replace so an interrupted g++ can never
     # leave a truncated .so at the final path (which would otherwise look
@@ -74,6 +98,8 @@ def _build() -> bool:
             logger.warning("native build failed:\n%s", proc.stderr[-2000:])
             return False
         os.replace(tmp, _so_path())
+        with open(_hash_path(), "w") as f:
+            f.write(_source_hash() + "\n")
     except (OSError, subprocess.TimeoutExpired) as e:  # pragma: no cover
         logger.warning("native build failed: %s", e)
         return False
@@ -87,18 +113,19 @@ def _build() -> bool:
 
 
 def _load():
-    global _mod, _load_attempted
+    global _mod, _load_attempted, _status
     with _lock:
         if _load_attempted:
             return _mod
         _load_attempted = True
         if os.environ.get("TFS_TPU_DISABLE_NATIVE", "") == "1":
             return None
-        if not os.path.exists(_so_path()) or (
-            os.path.getmtime(_so_path()) < os.path.getmtime(_source_path())
-        ):
+        _status = "loaded"
+        if not _so_is_current():
             if not _build():
+                _status = "unavailable"
                 return None
+            _status = "built"
         try:
             from . import _rowpack  # type: ignore[attr-defined]
 
@@ -116,13 +143,25 @@ def _load():
                     import importlib
 
                     _mod = importlib.import_module(f"{__name__}._rowpack")
+                    _status = "built"
                 except ImportError:
                     _mod = None
+        if _mod is None:
+            _status = "unavailable"
         return _mod
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """How this process got the extension: ``built`` (compiled now),
+    ``loaded`` (a cached ``.so`` whose recorded hash matches the
+    source) or ``unavailable`` (disabled, or the build failed and the
+    pure-Python path serves)."""
+    _load()
+    return _status
 
 
 def supported_dtype(np_dtype) -> bool:

@@ -52,7 +52,7 @@ from ..observability.metrics import histogram as _histogram
 from ..program import Program
 from ..resilience import fleet as _fleet
 from ..resilience.faults import delay_point, fault_point, register_site
-from ..utils import get_logger
+from ..utils import get_logger, is_tpu_backend
 
 logger = get_logger(__name__)
 
@@ -116,7 +116,7 @@ def donation_supported() -> bool:
     """True when the active backend implements input-buffer donation.
     XLA:CPU ignores donation with a per-call warning, so the donate
     paths gate on this instead of spamming host-only runs."""
-    return jax.default_backend() not in ("cpu",)
+    return is_tpu_backend()
 
 
 def bucket_rows(n: int) -> int:
@@ -268,8 +268,11 @@ class _KeyedBuildCache:
             try:
                 value, how = build()
             except Exception as e:
-                logger.debug("AOT path unavailable for %s (%s); using "
-                             "jit dispatch", describe, e)
+                logger.warning(
+                    "AOT build failed for %s (%s: %s); this key "
+                    "dispatches through the counted lazy-jit fallback",
+                    describe, type(e).__name__, e,
+                )
                 with self._lock:
                     self.failed.add(key)
                 return None, "failed"

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils import is_tpu_backend
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -148,8 +150,8 @@ def matmul_pallas_int8(
     k innermost (sequential accumulation into the output block), so
     VMEM holds only one tile per operand regardless of activation size.
     Gated behind ``config.pallas_int8_matmul`` (off by default until a
-    real-TPU window adjudicates it — ``dev/tpu_smoke.py`` prints the
-    comparison); shapes: x [*, k], w.q [k, n], per-output-channel
+    chip cell adjudicates it — ``chip_smoke.py`` compiles it once and
+    reports the outcome); shapes: x [*, k], w.q [k, n], per-output-channel
     scales. Same index-map x64 discipline as ops/segment.py (``i - i``
     is an i32 zero under jax x64)."""
     import jax.experimental.pallas as pl
@@ -277,7 +279,7 @@ def _pallas_int8_eligible(x, w) -> bool:
         and w.q.ndim == 2
         and w.scale.shape[:-1] == (1,)
         and _pallas_dtype_ok(jnp.asarray(x).dtype)
-        and jax.default_backend() == "tpu"
+        and is_tpu_backend()
         and _pallas_int8_probe_ok()
     )
 

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..utils import is_tpu_backend
+
 # rows per grid step (sublane-aligned); lanes carry the feature dim
 _TILE_ROWS = 256
 # above this many segments the one-hot matmul wastes more FLOPs than the
@@ -118,31 +120,31 @@ def segment_sum_pallas(
     return out[:num_segments, :d]
 
 
-# Mosaic kill-switch: a TPU-toolchain kernel-compile failure at runtime
-# must degrade to XLA's scatter path, never take down `aggregate`
-# (verbs.py catches the failure, calls disable_pallas(), and retries).
+# Manual process-wide switch for every pallas family (this kernel and
+# tensorframes_tpu/kernels). Nothing throws it automatically: a kernel
+# Mosaic refuses raises at its call site. It is a compile-cache
+# fingerprint axis (kernels.fingerprint_token), so executables built on
+# either side of a flip never mix.
 _pallas_disabled = False
 
 
 def disable_pallas(reason: str = "") -> None:
+    """Take every pallas kernel out of selection for the rest of the
+    process (the in-process form of ``TFTPU_PALLAS=0``)."""
     global _pallas_disabled
     if not _pallas_disabled:
         import logging
 
         logging.getLogger(__name__).warning(
-            "disabling pallas segment kernel (falling back to XLA "
-            "segment_sum)%s", f": {reason}" if reason else ""
+            "pallas kernels disabled for this process (XLA/host "
+            "lowerings only)%s", f": {reason}" if reason else ""
         )
-        try:
-            # fused plan epilogues traced with pallas enabled are stale
-            # the moment the kill-switch trips — drop them so the next
-            # force re-traces onto the XLA scatter instead of replaying
-            # the failing kernel from the cache forever
-            from ..plan.lower import clear_fused_cache
+        # fused plan epilogues traced with pallas enabled are stale the
+        # moment the switch flips — drop them so the next force
+        # re-traces onto the XLA scatter
+        from ..plan.lower import clear_fused_cache
 
-            clear_fused_cache()
-        except Exception:  # pragma: no cover - never block the switch
-            pass
+        clear_fused_cache()
     _pallas_disabled = True
 
 
@@ -156,7 +158,7 @@ def _pallas_eligible(values: jnp.ndarray, num_segments: int) -> bool:
         and values.ndim == 2
         and values.dtype in (jnp.float32, jnp.bfloat16)
         and 0 < num_segments <= _MAX_PALLAS_SEGMENTS
-        and jax.default_backend() == "tpu"
+        and is_tpu_backend()
     )
 
 
@@ -170,7 +172,7 @@ def host_segment_eligible(ops_key, val_cols) -> bool:
     bincount form). Works on numpy AND jax-array values so the fused
     plan epilogue and the eager path take the SAME branch — that
     sameness is what keeps fused and unfused outputs bit-identical."""
-    if jax.default_backend() != "cpu":
+    if is_tpu_backend():
         return False
     for x, op in ops_key:
         v = val_cols[x]

@@ -1,46 +1,27 @@
-"""Version-tolerant ``shard_map`` / mesh-context entry points (single
-copy for the whole package): jax >= 0.8 exposes ``jax.shard_map`` with
-``check_vma``; older releases have
-``jax.experimental.shard_map.shard_map`` with ``check_rep``. Likewise
-``jax.sharding.use_mesh`` supersedes entering the ``Mesh`` object as a
-context manager.
+"""``shard_map`` / mesh-context entry points (single copy for the whole
+package), spelled for the installed jax (0.9): ``jax.shard_map`` with
+``check_vma`` and ``jax.set_mesh``.
 """
 
 from __future__ import annotations
 
 import contextlib
 
+import jax
+
 
 def mesh_context(mesh):
     """Context manager installing ``mesh`` as the ambient mesh for
     tracing (axis names resolvable by ``with_sharding_constraint``/
-    collectives) — ``jax.sharding.use_mesh`` on new jax, the legacy
-    ``with mesh:`` entry elsewhere, and a no-op for ``mesh=None``. Used
-    by the sharded TFG108 probe, which must re-trace a program exactly
-    as the executor traced it, without touching device data."""
+    collectives); a no-op for ``mesh=None``. Used by the sharded TFG108
+    probe, which must re-trace a program exactly as the executor traced
+    it, without touching device data."""
     if mesh is None:
         return contextlib.nullcontext()
-    import jax
-
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        try:
-            return use_mesh(mesh)
-        except Exception:  # pragma: no cover - jax internals moved
-            pass
-    return mesh
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    try:
-        from jax import shard_map as _sm
-
-        return _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    except ImportError:  # pragma: no cover — old jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-        )
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
+    )

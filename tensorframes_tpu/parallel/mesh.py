@@ -152,25 +152,11 @@ def make_mesh(
         )
     # Auto axis types: XLA's SPMD partitioner solves intermediate shardings
     # (explicit sharding-in-types would demand out_sharding annotations on
-    # ambiguous ops like embedding gathers). Version-tolerant: AxisType
-    # (and make_mesh's axis_types parameter) only exist on newer jax —
-    # older releases are Auto-only, so falling back to the 2-argument
-    # form (or the raw Mesh constructor) is semantically identical.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(sizes), tuple(names),
-                (axis_type.Auto,) * len(names), devices=devices,
-            )
-        except TypeError:  # make_mesh predates the axis_types parameter
-            pass
-    try:
-        return jax.make_mesh(tuple(sizes), tuple(names), devices=devices)
-    except (AttributeError, TypeError):  # very old jax: no make_mesh
-        import numpy as np
-
-        return Mesh(np.asarray(devices).reshape(tuple(sizes)), tuple(names))
+    # ambiguous ops like embedding gathers).
+    return jax.make_mesh(
+        tuple(sizes), tuple(names),
+        (jax.sharding.AxisType.Auto,) * len(names), devices=devices,
+    )
 
 
 def batch_sharding(mesh: Mesh, rank: int, axis: Optional[str] = None) -> NamedSharding:

@@ -301,11 +301,11 @@ _FUSED_CACHE_MAX = 64
 
 def clear_fused_cache() -> None:
     """Drop every cached fused Program. ``ops.segment.disable_pallas``
-    calls this when the pallas kill-switch trips: per-block aggregate
-    epilogues embed ``segment_sum``'s pallas-vs-XLA branch at TRACE
-    time, so a program traced while pallas was enabled would keep
-    failing from the cache forever — re-tracing after the switch picks
-    the XLA scatter and the fused path recovers."""
+    calls this when the manual pallas switch is thrown: per-block
+    aggregate epilogues embed ``segment_sum``'s pallas-vs-XLA branch at
+    TRACE time, so a program traced while pallas was enabled would keep
+    replaying the kernel from the cache — re-tracing after the switch
+    picks the XLA scatter."""
     with _CACHE_LOCK:
         _FUSED_CACHE.clear()
 

@@ -277,10 +277,7 @@ class Program:
                 compiled = None
         if compiled is None:
             compiled = jax.jit(self.fn).lower(abstract).compile()
-        costs = compiled.cost_analysis()
-        if isinstance(costs, (list, tuple)):  # older jax returns [dict]
-            costs = costs[0] if costs else {}
-        cache[probe] = dict(costs or {})
+        cache[probe] = dict(compiled.cost_analysis() or {})
         return dict(cache[probe])
 
     def flops_per_row(self, probe: int = 8) -> float:

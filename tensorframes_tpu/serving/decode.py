@@ -313,41 +313,15 @@ class DecodeEngine:
         self._join_counter = 0
 
     def _run_step(self, *args):
-        """Dispatch one batched decode step, honoring the pallas
-        recovery contract: a Mosaic kernel-compile failure trips the
-        process-wide kill-switch (fused-cache invalidation included),
-        rebuilds the step on the XLA gather chain, and retries — a
-        custom kernel must never take down the engine."""
+        """Dispatch one batched decode step on the lowering chosen at
+        engine build, and record its wall and kernel dispatch. A
+        failure raises — the step is never rebuilt on another
+        lowering."""
         from .. import kernels as _kernels
         from ..plan.lower import observe_strategy_wall
 
         t_step = time.perf_counter()
-        try:
-            out = self._step(*args)
-        except Exception as e:
-            from ..models import generation as gen
-            from ..ops import segment as _segment
-            from ..ops.executor import aot_jit
-
-            if (
-                self._attn_kernel is None
-                or not _segment.pallas_enabled()
-                or "Mosaic" not in str(e)
-            ):
-                raise
-            _segment.disable_pallas(
-                f"{type(e).__name__} in decode-attention kernel"
-            )
-            self._attn_kernel = None
-            self._step = aot_jit(
-                gen.paged_decode_step_fn(
-                    self.cfg, self.config.page_size,
-                    self._pool.max_pages_per_seq, attn_kernel=None,
-                ),
-                label=f"decode.step[{self.name}]",
-            )
-            t_step = time.perf_counter()  # rebuilt step: time XLA only
-            out = self._step(*args)
+        out = self._step(*args)
         observe_strategy_wall(
             "decode_attention",
             "pallas_decode_attn" if self._attn_kernel is not None

@@ -12,8 +12,8 @@ Everything here is composition, not invention — the parts all exist:
   servers, so a death restarts exactly ONE replica while the survivors
   keep taking traffic — that is what keeps p99 bounded through a
   ``kill -9``.
-* **the warm store** (PR 5/10): every replica shares one
-  ``TFTPU_COMPILE_CACHE``. The first replica's warmup publishes each
+* **the warm store** (PR 5/10): every replica shares one compile
+  cache directory (``JAX_COMPILATION_CACHE_DIR``). The first replica's warmup publishes each
   bucket-ladder executable once; every later — and every RESTARTED —
   replica's warmup is pure store hits: **zero XLA compiles**, asserted
   over the restarted replica's healthz process counters
@@ -51,7 +51,7 @@ import threading
 import time
 from typing import Callable, Dict, Optional, Sequence, Union
 
-from ..config import get_config
+from ..config import get_config, resolve_compile_cache_dir
 from ..observability import context as _context
 from ..observability import flight as _flight
 from ..utils import get_logger
@@ -81,7 +81,9 @@ class ServingFleet:
     fleet owns the environment contract: each rank gets the PR 8 fleet
     identity (``TFTPU_RUN_ID``/``TFTPU_PROCESS_INDEX``/
     ``TFTPU_FLEET_DIR``/``TFTPU_FLEET_ATTEMPT``/``TFTPU_FLIGHT_DIR``)
-    plus ``TFTPU_COMPILE_CACHE`` pointing at ONE shared store, so a
+    plus ``JAX_COMPILATION_CACHE_DIR`` pointing at ONE shared cache
+    (``compile_cache``, default: the package resolver's directory —
+    never a temporary one, the path is part of the cache key), so a
     restarted replica warms with zero XLA compiles.
 
     Context-manager friendly::
@@ -123,8 +125,8 @@ class ServingFleet:
         self.rendezvous_dir = rendezvous_dir or tempfile.mkdtemp(
             prefix="tftpu-serving-fleet-"
         )
-        self.compile_cache = compile_cache or os.path.join(
-            self.rendezvous_dir, "store"
+        self.compile_cache = compile_cache or resolve_compile_cache_dir(
+            entry_point=True
         )
         self.max_restarts = int(max_restarts)
         self.heartbeat_timeout_s = (
@@ -248,7 +250,7 @@ class ServingFleet:
         e["TFTPU_FLEET_DIR"] = self.rendezvous_dir
         e["TFTPU_NUM_PROCESSES"] = str(self.num_replicas)
         e["TFTPU_FLEET_ATTEMPT"] = str(attempt)
-        e["TFTPU_COMPILE_CACHE"] = self.compile_cache
+        e["JAX_COMPILATION_CACHE_DIR"] = self.compile_cache
         if self._flight_explicit:
             e["TFTPU_FLIGHT_DIR"] = self.flight_dir
         else:

@@ -21,7 +21,7 @@ pieces the fleet layer above needs:
   a SIGTERM handler that drains instead of dropping in-flight work.
 
 The shared-store contract rides the environment: the fleet arms
-``TFTPU_COMPILE_CACHE`` for every replica, so the first replica's
+``JAX_COMPILATION_CACHE_DIR`` for every replica, so the first replica's
 warmup publishes each ladder executable once and every later (or
 RESTARTED) replica's warmup is pure store hits — **zero XLA compiles**,
 the property the fleet asserts over this replica's healthz process
@@ -274,6 +274,11 @@ def main(argv=None) -> int:
     if not args.demo:
         parser.error("only --demo is runnable standalone; real apps "
                      "call serve_replica(server) from their own worker")
+    # an entry point that runs on the chip: place the compile cache
+    # (the fleet's environment, else the checkout's fixed directory)
+    from ..config import use_compile_cache
+
+    use_compile_cache(entry_point=True)
     stack = contextlib.ExitStack()
     kill_after = int(os.environ.get("TFTPU_SERVING_CHAOS_KILL_AFTER", 0))
     kill_rank = int(os.environ.get("TFTPU_SERVING_CHAOS_KILL_RANK", 1))

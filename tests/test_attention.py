@@ -271,3 +271,48 @@ def test_transformer_ulysses_impl():
         np.asarray(hs, np.float32), np.asarray(want, np.float32),
         rtol=3e-2, atol=3e-2,  # bf16 activations
     )
+
+
+# -- flash dispatch: a static choice, never a caught failure (PR 21) ---------
+
+def test_flash_eligible_is_a_static_shape_predicate():
+    def mk(seq, d, dtype=jnp.float32):
+        return jnp.zeros((1, 2, seq, d), dtype)
+
+    assert att.flash_eligible(mk(256, 64), mk(256, 64), mk(256, 64))
+    assert att.flash_eligible(*(mk(128, 128, jnp.bfloat16),) * 3)
+    assert att.flash_eligible(mk(128, 256), mk(128, 256), mk(128, 256))
+    # seq not a whole number of 128 blocks / head_dim past 128 and not a
+    # multiple of it / mixed or unsupported dtypes
+    assert not att.flash_eligible(mk(200, 64), mk(200, 64), mk(200, 64))
+    assert not att.flash_eligible(mk(128, 64), mk(100, 64), mk(100, 64))
+    assert not att.flash_eligible(mk(128, 192), mk(128, 192), mk(128, 192))
+    assert not att.flash_eligible(
+        mk(128, 64), mk(128, 64, jnp.bfloat16), mk(128, 64))
+    assert not att.flash_eligible(*(mk(128, 64, jnp.float64),) * 3)
+
+
+def test_flash_on_cpu_is_blockwise():
+    q, k, v = _qkv(s=128)
+    np.testing.assert_array_equal(
+        np.asarray(att.flash_attention(q, k, v, causal=True)),
+        np.asarray(att.blockwise_attention(q, k, v, causal=True)),
+    )
+
+
+def test_flash_kernel_failure_surfaces_on_tpu(monkeypatch):
+    """On a TPU an eligible call runs the pallas kernel or raises — no
+    canary, no per-call except, no drop to blockwise."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as upstream
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel (test)")
+
+    monkeypatch.setattr(att, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(upstream, "flash_attention", boom)
+    q = jnp.zeros((1, 2, 128, 64), jnp.float32)
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        att.flash_attention(q, q, q)
+    # an ineligible shape is blockwise by choice, not by rescue
+    q = jnp.zeros((1, 2, 100, 64), jnp.float32)
+    assert att.flash_attention(q, q, q).shape == q.shape

@@ -26,8 +26,25 @@ def test_dryrun_multichip_8():
     graft.dryrun_multichip(8)
 
 
-def test_dryrun_multichip_1():
+def test_dryrun_multichip_1(monkeypatch):
+    """Enough devices exist (conftest provides 8): the dry run uses
+    them and touches neither ``jax_platforms`` nor ``XLA_FLAGS``."""
+    import os
+
+    import jax
+
+    touched = []
+    real = jax.config.update
+
+    def spy(name, value):
+        touched.append(name)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    flags = os.environ.get("XLA_FLAGS", "")
     graft.dryrun_multichip(1)
+    assert "jax_platforms" not in touched
+    assert os.environ.get("XLA_FLAGS", "") == flags
 
 
 def test_dryrun_multichip_16_subprocess():

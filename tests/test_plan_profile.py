@@ -30,6 +30,7 @@ import pytest
 import tensorframes_tpu as tfs
 from tensorframes_tpu.observability import cli, profile
 from tensorframes_tpu.observability.metrics import REGISTRY
+from tensorframes_tpu import kernels
 from tensorframes_tpu.plan import rules
 from tensorframes_tpu.plan import stats as plan_stats
 
@@ -279,7 +280,7 @@ def test_decide_epilogue_flips_only_when_exact():
 
 
 def test_decide_decode_attention_flip_and_force_pin(monkeypatch):
-    monkeypatch.setattr(rules, "_kernel_backend_ok", lambda: True)
+    monkeypatch.setattr(kernels, "selectable", lambda kernel: True)
     monkeypatch.setattr(rules, "_force_pins_kernels", lambda: False)
     walls = {
         "pallas_decode_attn": {"ewma_s": 0.02, "n": 4},

@@ -247,8 +247,8 @@ def test_pallas_int8_matmul_matches_structural_fusion():
     """Round 5 (VERDICT r4 #3 'consider'): the pallas in-kernel-dequant
     matmul must agree with quantize.matmul's structural fusion across
     shapes (incl. non-tile-multiple dims and 3-D activations), run in
-    interpret mode on CPU. The real-TPU speed adjudication lives in
-    dev/tpu_smoke.py."""
+    interpret mode on CPU. ``chip_smoke.py`` compiles it on the chip
+    and reports the outcome; its speed has no cell yet."""
     import jax.numpy as jnp
 
     from tensorframes_tpu.ops import quantize as qz
