@@ -64,7 +64,7 @@ def _time_rows_per_sec(run_once, n_rows: int, iters: int) -> float:
 
 def _record_mfu(name: str, program, rows_per_sec: float, n_rows: int) -> None:
     """Attach XLA-cost-model FLOPs to a profiling span so report() prints
-    achieved GFLOP/s (and MFU when config.peak_flops is set). Best-iter
+    achieved GFLOP/s. Best-iter
     seconds reconstructed from the returned throughput."""
     try:
         from tensorframes_tpu.utils import profiling
@@ -2177,20 +2177,6 @@ def main():
         sys.exit(2)
 
     n_chips = max(1, len(jax.devices()))
-    # per-chip bf16 peak FLOP/s by device kind → MFU column in the report
-    # (public spec sheets; MFU vs bf16 peak is the scaling-book convention)
-    from tensorframes_tpu import configure
-
-    kind = getattr(jax.devices()[0], "device_kind", "").lower()
-    for pat, peak in (
-        ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
-        ("v4", 275e12), ("v6e", 918e12), ("v6 lite", 918e12),
-    ):
-        if pat in kind:
-            # benched frames shard over every chip, so the recorded FLOPs
-            # are fleet-aggregate — compare against the fleet peak
-            configure(peak_flops=peak * n_chips)
-            break
     logreg_rps = _try("logreg", _bench_map_blocks_logreg, 0.0,
                       metric_keys=("logreg_map_blocks_rows_per_sec",))
     add3_rps = _try("add3", _bench_add3, 0.0,

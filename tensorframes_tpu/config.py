@@ -108,9 +108,6 @@ class Config:
     # backends that implement donation (not XLA:CPU); device-resident
     # frame columns are never donated.
     donate_inputs: bool = _env_bool("TFTPU_DONATE_INPUTS", True)
-    # Per-chip peak FLOP/s for MFU accounting in profiling.report()
-    # (0 = unknown; bench.py sets it from the detected device kind).
-    peak_flops: float = float(os.environ.get("TFTPU_PEAK_FLOPS", 0) or 0)
     # Persistent executable cache directory: first TPU compiles of
     # the big model programs take 20-40s; with a cache dir set, later
     # processes deserialize the executable instead of recompiling

@@ -465,12 +465,17 @@ def save_frame_sharded(frame, path: str) -> str:
     part = os.path.join(path, f"part-{pid}")
     os.makedirs(path, exist_ok=True)
     save_frame(TensorFrame([local_block], Schema(infos)), part)
-    # every process writes the identical meta (benign race) so a reload
-    # under a different process count fails loudly instead of dropping parts
+    # every process writes the identical meta so a reload under a
+    # different process count fails loudly instead of dropping parts;
+    # renamed into place, because a peer that is already loading must
+    # never read the file truncated (it would exit and hang the rest in
+    # their next collective)
     import json
 
-    with open(os.path.join(path, "parts.json"), "w") as f:
+    meta = os.path.join(path, "parts.json")
+    with open(f"{meta}.{pid}.tmp", "w") as f:
         json.dump({"num_parts": jax.process_count()}, f)
+    os.replace(f"{meta}.{pid}.tmp", meta)
     return part
 
 

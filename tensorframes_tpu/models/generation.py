@@ -342,8 +342,9 @@ def paged_prefill_fn(cfg: TransformerConfig, page_size: int,
         (T,) = tokens.shape
         h, nh, hd = cfg.hidden, cfg.num_heads, cfg.head_dim
         tpos = jnp.arange(T)
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-        x = x + params["embed"]["pos"][tpos].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+            x = x + params["embed"]["pos"][tpos].astype(cfg.dtype)
         valid = tpos < length                       # real prompt slots
         # per-position pool coordinates; masked positions → null page 0
         pg = jnp.where(valid, table[jnp.minimum(tpos // page_size,
@@ -353,49 +354,55 @@ def paged_prefill_fn(cfg: TransformerConfig, page_size: int,
         neg = jnp.asarray(-1e30, jnp.float32)
         pool = dict(pool)
         for li, p in enumerate(params["layers"]):
-            y = _layer_norm(x, **p["ln1"])
-            qkv = _mm(y, p["attn"]["qkv"]).reshape(T, 3, nh, hd)
-            q = qkv[:, 0].transpose(1, 0, 2)        # [nh, T, hd]
-            k = qkv[:, 1].transpose(1, 0, 2)
-            v = qkv[:, 2].transpose(1, 0, 2)
-            kq, ks = _quantize_slots(k[None])       # [1, nh, T, hd]
-            vq, vs = _quantize_slots(v[None])
-            kq, ks, vq, vs = kq[0], ks[0], vq[0], vs[0]
-            # ONE scatter per tensor per layer: advanced indices at the
-            # page and offset axes broadcast to [T, nh, ...] views
-            pool["k"] = pool["k"].at[pg, li, :, off].set(
-                kq.transpose(1, 0, 2)
-            )
-            pool["v"] = pool["v"].at[pg, li, :, off].set(
-                vq.transpose(1, 0, 2)
-            )
-            pool["k_scale"] = pool["k_scale"].at[pg, li, :, off].set(
-                ks.transpose(1, 0, 2)
-            )
-            pool["v_scale"] = pool["v_scale"].at[pg, li, :, off].set(
-                vs.transpose(1, 0, 2)
-            )
-            # attend within the chunk over the quantized k/v — the same
-            # dequantize-commutes formulation as _forward_cached, so
-            # prefill sees exactly what the pool now holds
-            kd = kq.astype(cfg.dtype)
-            scores = jnp.einsum(
-                "ntd,nsd->nts", q, kd,
-                preferred_element_type=jnp.float32,
-            ) / float(np.sqrt(hd))
-            scores = scores * ks[..., 0][:, None, :]
-            scores = jnp.where(causal[None], scores, neg)
-            w = jax.nn.softmax(scores, axis=-1)
-            w = (w * vs[..., 0][:, None, :]).astype(cfg.dtype)
-            ctx = jnp.einsum("nts,nsd->ntd", w, vq.astype(cfg.dtype))
-            ctx = ctx.transpose(1, 0, 2).reshape(T, h)
-            x = x + _mm(ctx, p["attn"]["out"])
-            x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
-        hs = _layer_norm(x, **params["final_ln"])
-        last = jnp.take(hs, length - 1, axis=0)
-        first = jnp.argmax(
-            _logits(cfg, params, last), axis=-1
-        ).astype(jnp.int32)
+            with jax.named_scope(f"layer_{li}/attn"):
+                y = _layer_norm(x, **p["ln1"])
+                qkv = _mm(y, p["attn"]["qkv"]).reshape(T, 3, nh, hd)
+                q = qkv[:, 0].transpose(1, 0, 2)        # [nh, T, hd]
+                k = qkv[:, 1].transpose(1, 0, 2)
+                v = qkv[:, 2].transpose(1, 0, 2)
+            with jax.named_scope(f"layer_{li}/kv_write"):
+                kq, ks = _quantize_slots(k[None])       # [1, nh, T, hd]
+                vq, vs = _quantize_slots(v[None])
+                kq, ks, vq, vs = kq[0], ks[0], vq[0], vs[0]
+                # ONE scatter per tensor per layer: advanced indices at
+                # the page and offset axes broadcast to [T, nh, ...]
+                pool["k"] = pool["k"].at[pg, li, :, off].set(
+                    kq.transpose(1, 0, 2)
+                )
+                pool["v"] = pool["v"].at[pg, li, :, off].set(
+                    vq.transpose(1, 0, 2)
+                )
+                pool["k_scale"] = pool["k_scale"].at[pg, li, :, off].set(
+                    ks.transpose(1, 0, 2)
+                )
+                pool["v_scale"] = pool["v_scale"].at[pg, li, :, off].set(
+                    vs.transpose(1, 0, 2)
+                )
+            with jax.named_scope(f"layer_{li}/attn"):
+                # attend within the chunk over the quantized k/v — the
+                # same dequantize-commutes formulation as
+                # _forward_cached, so prefill sees exactly what the pool
+                # now holds
+                kd = kq.astype(cfg.dtype)
+                scores = jnp.einsum(
+                    "ntd,nsd->nts", q, kd,
+                    preferred_element_type=jnp.float32,
+                ) / float(np.sqrt(hd))
+                scores = scores * ks[..., 0][:, None, :]
+                scores = jnp.where(causal[None], scores, neg)
+                w = jax.nn.softmax(scores, axis=-1)
+                w = (w * vs[..., 0][:, None, :]).astype(cfg.dtype)
+                ctx = jnp.einsum("nts,nsd->ntd", w, vq.astype(cfg.dtype))
+                ctx = ctx.transpose(1, 0, 2).reshape(T, h)
+                x = x + _mm(ctx, p["attn"]["out"])
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
+        with jax.named_scope("head"):
+            hs = _layer_norm(x, **params["final_ln"])
+            last = jnp.take(hs, length - 1, axis=0)
+            first = jnp.argmax(
+                _logits(cfg, params, last), axis=-1
+            ).astype(jnp.int32)
         return pool, first
 
     return prefill
@@ -431,8 +438,9 @@ def paged_suffix_prefill_fn(cfg: TransformerConfig, page_size: int,
         emb_pos = jnp.minimum(
             seqpos, params["embed"]["pos"].shape[0] - 1
         )
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-        x = x + params["embed"]["pos"][emb_pos].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+            x = x + params["embed"]["pos"][emb_pos].astype(cfg.dtype)
         pg = jnp.where(
             valid,
             table[jnp.minimum(seqpos // page_size, max_pages - 1)], 0,
@@ -445,33 +453,41 @@ def paged_suffix_prefill_fn(cfg: TransformerConfig, page_size: int,
         pos_r = jnp.where(valid, seqpos, 0)
         pool = dict(pool)
         for li, p in enumerate(params["layers"]):
-            y = _layer_norm(x, **p["ln1"])
-            qkv = _mm(y, p["attn"]["qkv"]).reshape(T, 3, nh, hd)
-            q = qkv[:, 0]                       # [T, nh, hd]
-            k = qkv[:, 1]
-            v = qkv[:, 2]
-            kq, ks = _quantize_slots(k[:, :, None, :])
-            vq, vs = _quantize_slots(v[:, :, None, :])
-            kq, ks = kq[:, :, 0], ks[:, :, 0]
-            vq, vs = vq[:, :, 0], vs[:, :, 0]
-            # write first, then gather-attend — row i sees positions
-            # 0..start+i including its own token, the decode-step order
-            pool["k"] = pool["k"].at[pg, li, :, off].set(kq)
-            pool["v"] = pool["v"].at[pg, li, :, off].set(vq)
-            pool["k_scale"] = pool["k_scale"].at[pg, li, :, off].set(ks)
-            pool["v_scale"] = pool["v_scale"].at[pg, li, :, off].set(vs)
-            ctx = paged_attention_reference(
-                q, pool["k"], pool["v"],
-                pool["k_scale"], pool["v_scale"],
-                li, tables_r, pos_r,
-            ).reshape(T, h)
-            x = x + _mm(ctx, p["attn"]["out"])
-            x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
-        hs = _layer_norm(x, **params["final_ln"])
-        last = jnp.take(hs, length - 1, axis=0)
-        first = jnp.argmax(
-            _logits(cfg, params, last), axis=-1
-        ).astype(jnp.int32)
+            with jax.named_scope(f"layer_{li}/attn"):
+                y = _layer_norm(x, **p["ln1"])
+                qkv = _mm(y, p["attn"]["qkv"]).reshape(T, 3, nh, hd)
+                q = qkv[:, 0]                       # [T, nh, hd]
+                k = qkv[:, 1]
+                v = qkv[:, 2]
+            with jax.named_scope(f"layer_{li}/kv_write"):
+                kq, ks = _quantize_slots(k[:, :, None, :])
+                vq, vs = _quantize_slots(v[:, :, None, :])
+                kq, ks = kq[:, :, 0], ks[:, :, 0]
+                vq, vs = vq[:, :, 0], vs[:, :, 0]
+                # write first, then gather-attend — row i sees positions
+                # 0..start+i including its own token, the decode-step
+                # order
+                pool["k"] = pool["k"].at[pg, li, :, off].set(kq)
+                pool["v"] = pool["v"].at[pg, li, :, off].set(vq)
+                pool["k_scale"] = pool["k_scale"].at[
+                    pg, li, :, off].set(ks)
+                pool["v_scale"] = pool["v_scale"].at[
+                    pg, li, :, off].set(vs)
+            with jax.named_scope(f"layer_{li}/attn"):
+                ctx = paged_attention_reference(
+                    q, pool["k"], pool["v"],
+                    pool["k_scale"], pool["v_scale"],
+                    li, tables_r, pos_r,
+                ).reshape(T, h)
+                x = x + _mm(ctx, p["attn"]["out"])
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
+        with jax.named_scope("head"):
+            hs = _layer_norm(x, **params["final_ln"])
+            last = jnp.take(hs, length - 1, axis=0)
+            first = jnp.argmax(
+                _logits(cfg, params, last), axis=-1
+            ).astype(jnp.int32)
         return pool, first
 
     return suffix_prefill
@@ -545,8 +561,9 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
 
         (S,) = tokens.shape
         h, nh, hd = cfg.hidden, cfg.num_heads, cfg.head_dim
-        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-        x = x + params["embed"]["pos"][pos].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+            x = x + params["embed"]["pos"][pos].astype(cfg.dtype)
         wpg = jnp.take_along_axis(
             tables, jnp.minimum(pos // page_size, max_pages - 1)[:, None],
             axis=1,
@@ -554,19 +571,25 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
         woff = pos % page_size
         pool = dict(pool)
         for li, p in enumerate(params["layers"]):
-            y = _layer_norm(x, **p["ln1"])
-            qkv = _mm(y, p["attn"]["qkv"]).reshape(S, 3, nh, hd)
-            q = qkv[:, 0]                           # [S, nh, hd]
-            k = qkv[:, 1]
-            v = qkv[:, 2]
-            kq, ks = _quantize_slots(k[:, :, None, :])  # [S, nh, 1, hd]
-            vq, vs = _quantize_slots(v[:, :, None, :])
-            kq, ks = kq[:, :, 0], ks[:, :, 0]       # [S, nh, hd/1]
-            vq, vs = vq[:, :, 0], vs[:, :, 0]
-            pool["k"] = pool["k"].at[wpg, li, :, woff].set(kq)
-            pool["v"] = pool["v"].at[wpg, li, :, woff].set(vq)
-            pool["k_scale"] = pool["k_scale"].at[wpg, li, :, woff].set(ks)
-            pool["v_scale"] = pool["v_scale"].at[wpg, li, :, woff].set(vs)
+            # scopes are trace-time names on the ops (layer_<i>/attn,
+            # /kv_write, /mlp): what a profile calls them, no run cost
+            with jax.named_scope(f"layer_{li}/attn"):
+                y = _layer_norm(x, **p["ln1"])
+                qkv = _mm(y, p["attn"]["qkv"]).reshape(S, 3, nh, hd)
+                q = qkv[:, 0]                           # [S, nh, hd]
+                k = qkv[:, 1]
+                v = qkv[:, 2]
+            with jax.named_scope(f"layer_{li}/kv_write"):
+                kq, ks = _quantize_slots(k[:, :, None, :])  # [S, nh, 1, hd]
+                vq, vs = _quantize_slots(v[:, :, None, :])
+                kq, ks = kq[:, :, 0], ks[:, :, 0]       # [S, nh, hd/1]
+                vq, vs = vq[:, :, 0], vs[:, :, 0]
+                pool["k"] = pool["k"].at[wpg, li, :, woff].set(kq)
+                pool["v"] = pool["v"].at[wpg, li, :, woff].set(vq)
+                pool["k_scale"] = pool["k_scale"].at[
+                    wpg, li, :, woff].set(ks)
+                pool["v_scale"] = pool["v_scale"].at[
+                    wpg, li, :, woff].set(vs)
             if attn_kernel == "pallas":
                 # fused paged-attention kernel: the page gather, int8
                 # dequant, and masked softmax-attend run in ONE pallas
@@ -576,11 +599,12 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
                     paged_decode_attention,
                 )
 
-                ctx = paged_decode_attention(
-                    q, pool["k"], pool["v"],
-                    pool["k_scale"], pool["v_scale"],
-                    li, tables, pos,
-                ).reshape(S, h)
+                with jax.named_scope(f"layer_{li}/attn"):
+                    ctx = paged_decode_attention(
+                        q, pool["k"], pool["v"],
+                        pool["k_scale"], pool["v_scale"],
+                        li, tables, pos,
+                    ).reshape(S, h)
             else:
                 # paged KV gather: each slot pulls its own pages (write
                 # above first, so slot j attends its own current token).
@@ -591,17 +615,21 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
                     paged_attention_reference,
                 )
 
-                ctx = paged_attention_reference(
-                    q, pool["k"], pool["v"],
-                    pool["k_scale"], pool["v_scale"],
-                    li, tables, pos,
-                ).reshape(S, h)
-            x = x + _mm(ctx, p["attn"]["out"])
-            x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
-        hs = _layer_norm(x, **params["final_ln"])
-        nxt = jnp.argmax(
-            _logits(cfg, params, hs), axis=-1
-        ).astype(jnp.int32)
+                with jax.named_scope(f"layer_{li}/attn"):
+                    ctx = paged_attention_reference(
+                        q, pool["k"], pool["v"],
+                        pool["k_scale"], pool["v_scale"],
+                        li, tables, pos,
+                    ).reshape(S, h)
+            with jax.named_scope(f"layer_{li}/attn"):
+                x = x + _mm(ctx, p["attn"]["out"])
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
+        with jax.named_scope("head"):
+            hs = _layer_norm(x, **params["final_ln"])
+            nxt = jnp.argmax(
+                _logits(cfg, params, hs), axis=-1
+            ).astype(jnp.int32)
         return pool, nxt
 
     return step

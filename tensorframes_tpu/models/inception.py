@@ -282,28 +282,40 @@ def _block_e(p, x):
 
 def forward(cfg: InceptionConfig, params: Dict, images: jnp.ndarray) -> jnp.ndarray:
     """images [n, H, W, 3] float → logits [n, num_classes] (float32)."""
-    x = images.astype(jnp.dtype(cfg.compute_dtype))
-    s = params["stem"]
-    x = _conv2d(s["c1"], x, stride=2, padding="VALID")
-    x = _conv2d(s["c2"], x, padding="VALID")
-    x = _conv2d(s["c3"], x)
-    x = _maxpool(x)
-    x = _conv2d(s["c4"], x)
-    x = _conv2d(s["c5"], x, padding="VALID")
-    x = _maxpool(x)
-    for i in range(3):
-        x = _block_a(params[f"mixed_a{i}"], x)
-    x = _block_b(params["mixed_b"], x)
-    for i in range(4):
-        x = _block_c(params[f"mixed_c{i}"], x)
-    x = _block_d(params["mixed_d"], x)
-    for i in range(2):
-        x = _block_e(params[f"mixed_e{i}"], x)
-    x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))  # global average pool
-    from ..ops.quantize import asarray as _qw
+    # scopes are trace-time names on the ops, after the paper's modules
+    # (stem, mixed_5b … mixed_7c, logits): what a profile calls them,
+    # no run cost
+    with jax.named_scope("stem"):
+        x = images.astype(jnp.dtype(cfg.compute_dtype))
+        s = params["stem"]
+        x = _conv2d(s["c1"], x, stride=2, padding="VALID")
+        x = _conv2d(s["c2"], x, padding="VALID")
+        x = _conv2d(s["c3"], x)
+        x = _maxpool(x)
+        x = _conv2d(s["c4"], x)
+        x = _conv2d(s["c5"], x, padding="VALID")
+        x = _maxpool(x)
+    for i, scope in enumerate(("mixed_5b", "mixed_5c", "mixed_5d")):
+        with jax.named_scope(scope):
+            x = _block_a(params[f"mixed_a{i}"], x)
+    with jax.named_scope("mixed_6a"):
+        x = _block_b(params["mixed_b"], x)
+    for i, scope in enumerate(
+            ("mixed_6b", "mixed_6c", "mixed_6d", "mixed_6e")):
+        with jax.named_scope(scope):
+            x = _block_c(params[f"mixed_c{i}"], x)
+    with jax.named_scope("mixed_7a"):
+        x = _block_d(params["mixed_d"], x)
+    for i, scope in enumerate(("mixed_7b", "mixed_7c")):
+        with jax.named_scope(scope):
+            x = _block_e(params[f"mixed_e{i}"], x)
+    with jax.named_scope("logits"):
+        # global average pool
+        x = jnp.mean(x.astype(jnp.float32), axis=(1, 2))
+        from ..ops.quantize import asarray as _qw
 
-    fc = params["fc"]
-    return x @ _qw(fc["w"], jnp.float32) + fc["b"].astype(jnp.float32)
+        fc = params["fc"]
+        return x @ _qw(fc["w"], jnp.float32) + fc["b"].astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

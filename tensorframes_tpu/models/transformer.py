@@ -227,21 +227,29 @@ def forward(
     required for ``attention_impl='ring'`` (sequence parallelism over its
     'sp' axis).
     """
-    x = params["embed"]["tok"][tokens].astype(cfg.dtype)
-    s = tokens.shape[1]
-    x = x + params["embed"]["pos"][:s].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+        s = tokens.shape[1]
+        x = x + params["embed"]["pos"][:s].astype(cfg.dtype)
 
     def layer(x, p):
-        x = x + _attention(cfg, p["attn"], _layer_norm(x, **p["ln1"]), mask, mesh)
-        return x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
+        # trace-time names on the ops (layer_<i>/attn, /mlp): what a
+        # profile calls them, no run cost
+        with jax.named_scope("attn"):
+            x = x + _attention(
+                cfg, p["attn"], _layer_norm(x, **p["ln1"]), mask, mesh)
+        with jax.named_scope("mlp"):
+            return x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
 
     if cfg.remat:
         # recompute each layer's activations in the backward pass instead
         # of keeping them resident: O(1) layers of activation HBM
         layer = jax.checkpoint(layer)
-    for p in params["layers"]:
-        x = layer(x, p)
-    return _layer_norm(x, **params["final_ln"])
+    for li, p in enumerate(params["layers"]):
+        with jax.named_scope(f"layer_{li}"):
+            x = layer(x, p)
+    with jax.named_scope("head"):
+        return _layer_norm(x, **params["final_ln"])
 
 
 def embed_program(cfg: TransformerConfig, params: Dict):

@@ -2,14 +2,21 @@
 
 The aggregate side of observability lives in ``utils/profiling.py``
 (per-span totals) and ``observability/metrics.py`` (counters/gauges/
-histograms). This module is the **timeline** side: begin/end spans with
-natural nesting, instant events, monotonic microsecond timestamps, and
-real thread ids, exported in the Chrome ``trace_event`` JSON format that
-Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` open
+histograms). This module is the **timeline** side: complete spans,
+async request events, instant events, monotonic microsecond timestamps
+and real thread ids, exported in the Chrome ``trace_event`` JSON format
+that Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` open
 directly. It layers ON TOP of ``utils/profiling.py`` — when tracing is
 enabled, every ``profiling.span`` (the five verbs, checkpoint IO, …)
 also lands on the timeline; disabling tracing costs one attribute check
 per span.
+
+The rule every instrumented site keeps: **on any one thread, complete
+("X") spans nest properly or do not overlap**; whatever the host does
+between two device programs on the hot paths lies under a leaf span;
+anything that overlaps freely on a thread — a request's life, of which
+dozens are open at once — is an async event (:meth:`Tracer.emit_async`,
+a "b"/"e" pair with its own id), never an "X" span.
 
 Usage::
 
@@ -25,12 +32,15 @@ The buffer is bounded (``max_events``): past the cap new events are
 dropped and counted (``TRACER.dropped``) — a week-long run must not eat
 the host's RAM. Spans are recorded as complete ("X"-phase) events at
 span END, so nesting is reconstructed by time containment per thread;
-a span that never exits (crash mid-body) leaves no partial event.
+a span that never exits (crash mid-body) leaves no partial event. An
+async pair is recorded whole at its end too: a full ring drops both
+halves (counted), never one.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
@@ -113,6 +123,7 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._named_threads: set = set()
+        self._async_ids = itertools.count(1)
         self.dropped = 0
         self.enabled = False
 
@@ -132,19 +143,23 @@ class Tracer:
 
     # -- recording ----------------------------------------------------------
 
-    def _append(self, ev: Dict[str, Any], tid: int) -> None:
+    def _append(self, ev: Dict[str, Any], tid: int,
+                end: Optional[Dict[str, Any]] = None) -> None:
+        """Append ``ev`` (and ``end``, the closing half of an async
+        pair: both land or neither does)."""
+        n = 1 if end is None else 2
         with self._lock:
             # the cap is hard: a full buffer drops the event (counted),
             # and thread_name metadata is only added when there is room
             # for it AND the event it annotates — no unbounded growth
             # from thread churn in a long run
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                _EVENTS_DROPPED.inc()
+            if len(self._events) + n > self.max_events:
+                self.dropped += n
+                _EVENTS_DROPPED.inc(n)
                 return
             if (
                 tid not in self._named_threads
-                and len(self._events) + 2 <= self.max_events
+                and len(self._events) + n + 1 <= self.max_events
             ):
                 self._named_threads.add(tid)
                 self._events.append({
@@ -155,6 +170,8 @@ class Tracer:
                     "args": {"name": threading.current_thread().name},
                 })
             self._events.append(ev)
+            if end is not None:
+                self._events.append(end)
 
     def emit_complete(
         self,
@@ -182,6 +199,39 @@ class Tracer:
         if args:
             ev["args"] = _clean_args(args)
         self._append(ev, tid)
+
+    def emit_async(
+        self,
+        name: str,
+        id: Optional[str],
+        t0_perf: float,
+        dur_s: float,
+        args: Optional[Dict[str, Any]] = None,
+        cat: str = "tftpu",
+    ) -> None:
+        """Record a Chrome async pair ("b" then "e", one ``id``) from a
+        perf_counter start + a duration, on the clock of
+        :meth:`emit_complete`. For what overlaps freely on one thread —
+        request lifetimes — where "X" spans would break the nesting
+        rule. The args ride the "b" event. ``id`` joins the pair (and,
+        where it is a request id, the router's and the replica's events
+        of one request); ``None`` draws a process-local one."""
+        if not self.enabled:
+            return
+        tid = threading.get_ident()
+        pid = os.getpid()
+        if id is None:
+            id = f"{pid:x}.{next(self._async_ids)}"
+        begin: Dict[str, Any] = {
+            "ph": "b", "name": name, "cat": cat, "id": id,
+            "ts": _us(t0_perf), "pid": pid, "tid": tid,
+        }
+        if args:
+            begin["args"] = _clean_args(args)
+        self._append(begin, tid, end={
+            "ph": "e", "name": name, "cat": cat, "id": id,
+            "ts": _us(t0_perf + dur_s), "pid": pid, "tid": tid,
+        })
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "tftpu", **args: Any) -> Iterator[None]:

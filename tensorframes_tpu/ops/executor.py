@@ -311,12 +311,13 @@ def _store_meta(kind: str, form: str, donate: bool, inputs,
     return meta
 
 
-def _hoisted_for(fn, feeds: Dict[str, jnp.ndarray]):
+def _hoisted_for(fn, feeds: Dict[str, jnp.ndarray], name: str = "run"):
     """Build a :class:`HoistedProgram` (program.py — weights as runtime
     arguments, device-committed once) at these feeds' shapes — and
     placements: sharded feeds trace (and later lower) with their
     shardings attached, so the hoisted executable is specialized to the
-    same layout the dispatch will call it with."""
+    same layout the dispatch will call it with. ``name`` becomes the
+    XLA module's (``jit_<name>``)."""
     from ..program import HoistedProgram
 
     abstract = {}
@@ -327,7 +328,7 @@ def _hoisted_for(fn, feeds: Dict[str, jnp.ndarray]):
             if sh is not None
             else jax.ShapeDtypeStruct(np.shape(v), v.dtype)
         )
-    return HoistedProgram(fn, abstract)
+    return HoistedProgram(fn, abstract, name=name)
 
 
 class CompiledProgram:
@@ -387,11 +388,19 @@ class CompiledProgram:
         _JIT_MISSES.inc()
         return True
 
+    def module_name(self, kind: str) -> str:
+        """What the executable is called in a device trace, less jax's
+        ``jit_`` prefix: ``tftpu_<role>_<block|rows>``, the role being
+        the program's (``map``, ``fused_map``, ``map_reduce``,
+        ``reduce``, …; see ``Program.role``)."""
+        return (f"tftpu_{self.program.role}_"
+                f"{'block' if kind == 'block' else 'rows'}")
+
     def _entry(self, key: Tuple, fn, feeds):
         entry = self._hoisted.get(key)
         if entry is None:
             try:
-                entry = _hoisted_for(fn, feeds)
+                entry = _hoisted_for(fn, feeds, self.module_name(key[0]))
             except Exception as e:
                 # exotic programs (host callbacks, non-array consts) keep
                 # the plain closure-capture path
@@ -434,6 +443,8 @@ class CompiledProgram:
             return fingerprint_from_closed(
                 closed, avals, outs, kind=kind, donate=donate,
                 hoisted=hoisted, shardings=shardings,
+                # the name is baked into the stored executable
+                extra={"module": self.module_name(kind)},
             )
         except Exception as e:
             from ..compilecache.store import note_unfingerprintable
@@ -523,9 +534,15 @@ class CompiledProgram:
                 entry.consts, entry._flat_abstract
             ).compile()
         else:
+            fn = self._kind_fn(kind)
+
+            def plain(feeds):
+                return fn(feeds)
+
+            plain.__name__ = plain.__qualname__ = self.module_name(kind)
             jitted = (
-                jax.jit(self._kind_fn(kind), donate_argnums=(0,))
-                if donate else jax.jit(self._kind_fn(kind))
+                jax.jit(plain, donate_argnums=(0,))
+                if donate else jax.jit(plain)
             )
             compiled = jitted.lower(abstract).compile()
         _COMPILE_SECONDS.observe(trace_s + (time.perf_counter() - t1))
@@ -578,6 +595,8 @@ class CompiledProgram:
         return built[1]
 
     def _run(self, kind: str, feeds, to_numpy: bool, donate: bool):
+        tracing = _events.TRACER.enabled
+        t_in = time.perf_counter() if tracing else 0.0
         # flight-record identity of this dispatch BEFORE anything can
         # fail (fault injection fires at the fault_point below): a crash
         # postmortem must carry the dispatch that was in flight
@@ -679,14 +698,34 @@ class CompiledProgram:
             # trace+XLA-compile on every path now, and the fallback has
             # its own counter (lumping would resurrect the pre-unification
             # accounting caveat)
-        if _events.TRACER.enabled:
+        if tracing:
+            which = "block" if kind == "block" else "rows"
+            # entry to dispatch: the flight summary, the feeds'
+            # asarray, the keys and the executable's lookup or build
             _events.TRACER.emit_complete(
-                f"executor.run_{'block' if kind == 'block' else 'rows'}",
-                t0, dt, args={"compiled": fresh}, cat="executor",
+                "executor.prepare", t_in, t0 - t_in,
+                args={"kind": which, "compiled": fresh}, cat="executor",
+            )
+            # synced: deadline mode blocked on the result inside the
+            # span; otherwise it times the dispatch alone
+            _events.TRACER.emit_complete(
+                f"executor.run_{which}", t0, dt,
+                args={"compiled": fresh, "synced": bool(deadline)},
+                cat="executor",
             )
         if not to_numpy:
             return out  # stay in HBM: sharded frames chain without transfers
-        return {k: np.asarray(v) for k, v in out.items()}
+        host = {k: np.asarray(v) for k, v in out.items()}
+        if tracing:
+            # from the end of the run span: where an unsynced dispatch
+            # is waited for
+            t1 = t0 + dt
+            _events.TRACER.emit_complete(
+                "executor.fetch", t1, time.perf_counter() - t1,
+                args={"bytes": sum(v.nbytes for v in host.values())},
+                cat="executor",
+            )
+        return host
 
     def _fallback_call(self, kind: str, key: Tuple, feeds, donate: bool):
         """Last-resort lazy jax.jit dispatch, reachable ONLY when the

@@ -44,6 +44,7 @@ from ..frame import Block, GroupedData, TensorFrame, _block_num_rows
 from ..program import Program, TensorSpec, analyze_program, program_from_function
 from ..schema import ColumnInfo, Schema
 from ..shape import Shape, Unknown
+from ..observability import events as _events
 from ..utils import get_logger
 from ..utils import profiling
 from ..validation import (
@@ -307,7 +308,11 @@ def _normalize_program(
             for s in program.inputs
         ]
         program = Program(program.fn, demoted, fetch_order=program.fetch_order)
+    # analyze_program returns a copy: the role and seg_info below never
+    # land on the caller's Program
     program = analyze_program(program, hints=hints)
+    if reduce_mode:
+        program.role = "reduce"
     program.seg_info = seg_info  # survives Program reuse via compile_program
     return program, seg_info
 
@@ -569,7 +574,7 @@ def map_blocks(
         # dispatch); label those spans distinctly so report() rows/s is
         # honest — only the host path measures completed execution
         name = "map_blocks.dispatch" if sharded else "map_blocks"
-        profiling.record(name, time.perf_counter() - t0, n_total)
+        profiling.record(name, time.perf_counter() - t0, n_total, t0=t0)
         return out_blocks
 
     result = TensorFrame(None, schema, pending=compute)
@@ -968,7 +973,7 @@ def map_rows(
                 results[bi] = nb
                 off += nr
         name = "map_rows.dispatch" if parent.is_sharded else "map_rows"
-        profiling.record(name, time.perf_counter() - t0, n_total)
+        profiling.record(name, time.perf_counter() - t0, n_total, t0=t0)
         return results
 
     result = TensorFrame(None, schema, pending=compute)
@@ -1132,20 +1137,37 @@ def reduce_rows(
             partials.append({x: np.asarray(feeds[x][0]) for x in out_names})
         else:
             res = fold({x: jnp.asarray(feeds[x]) for x in out_names})
-            partials.append({x: np.asarray(res[x]) for x in out_names})
+            tracing = _events.TRACER.enabled
+            t_f = time.perf_counter() if tracing else 0.0
+            part = {x: np.asarray(res[x]) for x in out_names}
+            if tracing:
+                _events.TRACER.emit_complete(
+                    "plan.reduce.fetch", t_f, time.perf_counter() - t_f,
+                    args={"block": len(partials),
+                          "bytes": sum(v.nbytes for v in part.values())},
+                    cat="plan",
+                )
+            partials.append(part)
     if not partials:
         raise ValueError("reduce_rows on an empty frame")
     if len(partials) == 1:
         finals = partials[0]
     else:
+        tracing = _events.TRACER.enabled
+        t_c = time.perf_counter() if tracing else 0.0
         stacked = {
             x: jnp.asarray(np.stack([p[x] for p in partials])) for x in out_names
         }
         res = fold(stacked)
         finals = {x: np.asarray(res[x]) for x in out_names}
+        if tracing:
+            _events.TRACER.emit_complete(
+                "plan.reduce.combine", t_c, time.perf_counter() - t_c,
+                args={"partials": len(partials)}, cat="plan",
+            )
     profiling.record(
         "reduce_rows", time.perf_counter() - t0,
-        planned[1] if planned is not None else frame.num_rows,
+        planned[1] if planned is not None else frame.num_rows, t0=t0,
     )
     return _unpack_results(program, finals)
 
@@ -1208,13 +1230,22 @@ def reduce_blocks(
     if len(partials) == 1:
         finals = partials[0]
     else:
+        tracing = _events.TRACER.enabled
+        t_c = time.perf_counter() if tracing else 0.0
         feeds = {
             f"{x}_input": np.stack([p[x] for p in partials]) for x in out_names
         }
         finals = compiled.run_block(feeds)
+        if tracing:
+            # the stack of the partials and the combine program's run
+            # (parent of that run's executor.* spans)
+            _events.TRACER.emit_complete(
+                "plan.reduce.combine", t_c, time.perf_counter() - t_c,
+                args={"partials": len(partials)}, cat="plan",
+            )
     profiling.record(
         "reduce_blocks", time.perf_counter() - t0,
-        planned[1] if planned is not None else frame.num_rows,
+        planned[1] if planned is not None else frame.num_rows, t0=t0,
     )
     return _unpack_results(program, finals)
 

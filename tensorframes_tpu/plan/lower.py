@@ -387,7 +387,7 @@ def _fused_program(plan: SegmentPlan, schema):
         return {name: env[name] for name in result_names}
 
     fused = Program(fn, in_specs, _output_specs(plan),
-                    fetch_order=list(result_names))
+                    fetch_order=list(result_names), role="fused_map")
     with _CACHE_LOCK:
         _FUSED_CACHE[key] = (fused, tuple(n.program for n in plan.included))
         while len(_FUSED_CACHE) > _FUSED_CACHE_MAX:
@@ -1108,7 +1108,8 @@ def _compose_with_epilogue(
                 env[k2] = outs_[k2]
         return epilogue(env)
 
-    fused = analyze_program(Program(fn, in_specs))
+    fused = analyze_program(
+        Program(fn, in_specs, role=f"map_{cache_key[0]}"))
     with _CACHE_LOCK:
         _FUSED_CACHE[key] = (fused, pinned_expect)
         while len(_FUSED_CACHE) > _FUSED_CACHE_MAX:
@@ -1953,15 +1954,34 @@ def lower_reduce(
     partials: List[Dict[str, np.ndarray]] = []
     blocks = pruned.blocks()
     n_rows = 0
+    tracer = _events.TRACER
     try:
-        for b in blocks:
+        for i, b in enumerate(blocks):
             nb = _block_num_rows(b)
             if nb == 0:
                 continue
             n_rows += nb
+            tracing = tracer.enabled
+            t_g = time.perf_counter() if tracing else 0.0
             feeds = gather_feeds(b, fused.input_names, fused)
+            if tracing:
+                tracer.emit_complete(
+                    "plan.reduce.gather", t_g, time.perf_counter() - t_g,
+                    args={"block": i}, cat="plan",
+                )
             res = compiled.run_block(feeds, to_numpy=False)
-            partials.append({x: np.asarray(res[x]) for x in out_names})
+            t_f = time.perf_counter() if tracing else 0.0
+            part = {x: np.asarray(res[x]) for x in out_names}
+            if tracing:
+                # the block's partial comes to the host: where an
+                # unsynced block program is waited for
+                tracer.emit_complete(
+                    "plan.reduce.fetch", t_f, time.perf_counter() - t_f,
+                    args={"block": i,
+                          "bytes": sum(v.nbytes for v in part.values())},
+                    cat="plan",
+                )
+            partials.append(part)
     except Exception as e:
         from ..validation import ValidationError
 

@@ -65,7 +65,7 @@ class HoistedProgram:
         "_jitted_donate", "closed", "out_tree",
     )
 
-    def __init__(self, fn: Callable, abstract_inputs):
+    def __init__(self, fn: Callable, abstract_inputs, name: str = "run"):
         from jax.core import eval_jaxpr
 
         closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(
@@ -89,6 +89,9 @@ class HoistedProgram:
             outs = eval_jaxpr(jaxpr, consts, *flat_ins)
             return jax.tree_util.tree_unflatten(out_tree, outs)
 
+        # the XLA module is named after the jitted callable
+        # (``jit_<name>``): what a device trace calls this program
+        run.__name__ = run.__qualname__ = name
         self._run = run
         self.jitted = jax.jit(run)
         self._jitted_donate = None
@@ -151,8 +154,14 @@ class Program:
         inputs: Sequence[TensorSpec],
         outputs: Optional[Sequence[TensorSpec]] = None,
         fetch_order: Optional[Sequence[str]] = None,
+        role: str = "map",
     ):
         self.fn = fn
+        # what the program computes in a verb, for the executable's name
+        # in a device trace (``jit_tftpu_<role>_<block|rows>``): "map",
+        # "reduce" (a reduce program, run per block and as the combine),
+        # "fused_map" / "map_<epilogue>" (plan-fused chains)
+        self.role = role
         self.inputs: List[TensorSpec] = list(inputs)
         self.outputs: List[TensorSpec] = list(outputs) if outputs else []
         # order in which the user listed fetches (defines result ordering for
@@ -210,7 +219,8 @@ class Program:
         def fn(feeds: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
             return inner({inv.get(k, k): v for k, v in feeds.items()})
 
-        renamed = Program(fn, new_inputs, self.outputs, self.fetch_order)
+        renamed = Program(fn, new_inputs, self.outputs, self.fetch_order,
+                          role=self.role)
         # carry the segment-lowering info (input names remapped) so the
         # aggregate fast path survives feed_dict renames
         seg = getattr(self, "seg_info", None)
@@ -377,7 +387,8 @@ def analyze_program(
     ordered = [by_name[n] for n in order if n in by_name] + [
         o for o in outputs if o.name not in order
     ]
-    return Program(program.fn, program.inputs, ordered, order)
+    return Program(program.fn, program.inputs, ordered, order,
+                   role=program.role)
 
 
 # ---------------------------------------------------------------------------

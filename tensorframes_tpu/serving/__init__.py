@@ -20,8 +20,9 @@ Guarantees, stated once:
   counted rejection (never a hang), per-request deadlines follow
   ``RetryPolicy.deadline_s`` total-elapsed semantics, and shutdown
   drains gracefully;
-* **observability** — ``tftpu_serving_*`` metrics, ``serving.flush`` /
-  ``serving.request`` trace spans, and flight-recorder ``serving.*``
+* **observability** — ``tftpu_serving_*`` metrics, ``serving.flush``
+  trace spans and ``serving.request`` async pairs, and flight-recorder
+  ``serving.*``
   records ride the standard registry/tracer/black-box surfaces.
 
 ISSUE 11 adds the **iterative decode engine** on top

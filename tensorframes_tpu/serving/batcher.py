@@ -88,9 +88,17 @@ class ResultFuture:
     ``result(timeout)`` blocks for the scattered output columns (a dict
     name → array holding exactly this request's rows) or raises the
     request's failure (:class:`DeadlineExceededError`, the dispatch
-    error, or :class:`ServingError` on abandon)."""
+    error, or :class:`ServingError` on abandon).
 
-    __slots__ = ("_done", "_value", "_exc", "rows", "endpoint")
+    ``t_submit``, ``t_first_token`` and ``t_done`` are
+    ``time.perf_counter`` seconds, ``None`` until known: admission, the
+    decode engine's first token for this request (where it observes
+    ``tftpu_decode_ttft_seconds``; stays ``None`` on endpoints that
+    stream no tokens), and the instant the result or the failure was
+    set. The result dict itself carries none of them."""
+
+    __slots__ = ("_done", "_value", "_exc", "rows", "endpoint",
+                 "t_submit", "t_first_token", "t_done")
 
     def __init__(self, endpoint: str, rows: int):
         self._done = threading.Event()
@@ -98,6 +106,9 @@ class ResultFuture:
         self._exc: Optional[BaseException] = None
         self.rows = rows
         self.endpoint = endpoint
+        self.t_submit: Optional[float] = None
+        self.t_first_token: Optional[float] = None
+        self.t_done: Optional[float] = None
 
     def done(self) -> bool:
         return self._done.is_set()
@@ -122,10 +133,12 @@ class ResultFuture:
 
     def _set(self, value: Dict[str, np.ndarray]) -> None:
         self._value = value
+        self.t_done = time.perf_counter()
         self._done.set()
 
     def _fail(self, exc: BaseException) -> None:
         self._exc = exc
+        self.t_done = time.perf_counter()
         self._done.set()
 
 
@@ -138,7 +151,7 @@ class _Request:
                  trace_id: Optional[str] = None):
         self.feeds = feeds
         self.rows = rows
-        self.t_submit = time.perf_counter()
+        self.t_submit = future.t_submit = time.perf_counter()
         self.deadline = (
             None if deadline_s is None else self.t_submit + deadline_s
         )
@@ -595,7 +608,9 @@ class ContinuousBatcher:
                 args = {"endpoint": self.name, "rows": req.rows}
                 if req.trace_id:
                     args["request_id"] = req.trace_id
-                _events.TRACER.emit_complete(
-                    "serving.request", req.t_submit, latency, args=args,
-                    cat="serving",
+                # a lifetime, dozens open at once: an async pair, not
+                # an "X" span on the worker's timeline
+                _events.TRACER.emit_async(
+                    "serving.request", req.trace_id, req.t_submit,
+                    latency, args=args, cat="serving",
                 )

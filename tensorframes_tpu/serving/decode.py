@@ -151,16 +151,17 @@ class _Seq:
     """One running sequence slot (engine-thread private)."""
 
     __slots__ = ("req", "seq", "prompt", "want", "pos", "joined",
-                 "generated", "replay")
+                 "waited", "generated", "replay")
 
     def __init__(self, req: _Request, seq: int, prompt: np.ndarray,
-                 want: int, joined: int):
+                 want: int, joined: int, waited: float):
         self.req = req
         self.seq = seq
         self.prompt = prompt
         self.want = want
         self.pos = int(prompt.shape[0])  # next KV position to write
         self.joined = joined             # monotonic join counter
+        self.waited = waited             # submit → this join, seconds
         self.generated: List[int] = []
         self.replay: Optional[Deque[int]] = None
 
@@ -311,6 +312,25 @@ class DecodeEngine:
         self._drain = True
         self._next_seq = 0
         self._join_counter = 0
+        # engine-thread trace state: the last phase boundary
+        # (perf_counter seconds; None while not tracing or idle)
+        self._t_mark: Optional[float] = None
+
+    def _phase(self, name: str, **args) -> None:
+        """Close the engine thread's current phase as the span ``name``:
+        it runs from the last boundary to now, and now is the next
+        phase's start, so the loop's phases are contiguous by
+        construction (one clock read per boundary). The first boundary
+        after tracing came on only sets the mark. Call sites sit behind
+        ``if _events.TRACER.enabled``."""
+        now = time.perf_counter()
+        if self._t_mark is not None:
+            args["endpoint"] = self.name
+            _events.TRACER.emit_complete(
+                name, self._t_mark, now - self._t_mark, args=args,
+                cat="serving",
+            )
+        self._t_mark = now
 
     def _run_step(self, *args):
         """Dispatch one batched decode step on the lowering chosen at
@@ -322,12 +342,21 @@ class DecodeEngine:
 
         t_step = time.perf_counter()
         out = self._step(*args)
+        dt = time.perf_counter() - t_step
         observe_strategy_wall(
             "decode_attention",
             "pallas_decode_attn" if self._attn_kernel is not None
             else "xla_decode_attn",
-            time.perf_counter() - t_step,
+            dt,
         )
+        if _events.TRACER.enabled:
+            # the host's enqueue of the step program (key walk, argument
+            # transfer, launch): a leaf inside decode.step, whose rest
+            # is the wait for the device
+            _events.TRACER.emit_complete(
+                "decode.step.enqueue", t_step, dt,
+                args={"endpoint": self.name}, cat="serving",
+            )
         if self._attn_kernel is not None:
             _kernels.note_dispatch(
                 "decode_attn", _kernels.interpret_mode()
@@ -727,17 +756,24 @@ class DecodeEngine:
                     "drain; running sequences abandoned"
                 ))
                 return
+            if not _events.TRACER.enabled:
+                self._t_mark = None
+            elif self._t_mark is None:
+                self._t_mark = time.perf_counter()
             self._purge_resume()
             free = [i for i, s in enumerate(self._slots) if s is None]
-            if free:
-                for req in self._admission.poll(
-                    len(free), can_take=self._admit_budget()
-                ):
-                    self._join(req)
+            polled = self._admission.poll(
+                len(free), can_take=self._admit_budget()
+            ) if free else ()
+            if _events.TRACER.enabled:
+                self._phase("decode.admit", polled=len(polled))
+            for req in polled:
+                self._join(req)
             if any(s is not None for s in self._slots):
                 self._decode_step()
                 continue
-            # idle: nothing running
+            # idle: the nap below belongs to no phase
+            self._t_mark = None
             if stopping and self._admission.queued_rows == 0:
                 return
             if self._admission.queued_rows > 0:
@@ -820,11 +856,13 @@ class DecodeEngine:
         })
 
     def _prefill_seq(self, seq: int, prompt: np.ndarray, plen: int,
-                     resumed: bool) -> Tuple[int, int]:
+                     resumed: bool) -> Tuple[int, int, str]:
         """Write the prompt's KV for a fresh sequence and produce its
         first token through the cheapest eligible path: shared-prefix
         suffix prefill, copy-on-extend, or cold full prefill. Returns
-        ``(first_token, shared_pages_referenced)``."""
+        ``(first_token, shared_pages_referenced, path)``, ``path`` one of
+        ``cold`` / ``suffix`` / ``cow``."""
+        tracing = _events.TRACER.enabled
         hit_pages: List[int] = []
         covered = 0
         cow = None
@@ -865,7 +903,9 @@ class DecodeEngine:
             # only the final prompt token through the solo decode step
             # — it rewrites KV the copy already holds (deterministic,
             # identical) and yields the first-token logits
+            path, bucket = "cow", self._slot_buckets[0]
             dst = self._pool.copy_on_extend(seq, cow)
+            t_disp = time.perf_counter() if tracing else 0.0
             self._pool.columns = self._copy_page(
                 self._pool.columns, np.int32(cow), np.int32(dst)
             )
@@ -893,6 +933,8 @@ class DecodeEngine:
             tb = self._prefill_bucket(tlen)
             padded = np.zeros(tb, np.int32)
             padded[:tlen] = prompt[covered:]
+            path, bucket = "suffix", tb
+            t_disp = time.perf_counter() if tracing else 0.0
             cols, fd = self._suffix_prefill(
                 self.params, self._pool.columns, padded,
                 np.int32(covered), np.int32(tlen),
@@ -905,12 +947,23 @@ class DecodeEngine:
             tb = self._prefill_bucket(plen)
             padded = np.zeros(tb, np.int32)
             padded[:plen] = prompt
+            path, bucket = "cold", tb
+            t_disp = time.perf_counter() if tracing else 0.0
             cols, fd = self._prefill(
                 self.params, self._pool.columns, padded,
                 np.int32(plen), self._pool.table(seq),
             )
             self._pool.columns = cols
             first = int(fd)
+        if tracing:
+            # the program's dispatch through the first token's arrival
+            # on the host: the device's share of decode.join
+            _events.TRACER.emit_complete(
+                "decode.prefill", t_disp, time.perf_counter() - t_disp,
+                args={"endpoint": self.name, "seq": seq, "path": path,
+                      "bucket": bucket},
+                cat="serving",
+            )
         m.DECODE_STEPS["prefill"].inc()
         if self._prefix_cache and not resumed:
             # publish this prompt's freshly written FULL pages so later
@@ -924,9 +977,11 @@ class DecodeEngine:
                 shared_pages=len(hit_pages), covered_tokens=covered,
                 copy_on_extend=cow is not None,
             )
-        return first, len(hit_pages)
+        return first, len(hit_pages), path
 
     def _join(self, req: _Request) -> None:
+        # the deadline and the wait read the clock, traced or not; the
+        # traced span starts at _t_mark, where the phase before it ended
         now = time.perf_counter()
         if req.deadline is not None and req.deadline <= now:
             # lost the race with the expirer between poll and here
@@ -948,12 +1003,12 @@ class DecodeEngine:
         seq = self._next_seq
         self._next_seq += 1
         replay = self._resume.pop(req, None)
-        first, prefix_pages = self._prefill_seq(
+        first, prefix_pages, path = self._prefill_seq(
             seq, prompt, plen, resumed=bool(replay)
         )
         self._join_counter += 1
         s = _Seq(req, seq, prompt, int(req.feeds["new"]),
-                 self._join_counter)
+                 self._join_counter, now - req.t_submit)
         tok = first
         if replay:
             s.replay = collections.deque(replay)
@@ -964,7 +1019,9 @@ class DecodeEngine:
             if not s.replay:
                 s.replay = None
         else:
-            m.DECODE_TTFT.observe(time.perf_counter() - req.t_submit)
+            t_first = time.perf_counter()
+            m.DECODE_TTFT.observe(t_first - req.t_submit)
+            req.future.t_first_token = t_first
             m.DECODE_TOKENS.inc()
         s.generated.append(tok)
         idx = self._slots.index(None)
@@ -976,19 +1033,17 @@ class DecodeEngine:
             "serving.decode.join", endpoint=self.name, seq=seq,
             prompt_len=plen, new_tokens=s.want,
             resumed=bool(replay), prefix_pages=prefix_pages,
-            waited_s=round(now - req.t_submit, 6),
+            waited_s=round(s.waited, 6),
         )
-        if _events.TRACER.enabled:
-            args = {"endpoint": self.name, "seq": seq,
-                    "prompt_len": plen, "resumed": bool(replay)}
-            if req.trace_id:
-                args["request_id"] = req.trace_id
-            _events.TRACER.emit_complete(
-                "decode.join", now, time.perf_counter() - now,
-                args=args, cat="serving",
-            )
         if len(s.generated) >= s.want:
             self._finish(s)
+        if _events.TRACER.enabled:
+            args = {"seq": seq, "prompt_len": plen,
+                    "resumed": bool(replay), "path": path,
+                    "waited_s": round(s.waited, 6)}
+            if req.trace_id:
+                args["request_id"] = req.trace_id
+            self._phase("decode.join", **args)
 
     def _swap_in(self, req: _Request, snap: Dict[str, object],
                  now: float) -> bool:
@@ -1040,7 +1095,8 @@ class DecodeEngine:
         )
         self._join_counter += 1
         s = _Seq(req, seq, req.feeds["prompt"],
-                 int(req.feeds["new"]), self._join_counter)
+                 int(req.feeds["new"]), self._join_counter,
+                 now - req.t_submit)
         s.pos = int(snap["pos"])
         s.generated = list(snap["generated"])
         s.replay = (collections.deque(snap["replay"])
@@ -1053,19 +1109,16 @@ class DecodeEngine:
         _flight.record(
             "serving.decode.swap_in", endpoint=self.name, seq=seq,
             pages=npg, tokens_done=len(s.generated),
-            waited_s=round(now - req.t_submit, 6),
+            waited_s=round(s.waited, 6),
         )
-        if _events.TRACER.enabled:
-            args = {"endpoint": self.name, "seq": seq,
-                    "swap_resumed": True}
-            if req.trace_id:
-                args["request_id"] = req.trace_id
-            _events.TRACER.emit_complete(
-                "decode.join", now, time.perf_counter() - now,
-                args=args, cat="serving",
-            )
         if len(s.generated) >= s.want:
             self._finish(s)
+        if _events.TRACER.enabled:
+            args = {"seq": seq, "swap_resumed": True, "path": "swap",
+                    "waited_s": round(s.waited, 6)}
+            if req.trace_id:
+                args["request_id"] = req.trace_id
+            self._phase("decode.join", **args)
         return True
 
     def _active(self) -> List[_Seq]:
@@ -1079,6 +1132,7 @@ class DecodeEngine:
         # — the oldest is never evicted, and the pool floor (one full
         # horizon) guarantees it can always finish: forward progress is
         # structural, preemption cannot livelock.
+        allocated = preempted = 0
         for s in sorted(self._active(), key=lambda x: x.joined):
             if s not in self._slots:
                 continue  # preempted by an earlier fault in this pass
@@ -1089,6 +1143,7 @@ class DecodeEngine:
             while self._pool.num_allocatable < 1:
                 victim = max(self._active(), key=lambda x: x.joined)
                 self._preempt(victim)
+                preempted += 1
                 if victim is s:
                     preempted_self = True
                     break
@@ -1096,10 +1151,16 @@ class DecodeEngine:
                 continue
             try:
                 self._pool.alloc(s.seq, 1)
+                allocated += 1
             except PoolExhaustedError:  # pragma: no cover - guarded above
                 self._preempt(s)
+                preempted += 1
         active = self._active()
         if not active:
+            if _events.TRACER.enabled:
+                self._phase("decode.prepare", slots=0,
+                            pages_allocated=allocated,
+                            preempted=preempted)
             return
         n = len(active)
         sb = next(b for b in self._slot_buckets if b >= n)
@@ -1111,7 +1172,10 @@ class DecodeEngine:
             tokens[row] = s.generated[-1]
             pos[row] = s.pos
             tables[row] = self._pool.table(s.seq)
-        t_step = time.perf_counter()
+        if _events.TRACER.enabled:
+            # page faults, preemption and the host-side build above
+            self._phase("decode.prepare", slots=n,
+                        pages_allocated=allocated, preempted=preempted)
         cols, nxt = self._run_step(
             self.params, self._pool.columns, tokens, pos, tables
         )
@@ -1119,14 +1183,12 @@ class DecodeEngine:
         nxt = np.asarray(nxt)
         m.DECODE_STEPS["decode"].inc()
         if _events.TRACER.enabled:
-            args = {"endpoint": self.name, "slots": n}
+            args = {"slots": n, "bucket": sb}
             rids = [s.req.trace_id for s in active if s.req.trace_id]
             if rids:
                 args["request_ids"] = rids[:16]
-            _events.TRACER.emit_complete(
-                "decode.step", t_step, time.perf_counter() - t_step,
-                args=args, cat="serving",
-            )
+            self._phase("decode.step", **args)
+        finished = 0
         for row, s in enumerate(active):
             s.pos += 1
             tok = int(nxt[row])
@@ -1143,6 +1205,10 @@ class DecodeEngine:
             s.generated.append(tok)
             if len(s.generated) >= s.want:
                 self._finish(s)
+                finished += 1
+        if _events.TRACER.enabled:
+            # token bookkeeping, replay checks and the finishes above
+            self._phase("decode.commit", finished=finished)
 
     def _slot_of(self, s: _Seq) -> int:
         return self._slots.index(s)
@@ -1216,6 +1282,8 @@ class DecodeEngine:
             self._drop_swap(s.req)
 
     def _finish(self, s: _Seq) -> None:
+        tracing = _events.TRACER.enabled
+        t_fin = time.perf_counter() if tracing else 0.0
         self._slots[self._slot_of(s)] = None
         m.DECODE_SLOTS.dec()
         self._pool.free_seq(s.seq)
@@ -1228,14 +1296,31 @@ class DecodeEngine:
             tokens=int(out.shape[1]),
             seconds=round(done - s.req.t_submit, 6),
         )
-        if _events.TRACER.enabled:
+        if tracing:
+            req = s.req
             args = {"endpoint": self.name, "seq": s.seq,
                     "tokens": int(out.shape[1])}
-            if s.req.trace_id:
-                args["request_id"] = s.req.trace_id
+            if req.trace_id:
+                args["request_id"] = req.trace_id
+            # the finish work itself: a leaf inside decode.commit (or
+            # decode.join, for a request that wanted one token)
             _events.TRACER.emit_complete(
-                "decode.finish", s.req.t_submit, done - s.req.t_submit,
+                "decode.finish", t_fin, time.perf_counter() - t_fin,
                 args=args, cat="serving",
+            )
+            # the request's whole life overlaps every other request's:
+            # an async pair, off the span timeline
+            t_first = req.future.t_first_token
+            _events.TRACER.emit_async(
+                "decode.request", req.trace_id, req.t_submit,
+                done - req.t_submit,
+                args=dict(
+                    args, prompt_len=int(s.prompt.shape[0]),
+                    waited_s=round(s.waited, 6),
+                    ttft_s=(None if t_first is None
+                            else round(t_first - req.t_submit, 6)),
+                ),
+                cat="serving",
             )
 
     def _bit_identity_violation(self, s: _Seq, got: int,

@@ -611,7 +611,7 @@ class QueryEndpoint:
         m.REQUESTS.inc()
         m.ROWS.inc()
         fut = ResultFuture(self.name, 1)
-        t0 = time.perf_counter()
+        t0 = fut.t_submit = time.perf_counter()
         try:
             fut._set(self.execute())
         except BaseException as e:  # the dispatch-error class: the

@@ -588,11 +588,11 @@ class Router:
             dur = time.perf_counter() - t0
             m.ROUTER_REQUEST_LATENCY.observe(dur)
             if _events.TRACER.enabled:
-                # the ingress half of the cross-process request span:
-                # merge joins it to the replica's serving.* spans via
-                # the shared request_id arg
-                _events.TRACER.emit_complete(
-                    "router.request", t0, dur,
+                # the ingress half of the cross-process request: an
+                # async pair under the request id, which the replica's
+                # serving.request / decode.request pairs share
+                _events.TRACER.emit_async(
+                    "router.request", key, t0, dur,
                     args={"request_id": key, "endpoint": endpoint,
                           "attempts": attempts},
                     cat="serving",

@@ -6,11 +6,16 @@ TPU-native equivalent first-class: per-verb wall-clock metrics plus
 ``jax.profiler`` device traces.
 
 * ``span(name, rows=...)`` — context manager accumulating wall-clock,
-  call count and row throughput per named operation. The five verbs wrap
-  their execution in spans automatically; user code can add its own.
+  call count and row throughput per named operation; user code can add
+  its own.
+* ``record(name, seconds, rows, t0=...)`` — the same for code that times
+  itself: the verbs call it once per invocation, with the instant they
+  started, so the timeline span sits where the work was.
 * ``metrics()`` / ``report()`` / ``reset_metrics()`` — inspect the
   accumulated stats (``report()`` is the profiling sibling of
-  ``explain``).
+  ``explain``): rates over the host's wall clock. Shares of a chip's
+  peak come from device time in a profiler trace (``benchmark/``), not
+  from here.
 * ``trace(logdir)`` — context manager around ``jax.profiler.trace``:
   captures a TensorBoard-viewable device trace (XLA ops, HBM transfers)
   when the runtime supports it; a no-op (with a log line) otherwise.
@@ -115,13 +120,16 @@ def record(
     rows: int = 0,
     flops: float = 0.0,
     bytes_accessed: Optional[float] = None,
+    t0: Optional[float] = None,
     **kwargs: float,
 ) -> None:
     """Directly accumulate one measurement (for code that times itself).
     ``flops``/``bytes_accessed`` let callers attach XLA cost-model
     counts (e.g. from ``Program.flops_per_row``/``bytes_per_row``) so
-    :func:`report` can print achieved FLOP/s, HBM GB/s, and — when
-    ``config.peak_flops`` is set — MFU.
+    :func:`report` can print achieved FLOP/s and HBM GB/s. ``t0`` is
+    the ``perf_counter`` instant the timed stretch began: with it the
+    timeline span sits exactly where the work was, so the spans emitted
+    inside the stretch nest in it.
 
     ``bytes=`` is the deprecated spelling of ``bytes_accessed`` (it
     shadowed the builtin); accepted for one release with a
@@ -154,11 +162,12 @@ def record(
     _latency(name, seconds)
     ev = _trace_events()
     if ev.TRACER.enabled:
-        # callers record immediately after timing (the verbs do
-        # ``record(name, perf_counter() - t0, ...)``), so "it just
-        # ended" reconstructs the start closely enough for a timeline
+        # without t0: callers record immediately after timing, so "it
+        # just ended" reconstructs the start closely enough for a
+        # timeline (a few microseconds late: children may stick out)
         ev.TRACER.emit_complete(
-            name, time.perf_counter() - seconds, seconds,
+            name, time.perf_counter() - seconds if t0 is None else t0,
+            seconds,
             args={"rows": rows} if rows else None, cat="profiling",
         )
 
@@ -176,21 +185,19 @@ def reset_metrics() -> None:
 
 def report() -> str:
     """Human-readable per-span table (the profiling ``explain``). Spans
-    carrying FLOP counts get achieved GFLOP/s, plus model FLOP
-    utilization (achieved / ``config.peak_flops``) when the chip's peak
-    is configured — perf work becomes a number, not a vibe."""
-    from ..config import get_config
-
+    carrying FLOP or byte counts get achieved GFLOP/s and GB/s over the
+    host's wall clock. A share of the chip's peak is not computed here:
+    that takes device time and the device's own peak, which the
+    benchmark reads from a profiler trace (``benchmark/readers``)."""
     snap = metrics()
     if not snap:
         return "no spans recorded"
-    peak = float(getattr(get_config(), "peak_flops", 0.0) or 0.0)
     any_flops = any(s.flops for s in snap.values())
     any_bytes = any(s.bytes for s in snap.values())
     name_w = max(len(k) for k in snap) + 2
     hdr = f"{'span':<{name_w}}{'calls':>7}{'seconds':>12}{'rows':>12}{'rows/s':>14}"
     if any_flops:
-        hdr += f"{'GFLOP/s':>12}" + (f"{'MFU%':>8}" if peak else "")
+        hdr += f"{'GFLOP/s':>12}"
     if any_bytes:
         hdr += f"{'GB/s':>10}"
 
@@ -204,12 +211,6 @@ def report() -> str:
             line += (
                 f"{s.flops_per_sec / 1e9:>12,.1f}" if s.flops else f"{'-':>12}"
             )
-            if peak:
-                line += (
-                    f"{100.0 * s.flops_per_sec / peak:>8.1f}"
-                    if s.flops
-                    else f"{'-':>8}"
-                )
         if any_bytes:
             line += (
                 f"{s.bytes_per_sec / 1e9:>10,.1f}" if s.bytes else f"{'-':>10}"

@@ -82,11 +82,25 @@ def _post(url, body=None, raw=None, headers=None, timeout=20):
         return e.code, json.loads(e.read() or b"{}")
 
 
-def _spans(name):
-    return [
-        e for e in events.to_chrome_trace()["traceEvents"]
-        if e.get("name") == name and e.get("ph") == "X"
-    ]
+def _spans(name, evs=None):
+    """The events under ``name``: complete ("X") spans, and for a
+    request lifetime — an async pair since ISSUE 25 — the "b" half
+    (it carries the args) of every pair. A lifetime written as "X", or a
+    pair missing a half, fails here."""
+    if evs is None:
+        evs = events.to_chrome_trace()["traceEvents"]
+    mine = [e for e in evs if e.get("name") == name]
+    if name.endswith(".request"):
+        assert {e["ph"] for e in mine} <= {"b", "e"}, mine
+        begins = [e for e in mine if e["ph"] == "b"]
+        ends = {e["id"]: e for e in mine if e["ph"] == "e"}
+        assert sorted(e["id"] for e in begins) == sorted(ends)
+        for b in begins:
+            e = ends[b["id"]]
+            assert e["ts"] >= b["ts"] and e["cat"] == b["cat"]
+            assert (e["pid"], e["tid"]) == (b["pid"], b["tid"])
+        return begins
+    return [e for e in mine if e.get("ph") == "X"]
 
 
 @pytest.fixture
@@ -442,17 +456,18 @@ def test_two_process_trace_merges_with_one_request_id(tmp_path):
     # ONE request id spans both processes: the router's ingress span on
     # pid 0 and the replica's serving spans on pid 1
     ingress = [
-        e for e in evs
-        if e.get("name") == "router.request"
-        and e["args"].get("request_id") == rid
+        e for e in _spans("router.request", evs)
+        if e["args"].get("request_id") == rid
     ]
     served = [
-        e for e in evs
-        if e.get("name") == "serving.request"
-        and e["args"].get("request_id") == rid
+        e for e in _spans("serving.request", evs)
+        if e["args"].get("request_id") == rid
     ]
     assert len(ingress) == 1 and ingress[0]["pid"] == 0
     assert len(served) == 1 and served[0]["pid"] == 1
+    # the pairs share the request id as their async id: one request,
+    # one track, across both processes
+    assert ingress[0]["id"] == served[0]["id"] == rid
     flushes = [
         e for e in evs
         if e.get("name") == "serving.flush"

@@ -1,0 +1,375 @@
+"""The tracing rule (ISSUE 25): on any one thread "X" spans nest properly
+or do not overlap; whatever the host does between two device programs
+on the hot paths lies under a leaf span; a request's life is an async
+pair, never an "X" span.
+
+* the serving loop — a saturated ``DecodeEngine`` under tracing: stack
+  discipline on the engine thread, no uncovered stretch between the
+  first and the last ``decode.step``, no lifetime on the timeline,
+  ``decode.request`` as matched "b"/"e" pairs that survive a merge;
+* the frame pass — a sharded ``reduce_blocks(map_blocks(...))`` over
+  four virtual devices emits ``executor.prepare``, ``plan.reduce.*``
+  with the stated nesting;
+* tracing off — the same runs append nothing;
+* first-token time — ``ResultFuture.t_submit <= t_first_token <=
+  t_done`` and ``t_first_token`` is what ``DECODE_TTFT`` observed;
+* names — scope names in the lowered text of the decode step and of
+  the Inception forward; three module names for the frame pass's three
+  programs.
+"""
+
+import json
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import tensorframes_tpu as tfs
+from tensorframes_tpu.models import generation as gen
+from tensorframes_tpu.models import inception as inc
+from tensorframes_tpu.models import transformer as tr
+from tensorframes_tpu.observability import events, merge
+from tensorframes_tpu.observability.metrics import REGISTRY
+from tensorframes_tpu.program import Program, TensorSpec
+from tensorframes_tpu.serving import DecodeConfig, DecodeEngine
+
+PHASES = ("decode.admit", "decode.join", "decode.prepare", "decode.step",
+          "decode.commit")
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = gen.gpt_tiny()
+    return cfg, tr.quantize_params(tr.init_params(cfg, seed=0))
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    cfg, params = model
+    eng = DecodeEngine("t_spans", cfg, params, DecodeConfig(
+        max_slots=4, page_size=8, max_prompt_len=16, max_new_tokens=8,
+    ))
+    eng.start()
+    yield eng
+    eng.stop(drain=True, timeout=120)
+
+
+@pytest.fixture
+def tracing():
+    was = events.TRACER.enabled
+    events.clear()
+    events.enable()
+    yield
+    events.clear()
+    if not was:
+        events.disable()
+
+
+def _saturate(eng, vocab, n=12, seed=0):
+    """Three times as many requests as slots, mixed lengths, one of them
+    wanting a single token (it finishes inside its join)."""
+    rng = np.random.default_rng(seed)
+    futs = []
+    for i in range(n):
+        prompt = rng.integers(0, vocab, (int(rng.integers(3, 17)),))
+        futs.append(eng.submit({
+            "prompt": prompt.astype(np.int32),
+            "max_new_tokens": 1 if i == 5 else int(rng.integers(2, 9)),
+        }))
+    for f in futs:
+        f.result(120)
+    return futs
+
+
+def _events():
+    return events.to_chrome_trace()["traceEvents"]
+
+
+def _engine_spans(evs, eng):
+    tid = eng._thread.ident
+    return sorted(
+        (e for e in evs if e.get("ph") == "X" and e["tid"] == tid),
+        key=lambda e: (e["ts"], -e["dur"]),
+    )
+
+
+def _assert_stack_discipline(spans, slack_us=0.5):
+    stack = []
+    for e in spans:
+        while stack and stack[-1]["ts"] + stack[-1]["dur"] <= e["ts"] + slack_us:
+            stack.pop()
+        if stack:
+            top = stack[-1]
+            assert e["ts"] + e["dur"] <= top["ts"] + top["dur"] + slack_us, (
+                f"{e['name']} straddles the end of {top['name']}")
+        stack.append(e)
+
+
+def test_engine_thread_spans_nest_and_cover_the_loop(model, engine, tracing):
+    _saturate(engine, model[0].vocab_size)
+    events.disable()
+    evs = _events()
+    spans = _engine_spans(evs, engine)
+    names = {e["name"] for e in spans}
+    assert set(PHASES) | {"decode.prefill", "decode.step.enqueue",
+                          "decode.finish"} <= names
+    _assert_stack_discipline(spans)
+
+    # the five phases tile the loop: between the first and the last
+    # decode.step nothing longer than 1 ms is uncovered
+    steps = [e for e in spans if e["name"] == "decode.step"]
+    lo, hi = steps[0]["ts"], steps[-1]["ts"] + steps[-1]["dur"]
+    at, worst = lo, 0.0
+    for e in spans:
+        if e["name"] not in PHASES or e["ts"] + e["dur"] <= lo or e["ts"] >= hi:
+            continue
+        worst = max(worst, e["ts"] - at)
+        at = max(at, e["ts"] + e["dur"])
+    assert worst <= 1000.0, f"{worst:.0f} us of the loop under no phase"
+
+    # no lifetime on the timeline: nothing outlasts a step plus a join
+    longest = (max(e["dur"] for e in steps)
+               + max(e["dur"] for e in spans if e["name"] == "decode.join"))
+    for e in spans:
+        assert e["dur"] <= longest, (e["name"], e["dur"], longest)
+
+    # children sit where the tables say
+    def inside(child, parents):
+        return any(p["ts"] - 0.5 <= child["ts"] and child["ts"] + child["dur"]
+                   <= p["ts"] + p["dur"] + 0.5 for p in parents)
+
+    joins = [e for e in spans if e["name"] == "decode.join"]
+    commits = [e for e in spans if e["name"] == "decode.commit"]
+    prefills = [e for e in spans if e["name"] == "decode.prefill"]
+    for e in spans:
+        if e["name"] == "decode.prefill":
+            assert inside(e, joins)
+            assert e["args"]["bucket"] >= 1
+        elif e["name"] == "decode.finish":
+            assert inside(e, joins + commits)
+        elif e["name"] == "decode.step.enqueue":
+            assert inside(e, steps)
+    assert len(prefills) == 12
+    assert len(steps) == sum(
+        e["name"] == "decode.step.enqueue" for e in spans)
+    assert any(e["name"] == "decode.finish" and inside(e, joins)
+               for e in spans), "the one-token request finishes in its join"
+    assert all(e["args"]["path"] == "cold" and "waited_s" in e["args"]
+               for e in joins)
+    assert all({"slots", "bucket"} <= set(e["args"]) for e in steps)
+    prepares = [e for e in spans if e["name"] == "decode.prepare"]
+    assert all({"slots", "pages_allocated", "preempted"} <= set(e["args"])
+               for e in prepares)
+    assert sum(e["args"]["finished"] for e in commits) == 11
+    assert sum(e["args"]["polled"] for e in spans
+               if e["name"] == "decode.admit") == 12
+
+
+def test_decode_request_is_an_async_pair_and_survives_merge(
+        model, engine, tracing, tmp_path):
+    futs = _saturate(engine, model[0].vocab_size, n=6, seed=1)
+    events.disable()
+    evs = _events()
+    assert not [e for e in evs if e["name"] == "decode.request"
+                and e["ph"] == "X"]
+    begins = {e["id"]: e for e in evs
+              if e["name"] == "decode.request" and e["ph"] == "b"}
+    ends = {e["id"]: e for e in evs
+            if e["name"] == "decode.request" and e["ph"] == "e"}
+    assert len(begins) == len(futs) and set(begins) == set(ends)
+    for rid, b in begins.items():
+        assert {"endpoint", "seq", "tokens", "prompt_len", "waited_s",
+                "ttft_s"} <= set(b["args"])
+        assert ends[rid]["ts"] >= b["ts"]
+        assert b["args"]["ttft_s"] * 1e6 <= ends[rid]["ts"] - b["ts"] + 1.0
+    shard = events.save_shard(str(tmp_path))
+    merged = merge.merge_traces([shard])["traceEvents"]
+    kept = [e for e in merged if e["name"] == "decode.request"]
+    assert sorted(e["ph"] for e in kept) == ["b"] * 6 + ["e"] * 6
+    assert {e["id"] for e in kept} == set(begins)
+    json.dumps(merged)
+
+
+def test_emit_async_drops_both_halves_or_neither():
+    t = events.Tracer(max_events=3)
+    t.enable()
+    t.emit_async("r", "a", 1.0, 0.5, args={"k": 1})   # M + b + e
+    t.emit_async("r", None, 2.0, 0.5)                  # no room for two
+    evs = t.to_chrome_trace()["traceEvents"]
+    assert [e["ph"] for e in evs] == ["M", "b", "e"]
+    assert evs[1]["id"] == evs[2]["id"] == "a"
+    assert evs[2]["ts"] - evs[1]["ts"] == pytest.approx(0.5e6)
+    assert t.dropped == 2
+    t2 = events.Tracer()
+    t2.enable()
+    t2.emit_async("r", None, 1.0, 0.1)
+    t2.emit_async("r", None, 1.0, 0.1)
+    ids = [e["id"] for e in t2.to_chrome_trace()["traceEvents"]
+           if e["ph"] == "b"]
+    assert len(set(ids)) == 2
+
+
+def test_tracer_off_appends_nothing_and_reads_no_phase_clock(model, engine):
+    was = events.TRACER.enabled
+    events.disable()
+    events.clear()
+    try:
+        _saturate(engine, model[0].vocab_size, n=6, seed=2)
+        _frame_pass()
+        assert _events() == []
+        assert engine._t_mark is None
+    finally:
+        if was:
+            events.enable()
+
+
+def test_first_token_time_reaches_the_caller(model, engine):
+    def ttft():
+        return [d for d in REGISTRY.snapshot()
+                if d["name"] == "tftpu_decode_ttft_seconds"][0]
+
+    before = ttft()
+    fut = engine.submit({"prompt": np.arange(5, dtype=np.int32)})
+    assert fut.t_submit is not None
+    out = fut.result(120)
+    after = ttft()
+    assert set(out) == {"tokens"}
+    assert fut.t_submit <= fut.t_first_token <= fut.t_done
+    assert after["count"] - before["count"] == 1
+    assert after["sum"] - before["sum"] == pytest.approx(
+        fut.t_first_token - fut.t_submit, abs=1e-9)
+
+
+# -- the frame pass -------------------------------------------------------
+
+def _frame_pass(devices=None, blocks=3, rows=8):
+    """map_blocks then reduce_blocks over a frame of ``blocks`` blocks,
+    resident (and, given devices, sharded) as the benchmark's is."""
+    from tensorframes_tpu import dtypes as dt
+    from tensorframes_tpu.frame import TensorFrame
+    from tensorframes_tpu.parallel.mesh import batch_sharding, make_mesh
+    from tensorframes_tpu.schema import ColumnInfo, Schema
+    from tensorframes_tpu.shape import Shape
+
+    devices = devices or jax.devices()[:1]
+    mesh = make_mesh(devices=devices)
+    sharding = batch_sharding(mesh, 2)
+    data = [{"x": jax.device_put(
+        np.arange(rows * 4, dtype=np.float32).reshape(rows, 4) + i, sharding)}
+        for i in range(blocks)]
+    frame = TensorFrame(
+        data, Schema([ColumnInfo("x", dt.float32, Shape((-1, 4)))]))
+    frame._mesh, frame._axis = mesh, mesh.axis_names[0]
+    score = tfs.compile_program(lambda x: {"y": x * 2.0 + 1.0}, frame)
+    total = tfs.compile_program(
+        lambda y_input: {"y": y_input.sum(axis=0)},
+        tfs.map_blocks(score, frame), reduce_mode="blocks")
+    out = tfs.reduce_blocks(total, tfs.map_blocks(score, frame))
+    want = sum((np.asarray(b["x"]) * 2.0 + 1.0).sum(axis=0) for b in data)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
+    return frame, score, total
+
+
+def test_sharded_frame_pass_spans_nest(tracing):
+    assert len(jax.devices()) >= 4
+    _frame_pass(jax.devices()[:4])          # compiles outside the check
+    events.clear()
+    _frame_pass(jax.devices()[:4])
+    events.disable()
+    tid = threading.get_ident()
+    spans = sorted((e for e in _events()
+                    if e.get("ph") == "X" and e["tid"] == tid),
+                   key=lambda e: (e["ts"], -e["dur"]))
+    _assert_stack_discipline(spans)
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+
+    def inside(c, p):
+        return (p["ts"] - 0.5 <= c["ts"]
+                and c["ts"] + c["dur"] <= p["ts"] + p["dur"] + 0.5)
+
+    verb = by["reduce_blocks"][-1]
+    assert [e["args"]["block"] for e in by["plan.reduce.gather"]] == [0, 1, 2]
+    assert [e["args"]["block"] for e in by["plan.reduce.fetch"]] == [0, 1, 2]
+    assert all(e["args"]["bytes"] == 16 for e in by["plan.reduce.fetch"])
+    (combine,) = by["plan.reduce.combine"]
+    assert combine["args"]["partials"] == 3
+    # one block program per block, one combine program per call
+    assert len(by["executor.prepare"]) == len(by["executor.run_block"]) == 4
+    assert all({"kind", "compiled"} <= set(e["args"])
+               for e in by["executor.prepare"])
+    assert all("synced" in e["args"] for e in by["executor.run_block"])
+    (fetch,) = by["executor.fetch"]         # the combine run's to_numpy
+    assert fetch["args"]["bytes"] == 16
+    for name in ("plan.reduce.gather", "plan.reduce.fetch",
+                 "plan.reduce.combine", "executor.prepare",
+                 "executor.run_block", "executor.fetch"):
+        assert all(inside(e, verb) for e in by[name]), name
+    in_combine = [e for name in ("executor.prepare", "executor.run_block",
+                                 "executor.fetch")
+                  for e in by[name] if inside(e, combine)]
+    assert sorted(e["name"] for e in in_combine) == [
+        "executor.fetch", "executor.prepare", "executor.run_block"]
+    # gather, prepare, run, fetch of one block follow each other
+    for g, p, r, f in zip(by["plan.reduce.gather"], by["executor.prepare"],
+                          by["executor.run_block"], by["plan.reduce.fetch"]):
+        assert g["ts"] <= p["ts"] <= r["ts"] <= f["ts"]
+
+
+def _module_names(program):
+    """The XLA module name of each executable the program holds, read
+    from its lowered text."""
+    names = set()
+    for entry in program.compiled()._hoisted.values():
+        if entry:
+            text = entry.jitted.lower(
+                entry.consts, entry._flat_abstract).as_text()
+            names.add(re.search(r"module @(\S+)", text).group(1))
+    return names
+
+
+def test_frame_programs_have_distinct_module_names():
+    from tensorframes_tpu import dtypes as dt
+    from tensorframes_tpu.plan import lower
+    from tensorframes_tpu.shape import Shape
+
+    frame, score, total = _frame_pass()
+    tfs.map_blocks(score, frame).blocks()   # the map program on its own
+    assert _module_names(score) == {"jit_tftpu_map_block"}
+    assert _module_names(total) == {"jit_tftpu_reduce_block"}
+    fused = set()
+    for program, _pinned in lower._FUSED_CACHE.values():
+        if total in _pinned:
+            fused |= _module_names(program)
+    assert fused == {"jit_tftpu_map_reduce_block"}
+    # the rows entry of the same program is another executable
+    assert score.compiled().module_name("vmap") == "tftpu_map_rows"
+    # a reduce labels its own analysed copy, never the caller's Program
+    raw = Program(lambda feeds: {"y": feeds["y_input"].sum(axis=0)},
+                  [TensorSpec("y_input", dt.float32, Shape((-1, 4)))])
+    tfs.reduce_blocks(raw, tfs.map_blocks(score, frame))
+    assert raw.role == "map"
+
+
+def test_scope_names_in_lowered_text(model):
+    cfg, params = model
+    step = gen.paged_decode_step_fn(cfg, 8, 3)
+    pool = gen.init_paged_kv(cfg, 4, 8)
+    text = jax.jit(step).lower(
+        params, pool, jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
+        jnp.zeros((2, 3), jnp.int32)).as_text(debug_info=True)
+    for scope in ("embed", "layer_0/attn", "layer_0/kv_write",
+                  "layer_0/mlp", f"layer_{cfg.num_layers - 1}/mlp", "head"):
+        assert scope in text, scope
+    icfg = inc.tiny()
+    iparams = inc.init_params(icfg, seed=0)
+    images = jnp.zeros((1, icfg.image_size, icfg.image_size, 3), jnp.float32)
+    text = jax.jit(lambda p, x: inc.forward(icfg, p, x)).lower(
+        iparams, images).as_text(debug_info=True)
+    for scope in ("stem", "mixed_5b", "mixed_5d", "mixed_6a", "mixed_6e",
+                  "mixed_7a", "mixed_7c", "logits"):
+        assert scope in text, scope
