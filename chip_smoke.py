@@ -550,10 +550,11 @@ def child_main(args) -> int:
             dcfg.page_size
         P = 1 + S * maxp
         q = jnp.asarray(rng.standard_normal((S, nh, hd)), jnp.float32)
-        kp = jnp.asarray(rng.integers(-127, 128, (P, 2, nh, pg, hd)), jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (P, 2, nh, pg, hd)), jnp.int8)
-        ks = jnp.asarray(rng.uniform(.01, .1, (P, 2, nh, pg, 1)), jnp.float32)
-        vs = jnp.asarray(rng.uniform(.01, .1, (P, 2, nh, pg, 1)), jnp.float32)
+        lanes = kda.SCALE_LANES
+        kp = jnp.asarray(rng.integers(-127, 128, (P, 2, pg, nh * hd)), jnp.int8)
+        vp = jnp.asarray(rng.integers(-127, 128, (P, 2, pg, nh * hd)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(.01, .1, (P, 2, pg, lanes)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(.01, .1, (P, 2, pg, lanes)), jnp.float32)
         tables = jnp.asarray(
             rng.integers(1, P, (S, maxp)), jnp.int32).at[-1].set(0)
         pos = jnp.asarray(

@@ -15,7 +15,8 @@ serializes scatter. This package holds the purpose-built kernels:
   maps), dequantize in-register, and the attention math runs in the
   same kernel — the gather→dequant→attend chain of
   ``models/generation.paged_decode_step_fn`` becomes ONE kernel with no
-  materialized ``[S, pages, heads, page, hd]`` copy.
+  materialized ``[S, pages, page, heads*hd]`` copy, reading the
+  resident pool columns in the layout the KV write leaves them in.
 * :mod:`.ragged_gather` — ragged row staging on device: cells move as
   one flat buffer + offsets, and the kernel scatters each shape
   group's rows into its padded batch in VMEM, replacing the per-group

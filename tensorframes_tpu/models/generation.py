@@ -285,18 +285,35 @@ def generate_naive(
 # — but laid out page-major so a pool of fixed-size pages can be shared
 # by many sequences through per-sequence page tables (vLLM-style paged
 # attention, ISSUE 11).
+#
+# One physical layout (PR 26): a column's row-major default layout is
+# the layout it is resident in, the layout every KV write scatters into
+# and the layout the attention kernel reads, and every program that
+# returns the pool takes it DONATED (serving/decode.py), so a write
+# touches the rows it writes and nothing else. No program here may
+# reshape, transpose or slice a whole column: that is a pool-sized copy
+# per layer (what the earlier [pages, layers, heads, page, head_dim]
+# shape cost; tests/test_decode_compile.py holds the compiled step to
+# zero such copies).
 # ---------------------------------------------------------------------------
 
 def init_paged_kv(
     cfg: TransformerConfig, num_pages: int, page_size: int
 ) -> Dict[str, jnp.ndarray]:
-    """The paged int8 KV pool as columnar state: page-major arrays
-    ``[num_pages, layers, heads, page_size, head_dim]`` (int8 k/v, f32
-    per-slot scales) — each array is one frame column with pages as
-    rows (``serving.kvpool.PagedKVPool.as_frame``). Page 0 is the
-    reserved NULL page: padding slots and masked prefill positions
-    write there, and attention masks guarantee it is never read
-    unmasked, so its garbage contents cannot reach any output."""
+    """The paged int8 KV pool as columnar state, page-major: ``k``/``v``
+    int8 ``[num_pages, layers, page_size, heads*head_dim]`` (a position's
+    heads side by side in one row) and ``k_scale``/``v_scale`` float32
+    ``[num_pages, layers, page_size, SCALE_LANES]`` (head ``h``'s
+    per-position scale in lane ``h``; the lanes past ``heads`` are
+    padding, there so that a page's scale rows are whole (8, 128)
+    tiles: 8 KB per page and layer beside 12 KB of int8 at GPT-2-small
+    widths). Each array is one frame column with pages as rows
+    (``serving.kvpool.PagedKVPool.as_frame``). Page 0 is the reserved
+    NULL page: padding slots and masked prefill positions write there,
+    and attention masks guarantee it is never read unmasked, so its
+    garbage contents cannot reach any output."""
+    from ..kernels.decode_attention import SCALE_LANES
+
     if num_pages < 2:
         raise ValueError(
             f"num_pages must be >= 2 (page 0 is the reserved null "
@@ -304,15 +321,44 @@ def init_paged_kv(
         )
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (num_pages, cfg.num_layers, cfg.num_heads, page_size,
-             cfg.head_dim)
-    sshape = shape[:-1] + (1,)
+    if cfg.num_heads > SCALE_LANES:
+        raise ValueError(
+            f"the paged pool holds one scale lane per head: num_heads="
+            f"{cfg.num_heads} exceeds {SCALE_LANES}"
+        )
+    shape = (num_pages, cfg.num_layers, page_size,
+             cfg.num_heads * cfg.head_dim)
+    sshape = shape[:-1] + (SCALE_LANES,)
     return {
         "k": jnp.zeros(shape, jnp.int8),
         "v": jnp.zeros(shape, jnp.int8),
         "k_scale": jnp.ones(sshape, jnp.float32),
         "v_scale": jnp.ones(sshape, jnp.float32),
     }
+
+
+def _pool_rows(xq: jnp.ndarray, xs: jnp.ndarray):
+    """Arrange quantized positions as pool rows: int8 ``[N, heads,
+    head_dim]`` → ``[N, heads*head_dim]`` and scales ``[N, heads]`` →
+    ``[N, SCALE_LANES]`` (padding lanes 1.0, read by no output)."""
+    from ..kernels.decode_attention import SCALE_LANES
+
+    n, nh, hd = xq.shape
+    return xq.reshape(n, nh * hd), jnp.pad(
+        xs, ((0, 0), (0, SCALE_LANES - nh)), constant_values=1.0
+    )
+
+
+def _write_kv(pool, li: int, pg, off, k_rows, v_rows) -> None:
+    """Scatter one layer's new positions into ``pool`` (a dict, updated
+    in place) at (page ``pg``, offset ``off``) pairs: ONE scatter per
+    column, of whole rows of the resident layout — in place when the
+    pool was donated. ``k_rows``/``v_rows`` are :func:`_pool_rows`
+    pairs."""
+    for name, (rows, srows) in (("k", k_rows), ("v", v_rows)):
+        pool[name] = pool[name].at[pg, li, off].set(rows)
+        pool[name + "_scale"] = pool[name + "_scale"].at[
+            pg, li, off].set(srows)
 
 
 def paged_kv_nbytes(pool: Dict[str, jnp.ndarray]) -> int:
@@ -364,19 +410,10 @@ def paged_prefill_fn(cfg: TransformerConfig, page_size: int,
                 kq, ks = _quantize_slots(k[None])       # [1, nh, T, hd]
                 vq, vs = _quantize_slots(v[None])
                 kq, ks, vq, vs = kq[0], ks[0], vq[0], vs[0]
-                # ONE scatter per tensor per layer: advanced indices at
-                # the page and offset axes broadcast to [T, nh, ...]
-                pool["k"] = pool["k"].at[pg, li, :, off].set(
-                    kq.transpose(1, 0, 2)
-                )
-                pool["v"] = pool["v"].at[pg, li, :, off].set(
-                    vq.transpose(1, 0, 2)
-                )
-                pool["k_scale"] = pool["k_scale"].at[pg, li, :, off].set(
-                    ks.transpose(1, 0, 2)
-                )
-                pool["v_scale"] = pool["v_scale"].at[pg, li, :, off].set(
-                    vs.transpose(1, 0, 2)
+                _write_kv(
+                    pool, li, pg, off,
+                    _pool_rows(kq.transpose(1, 0, 2), ks[..., 0].T),
+                    _pool_rows(vq.transpose(1, 0, 2), vs[..., 0].T),
                 )
             with jax.named_scope(f"layer_{li}/attn"):
                 # attend within the chunk over the quantized k/v — the
@@ -467,12 +504,10 @@ def paged_suffix_prefill_fn(cfg: TransformerConfig, page_size: int,
                 # write first, then gather-attend — row i sees positions
                 # 0..start+i including its own token, the decode-step
                 # order
-                pool["k"] = pool["k"].at[pg, li, :, off].set(kq)
-                pool["v"] = pool["v"].at[pg, li, :, off].set(vq)
-                pool["k_scale"] = pool["k_scale"].at[
-                    pg, li, :, off].set(ks)
-                pool["v_scale"] = pool["v_scale"].at[
-                    pg, li, :, off].set(vs)
+                _write_kv(
+                    pool, li, pg, off,
+                    _pool_rows(kq, ks[..., 0]), _pool_rows(vq, vs[..., 0]),
+                )
             with jax.named_scope(f"layer_{li}/attn"):
                 ctx = paged_attention_reference(
                     q, pool["k"], pool["v"],
@@ -508,6 +543,9 @@ def paged_page_ops_fns(max_pages: int):
     * ``copy_page(pool, src, dst) -> pool`` — duplicate one page
       (copy-on-extend: a ragged-tail prefix-cache hit copies the shared
       page before writing into it).
+
+    ``restore`` and ``copy_page`` return the pool: the engine jits them
+    with it donated, so they touch the pages they name and no others.
     """
 
     def extract(pool, idx):
@@ -543,7 +581,9 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
     gather), and attends masked to ``j <= pos``. Every slot's row is
     computed independently (the map_rows/vmap convention), which is
     what makes a batched step bit-identical per slot to a solo step —
-    the serving bench hard-gates it.
+    the serving bench hard-gates it. The engine jits the step with
+    ``pool`` donated: the writes are scatters of whole rows into the
+    resident columns, in place.
 
     ``attn_kernel="pallas"`` replaces the gather→dequant→attend chain
     with the fused paged int8-KV pallas kernel
@@ -584,12 +624,10 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
                 vq, vs = _quantize_slots(v[:, :, None, :])
                 kq, ks = kq[:, :, 0], ks[:, :, 0]       # [S, nh, hd/1]
                 vq, vs = vq[:, :, 0], vs[:, :, 0]
-                pool["k"] = pool["k"].at[wpg, li, :, woff].set(kq)
-                pool["v"] = pool["v"].at[wpg, li, :, woff].set(vq)
-                pool["k_scale"] = pool["k_scale"].at[
-                    wpg, li, :, woff].set(ks)
-                pool["v_scale"] = pool["v_scale"].at[
-                    wpg, li, :, woff].set(vs)
+                _write_kv(
+                    pool, li, wpg, woff,
+                    _pool_rows(kq, ks[..., 0]), _pool_rows(vq, vs[..., 0]),
+                )
             if attn_kernel == "pallas":
                 # fused paged-attention kernel: the page gather, int8
                 # dequant, and masked softmax-attend run in ONE pallas

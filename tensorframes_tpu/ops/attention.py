@@ -348,7 +348,9 @@ def paged_decode_attention(
     in-register, and computes the masked softmax attention — the
     public face of ``kernels/decode_attention.paged_decode_attention``.
     ``q`` [slots, heads, head_dim]; the pool arrays are the
-    ``models/generation.init_paged_kv`` layout. Bit-identical on the
+    ``models/generation.init_paged_kv`` columns (k/v ``[pages, layers,
+    page, heads*head_dim]`` int8, scales ``[pages, layers, page, 128]``
+    float32), read where they lie. Bit-identical on the
     CPU interpreter to its same-tiling emulation and within float
     tolerance of the XLA gather→dequant→attend chain (asserted in
     tests). The serving decode engine selects it per engine via the

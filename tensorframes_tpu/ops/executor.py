@@ -875,13 +875,18 @@ class _AotJit:
     fallback explicitly counted. See :func:`aot_jit`."""
 
     def __init__(self, fn, in_shardings=None, out_shardings=None,
-                 label: Optional[str] = None):
+                 label: Optional[str] = None,
+                 donate_argnums: Tuple[int, ...] = ()):
         kw = {}
         if in_shardings is not None:
             kw["in_shardings"] = in_shardings
         if out_shardings is not None:
             kw["out_shardings"] = out_shardings
+        donate_argnums = tuple(sorted(int(i) for i in donate_argnums))
+        if donate_argnums:
+            kw["donate_argnums"] = donate_argnums
         self._fn = fn
+        self._donate = donate_argnums
         self._jitted = jax.jit(fn, **kw)
         self._label = label or getattr(fn, "__qualname__", None) \
             or type(fn).__name__
@@ -889,6 +894,12 @@ class _AotJit:
             "in_shardings": _shardings_tree_token(in_shardings),
             "out_shardings": _shardings_tree_token(out_shardings),
         }
+        if donate_argnums:
+            # a donating executable aliases its outputs onto these
+            # arguments and deletes them: the in-process key and the
+            # store's fingerprint must never serve it to (or from) an
+            # undonated entry of the same function
+            self._decl["donate_argnums"] = list(donate_argnums)
         self._builds = _KeyedBuildCache()
         self._dispatched: set = set()
 
@@ -906,7 +917,7 @@ class _AotJit:
         # The treedef enters as the OBJECT (hashable, eq-comparable) —
         # stringifying a transformer's param tree repr per step is
         # dispatch overhead the jax.jit C++ fast path never paid.
-        return (treedef,) + tuple(
+        return (treedef, self._donate) + tuple(
             (tuple(int(d) for d in v.shape), str(v.dtype),
              bool(getattr(v, "weak_type", False)), _placement_token(v))
             for v in leaves
@@ -985,7 +996,7 @@ class _AotJit:
         if fp is not None:
             loaded = store.get(fp, rank=rank)
             if loaded is not None:
-                return lambda *a: loaded(*a)
+                return loaded
             store.record_miss(
                 "fn", [(n, tuple(s), d) for (n, s, d) in avals],
                 False, sharded=bool(shardings),
@@ -995,12 +1006,22 @@ class _AotJit:
         _COMPILE_SECONDS.observe(trace_s + (time.perf_counter() - t1))
         if fp is not None:
             meta = _store_meta(
-                "fn", "plain", False,
+                "fn", "plain", bool(self._donate),
                 sorted((n, list(s), d) for (n, s, d) in avals),
                 shardings, multiprocess, rank, label=self._label,
             )
             store.put(fp, compiled, meta=meta, rank=rank)
-        return lambda *a: compiled(*a)
+        return compiled
+
+    def executable(self, *args):
+        """The executable (``jax.stages.Compiled``, compiled here or
+        loaded from the store) that serves ``args``' signature, built
+        now if it was not yet; None for a lazy-jit-only signature. For
+        introspection (``memory_analysis()``, ``as_text()``): dispatch
+        goes through ``__call__``, which counts it."""
+        leaves, treedef = jax.tree_util.tree_flatten(args)
+        key = self._key(leaves, treedef)
+        return None if key is None else self._build(key, args)
 
     def __call__(self, *args):
         leaves, treedef = jax.tree_util.tree_flatten(args)
@@ -1062,7 +1083,8 @@ class _AotJit:
 
 
 def aot_jit(fn, *, in_shardings=None, out_shardings=None,
-            label: Optional[str] = None) -> Callable:
+            label: Optional[str] = None,
+            donate_argnums: Tuple[int, ...] = ()) -> Callable:
     """Drop-in replacement for ``jax.jit(fn, in_shardings=...,
     out_shardings=...)`` that dispatches through the executor's unified
     AOT pipeline (ISSUE 10): explicit ``lower().compile()`` per
@@ -1077,9 +1099,16 @@ def aot_jit(fn, *, in_shardings=None, out_shardings=None,
 
     Positional array arguments only (pytrees fine); a call with a
     Python-scalar leaf stays on the lazy-jit path for that key (an AOT
-    executable is strongly typed; jit traces scalars weakly)."""
+    executable is strongly typed; jit traces scalars weakly).
+
+    ``donate_argnums`` is ``jax.jit``'s: the named positional arguments'
+    buffers become the outputs' where shapes allow, and the arrays the
+    caller passed are DELETED by the call — use what came back. It is
+    part of the in-process key and of the store's fingerprint, so a
+    donating executable is never confused with the plain one."""
     return _AotJit(fn, in_shardings=in_shardings,
-                   out_shardings=out_shardings, label=label)
+                   out_shardings=out_shardings, label=label,
+                   donate_argnums=donate_argnums)
 
 
 def gather_feeds(

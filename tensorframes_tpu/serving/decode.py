@@ -216,9 +216,16 @@ class DecodeEngine:
         grid = decode_warmup_grid(cfg.max_slots, cfg.max_prompt_len)
         self._slot_buckets = grid["decode"]
         self._prefill_buckets = grid["prefill"]
+        # Every program that returns the pool takes it DONATED
+        # (argument 1 of the model steps, 0 of the page ops): the KV
+        # write happens in the resident buffers, and the columns handed
+        # in are deleted by the call. Each call site rebinds
+        # ``self._pool.columns`` to what came back before anything else
+        # can read them; only the engine thread (or start()'s warm-up,
+        # before that thread exists) ever holds the columns.
         self._prefill = aot_jit(
             gen.paged_prefill_fn(model_cfg, cfg.page_size, max_pages),
-            label=f"decode.prefill[{name}]",
+            label=f"decode.prefill[{name}]", donate_argnums=(1,),
         )
         # decode-attention lowering: a counted cost-model decision made
         # ONCE per engine (ISSUE 12) — batched and solo steps trace the
@@ -245,8 +252,11 @@ class DecodeEngine:
                 model_cfg, cfg.page_size, max_pages,
                 attn_kernel=self._attn_kernel,
             ),
-            label=f"decode.step[{name}]",
+            label=f"decode.step[{name}]", donate_argnums=(1,),
         )
+        # widest slot bucket whose step executable's memory plan the
+        # tftpu_decode_step_*_bytes gauges show (0: none yet)
+        self._step_memory_bucket = 0
         # KV memory hierarchy executables (ISSUE 19) — all fixed-shape,
         # warmed alongside the grid, so neither tier costs a
         # steady-state compile
@@ -260,6 +270,7 @@ class DecodeEngine:
                     model_cfg, cfg.page_size, max_pages
                 ),
                 label=f"decode.suffix_prefill[{name}]",
+                donate_argnums=(1,),
             )
         if self._prefix_cache or self._kv_swap:
             ex_fn, rs_fn, cp_fn = gen.paged_page_ops_fns(max_pages)
@@ -268,11 +279,13 @@ class DecodeEngine:
                     ex_fn, label=f"decode.kvswap.extract[{name}]"
                 )
                 self._restore = aot_jit(
-                    rs_fn, label=f"decode.kvswap.restore[{name}]"
+                    rs_fn, label=f"decode.kvswap.restore[{name}]",
+                    donate_argnums=(0,),
                 )
             if self._prefix_cache:
                 self._copy_page = aot_jit(
-                    cp_fn, label=f"decode.prefix.copy[{name}]"
+                    cp_fn, label=f"decode.prefix.copy[{name}]",
+                    donate_argnums=(0,),
                 )
         self._swap_store = None
         self._swap: Dict[_Request, Dict[str, object]] = {}
@@ -334,15 +347,19 @@ class DecodeEngine:
 
     def _run_step(self, *args):
         """Dispatch one batched decode step on the lowering chosen at
-        engine build, and record its wall and kernel dispatch. A
-        failure raises — the step is never rebuilt on another
-        lowering."""
+        engine build, and record its wall and kernel dispatch. Returns
+        ``(pool, next_tokens)``; the pool columns passed in (``args[1]``)
+        are donated — deleted by the call — so the caller rebinds
+        ``self._pool.columns`` to the returned ones. A failure raises —
+        the step is never rebuilt on another lowering."""
         from .. import kernels as _kernels
         from ..plan.lower import observe_strategy_wall
 
         t_step = time.perf_counter()
         out = self._step(*args)
         dt = time.perf_counter() - t_step
+        if args[2].shape[0] > self._step_memory_bucket:
+            self._note_step_memory(args)
         observe_strategy_wall(
             "decode_attention",
             "pallas_decode_attn" if self._attn_kernel is not None
@@ -362,6 +379,24 @@ class DecodeEngine:
                 "decode_attn", _kernels.interpret_mode()
             )
         return out
+
+    def _note_step_memory(self, args) -> None:
+        """Publish the memory plan of the step executable that served
+        ``args`` (the widest slot bucket so far) as the
+        ``tftpu_decode_step_alias_bytes`` / ``_temp_bytes`` gauges: the
+        evidence that the pool is written in place (alias = the pool's
+        bytes, temp well under one pool). Shapes only — the donated
+        columns in ``args`` are never touched. Left unset where the
+        executable offers no analysis."""
+        self._step_memory_bucket = int(args[2].shape[0])
+        try:
+            stats = self._step.executable(*args).memory_analysis()
+            alias = int(stats.alias_size_in_bytes)
+            temp = int(stats.temp_size_in_bytes)
+        except Exception:  # no executable or no analysis: leave unset
+            return
+        m.DECODE_STEP_ALIAS_BYTES.set(alias)
+        m.DECODE_STEP_TEMP_BYTES.set(temp)
 
     # -- introspection ------------------------------------------------------
 
@@ -447,39 +482,44 @@ class DecodeEngine:
 
     def _warm(self) -> None:
         """Execute every point of the slot × phase bucket grid once
-        against null tables (writes land in the null page, results are
-        discarded — the pool state object is never reassigned). Unlike
-        ``warm_program`` this executes, not just compiles: the grid is
-        tiny, and the run also faults in the gather/scatter kernels."""
+        against null tables (writes land in the null page, whose
+        contents are garbage by contract). Every program that returns
+        the pool takes it donated, so each call's result is threaded
+        into the next and ``self._pool.columns`` is rebound after every
+        call — a compile that fails mid-ladder leaves the pool holding
+        live arrays. Unlike ``warm_program`` this executes, not just
+        compiles: the grid is tiny, and the run also faults in the
+        gather/scatter kernels."""
         t0 = time.perf_counter()
-        cols = self._pool.columns
-        null = self._pool.null_table()
+        pool = self._pool
+        null = pool.null_table()
+        maxp = pool.max_pages_per_seq
         for tb in self._prefill_buckets:
-            self._prefill(
-                self.params, cols, np.zeros(tb, np.int32),
+            pool.columns, _ = self._prefill(
+                self.params, pool.columns, np.zeros(tb, np.int32),
                 np.int32(1), null,
             )
         for sb in self._slot_buckets:
-            self._run_step(
-                self.params, cols, np.zeros(sb, np.int32),
-                np.zeros(sb, np.int32),
-                np.zeros((sb, self._pool.max_pages_per_seq), np.int32),
+            pool.columns, _ = self._run_step(
+                self.params, pool.columns, np.zeros(sb, np.int32),
+                np.zeros(sb, np.int32), np.zeros((sb, maxp), np.int32),
             )
-        maxp = self._pool.max_pages_per_seq
         if self._suffix_prefill is not None:
             for tb in self._prefill_buckets:
-                self._suffix_prefill(
-                    self.params, cols, np.zeros(tb, np.int32),
+                pool.columns, _ = self._suffix_prefill(
+                    self.params, pool.columns, np.zeros(tb, np.int32),
                     np.int32(0), np.int32(1), null,
                 )
         if self._copy_page is not None:
             # null page onto itself — garbage by contract either way
-            self._copy_page(cols, np.int32(0), np.int32(0))
+            pool.columns = self._copy_page(
+                pool.columns, np.int32(0), np.int32(0)
+            )
         if self._extract is not None:
             idx = np.zeros(maxp, np.int32)
-            ex = self._extract(cols, idx)
-            self._restore(
-                cols, idx,
+            ex = self._extract(pool.columns, idx)
+            pool.columns = self._restore(
+                pool.columns, idx,
                 np.asarray(ex["k"]), np.asarray(ex["v"]),
                 np.asarray(ex["k_scale"]), np.asarray(ex["v_scale"]),
             )

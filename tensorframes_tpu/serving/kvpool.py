@@ -2,11 +2,14 @@
 
 The memory manager half of the iterative decode engine (ISSUE 11,
 vLLM-style). Device state is the columnar pool from
-``models.generation.init_paged_kv`` — int8 k/v plus f32 per-slot scales,
-page-major ``[num_pages, layers, heads, page_size, head_dim]`` — so the
-pool IS a set of frame columns with pages as rows (:meth:`as_frame`
-materializes the TensorFrame view; ROADMAP #3's data plane can later
-back these columns with its block store). This class owns the HOST side:
+``models.generation.init_paged_kv`` — int8 k/v page-major ``[num_pages,
+layers, page_size, heads*head_dim]`` plus f32 per-position-per-head
+scales ``[num_pages, layers, page_size, SCALE_LANES]`` (head ``h`` in
+lane ``h``), the ONE layout the pool is resident in, written in and read
+by the attention kernel in — so the pool IS a set of frame columns with
+pages as rows (:meth:`as_frame` materializes the TensorFrame view;
+ROADMAP #3's data plane can later back these columns with its block
+store). This class owns the HOST side:
 the free list, per-sequence page ownership, the page tables the step
 functions gather through, and (ISSUE 19) the two extra page lifecycles
 of the serving KV memory hierarchy:
@@ -67,10 +70,15 @@ def _chain_key(prev: bytes, tokens: np.ndarray) -> bytes:
 
 class PagedKVPool:
     """Fixed-size KV pages + per-sequence page tables over the columnar
-    pool state. ``columns`` holds the device arrays (reassigned by the
-    engine after every functional step); everything else is host-side
-    bookkeeping under the engine's scheduling thread (single-threaded
-    by design — the pool is not itself locked)."""
+    pool state. ``columns`` holds the device arrays. The donation
+    contract: every engine program that returns the pool takes
+    ``columns`` donated and writes it in place, so the arrays passed in
+    are DELETED by the call and the engine rebinds ``columns`` to what
+    came back — never keep a reference to a column across a step; read
+    through ``pool.columns`` on the engine thread (or on a stopped
+    engine). Everything else is host-side bookkeeping under the
+    engine's scheduling thread (single-threaded by design — the pool is
+    not itself locked)."""
 
     def __init__(self, cfg, num_pages: int, page_size: int,
                  max_pages_per_seq: int):
@@ -491,6 +499,31 @@ class PagedKVPool:
 
     # -- host-swap tier (blockstore-backed, ISSUE 15 + 19) -------------------
 
+    def page_shapes(self) -> Dict[str, List[int]]:
+        """Each column's per-page shape (everything after the page
+        axis). Every snapshot and swap segment carries it, and a
+        snapshot whose shapes differ — one written by a build with
+        another pool layout — is refused, never reinterpreted."""
+        return {k: [int(d) for d in v.shape[1:]]
+                for k, v in self.columns.items()}
+
+    def _same_page_shapes(self, snapshot: Dict[str, object]) -> bool:
+        got = snapshot.get("page_shapes")
+        return got is not None and {
+            k: list(v) for k, v in dict(got).items()
+        } == self.page_shapes()
+
+    def _check_page_shapes(self, snapshot: Dict[str, object],
+                           what: str) -> None:
+        if not self._same_page_shapes(snapshot):
+            raise PoolAccountingError(
+                f"{what}: snapshot page shapes "
+                f"{snapshot.get('page_shapes')} != this pool's "
+                f"{self.page_shapes()} — it was written by another pool "
+                "layout and cannot be reinterpreted; recompute the "
+                "sequences instead"
+            )
+
     def spill(self, store, swaps: Optional[Dict[str, Dict]] = None,
               swap_store=None) -> Dict[str, object]:
         """Snapshot the whole pool into a
@@ -545,6 +578,7 @@ class PagedKVPool:
             "num_pages": self.num_pages,
             "page_size": self.page_size,
             "max_pages_per_seq": self.max_pages_per_seq,
+            "page_shapes": self.page_shapes(),
         }
 
     def restore(self, store, snapshot: Dict[str, object],
@@ -566,6 +600,7 @@ class PagedKVPool:
                     f"restore into a pool with different {field}: "
                     f"snapshot {snapshot[field]}, pool {getattr(self, field)}"
                 )
+        self._check_page_shapes(snapshot, "restore")
         block = store.get(snapshot["ref"])
         if set(block) != set(self.columns):
             raise PoolAccountingError(
@@ -631,10 +666,9 @@ class PagedKVPool:
             except Exception:
                 continue
             new = {k: v for k, v in entry.items() if k != "ref"}
+            if not self._same_page_shapes(new):
+                continue  # another layout's segment: recompute on redrive
             new["ref"] = swap_store.put_spilled(seg)
-            if int(new.get("page_size", self.page_size)) != self.page_size:
-                swap_store.drop(new["ref"])
-                continue
             manifest[str(tid)] = new
         return manifest
 
@@ -660,7 +694,7 @@ class PagedKVPool:
             "ref": ref,
             "pages": len(pages),
             "freed": freed,
-            "page_size": self.page_size,
+            "page_shapes": self.page_shapes(),
         }
 
     def swap_in_seq(self, store, snapshot: Dict[str, object],
@@ -676,11 +710,7 @@ class PagedKVPool:
         re-acquired; re-sharing would need a content re-proof)."""
         from ..blockstore.store import BlockCorruptionError
 
-        if int(snapshot["page_size"]) != self.page_size:
-            raise PoolAccountingError(
-                f"swap_in_seq: snapshot page_size {snapshot['page_size']}"
-                f" != pool page_size {self.page_size}"
-            )
+        self._check_page_shapes(snapshot, "swap_in_seq")
         try:
             block = store.get(snapshot["ref"])
         except BlockCorruptionError:
@@ -695,7 +725,9 @@ class PagedKVPool:
     def as_frame(self):
         """The pool as a TensorFrame (one row per page, one column per
         pool array) — a materialized snapshot view for the data plane /
-        debugging, not a live alias."""
+        debugging, not a live alias. Call it on the engine thread or on
+        a stopped engine: a running engine's next step deletes the
+        arrays this reads (the donation contract)."""
         from ..frame import frame_from_arrays
 
         return frame_from_arrays(

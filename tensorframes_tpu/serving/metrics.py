@@ -173,6 +173,19 @@ DECODE_FREE_PAGES = _gauge(
     "Free pages across decode KV pools (the headroom preemption "
     "defends)",
 )
+DECODE_STEP_ALIAS_BYTES = _gauge(
+    "tftpu_decode_step_alias_bytes",
+    "Bytes of the widest compiled decode step's outputs that alias its "
+    "donated arguments (XLA memory_analysis). Equal to the KV pool's "
+    "bytes when the pool is written in place; the engine that built "
+    "its step last sets it",
+)
+DECODE_STEP_TEMP_BYTES = _gauge(
+    "tftpu_decode_step_temp_bytes",
+    "Temporary device bytes the widest compiled decode step plans "
+    "(XLA memory_analysis). Well under one KV pool when no program "
+    "copies or converts a pool column",
+)
 DECODE_PREEMPTIONS = _counter(
     "tftpu_decode_preemptions_total",
     "Running sequences preempted because the KV pool had no free page "

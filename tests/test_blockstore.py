@@ -576,6 +576,63 @@ def test_kvpool_spill_restore_bit_identical(tmp_path):
     st.close()
 
 
+def test_kvpool_refuses_snapshots_of_another_layout(tmp_path):
+    """A whole-pool spill or a swap segment written by a build with
+    another pool layout (the [pages, layers, heads, page, head_dim]
+    columns before PR 26, or one with no shapes recorded at all) is
+    refused with a clear error — its bytes are never reinterpreted —
+    before any pool state is touched."""
+    from tensorframes_tpu.models import generation as gen
+    from tensorframes_tpu.serving.kvpool import (
+        PagedKVPool, PoolAccountingError,
+    )
+
+    cfg = gen.gpt_tiny()
+    st = BlockStore(root=str(tmp_path / "kv"), budget_bytes=0)
+    pool = PagedKVPool(cfg, num_pages=9, page_size=4, max_pages_per_seq=4)
+    pool.alloc(1, 2)
+    snap = pool.spill(st)
+    assert snap["page_shapes"] == pool.page_shapes() == {
+        "k": [cfg.num_layers, 4, cfg.num_heads * cfg.head_dim],
+        "v": [cfg.num_layers, 4, cfg.num_heads * cfg.head_dim],
+        "k_scale": [cfg.num_layers, 4, 128],
+        "v_scale": [cfg.num_layers, 4, 128],
+    }
+    old_shapes = {
+        "k": [cfg.num_layers, cfg.num_heads, 4, cfg.head_dim],
+        "v": [cfg.num_layers, cfg.num_heads, 4, cfg.head_dim],
+        "k_scale": [cfg.num_layers, cfg.num_heads, 4, 1],
+        "v_scale": [cfg.num_layers, cfg.num_heads, 4, 1],
+    }
+    free_before = pool.num_free
+    for bad in (dict(snap, page_shapes=old_shapes),
+                {k: v for k, v in snap.items() if k != "page_shapes"}):
+        with pytest.raises(PoolAccountingError, match="another pool layout"):
+            pool.restore(st, bad)
+        assert pool.num_free == free_before and pool.owned(1)
+    # per-sequence swap segments carry the shapes too
+    payload = {
+        k: np.asarray(v)[1:3].copy() for k, v in pool.columns.items()
+    }
+    seg = pool.swap_out_seq(st, 1, payload)
+    assert seg["page_shapes"] == pool.page_shapes()
+    with pytest.raises(PoolAccountingError, match="another pool layout"):
+        pool.swap_in_seq(st, dict(seg, page_shapes=old_shapes), 2)
+    assert not pool.owned(2)
+    # ... and adopt_swapped skips a foreign segment instead of re-homing
+    swap = BlockStore(root=str(tmp_path / "swap"), budget_bytes=0)
+    folded = {"swapped": {
+        "ours": dict(seg), "theirs": dict(seg, page_shapes=old_shapes),
+    }}
+    assert set(pool.adopt_swapped(st, folded, swap)) == {"ours"}
+    pages, block = pool.swap_in_seq(st, seg, 2)
+    assert len(pages) == 2
+    for k in payload:
+        np.testing.assert_array_equal(np.asarray(block[k]), payload[k])
+    swap.close()
+    st.close()
+
+
 def test_kvpool_spill_folds_swap_segments(tmp_path):
     """PR 18 follow-up: per-sequence host-swap segments ride the
     whole-pool spill() snapshot (keyed by the request's cross-restart

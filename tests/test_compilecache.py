@@ -676,6 +676,52 @@ def test_aot_jit_weak_type_keys_apart_and_promotes_like_jit():
     assert len(f._builds.built) == 2 and not f._builds.failed
 
 
+def test_aot_jit_donation_keys_and_fingerprints_apart(store_dir):
+    """``aot_jit(donate_argnums=...)`` donates like ``jax.jit`` (the
+    argument is deleted, the result correct), and the donating
+    executable is told from the plain one of the same function in the
+    in-process key AND in the store's fingerprint — a fresh donating
+    instance loads the donating entry from disk, still donating."""
+    import jax.numpy as jnp
+
+    from tensorframes_tpu.ops.executor import aot_jit
+
+    def f(a, i):
+        return a.at[i].set(7.0)
+
+    def x():
+        return jnp.zeros((64, 8), jnp.float32)
+
+    i = jnp.asarray(3, jnp.int32)
+    plain = aot_jit(f, label="don")
+    donating = aot_jit(f, label="don", donate_argnums=(0,))
+    assert "donate_argnums" not in plain._decl
+    assert donating._decl["donate_argnums"] == [0]
+
+    a = x()
+    want = np.asarray(plain(a, i))
+    assert not a.is_deleted()
+    m0 = _counter_val("tftpu_compilecache_misses_total")
+    b = x()
+    got = np.asarray(donating(b, i))
+    assert b.is_deleted()
+    np.testing.assert_array_equal(got, want)
+    # same function, same shapes, same label: the plain entry just
+    # published must NOT have served the donating instance
+    assert _counter_val("tftpu_compilecache_misses_total") == m0 + 1
+    (kp,), (kd,) = plain._builds.built, donating._builds.built
+    assert kp != kd and kp[2:] == kd[2:]
+
+    h0 = _counter_val("tftpu_compilecache_hits_total")
+    c = x()
+    again = aot_jit(f, label="don", donate_argnums=(0,))  # fresh instance
+    np.testing.assert_array_equal(np.asarray(again(c, i)), want)
+    assert _counter_val("tftpu_compilecache_hits_total") == h0 + 1
+    assert c.is_deleted()  # the loaded executable donates too
+    stats = again.executable(x(), i).memory_analysis()
+    assert stats.alias_size_in_bytes == 64 * 8 * 4
+
+
 # ---------------------------------------------------------------------------
 # accounting split (ISSUE 5 satellite)
 # ---------------------------------------------------------------------------

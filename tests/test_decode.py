@@ -163,6 +163,13 @@ def test_kvpool_floor_and_table(model):
     fr = pool.as_frame()
     assert fr.num_rows == 5
     assert set(fr.schema.names) == {"k", "v", "k_scale", "v_scale"}
+    # one physical layout: a position's heads side by side in one int8
+    # row, its per-head scales in the lanes of one float32 row
+    shapes = {k: tuple(v.shape) for k, v in pool.columns.items()}
+    assert shapes["k"] == shapes["v"] == (
+        5, cfg.num_layers, 4, cfg.num_heads * cfg.head_dim)
+    assert shapes["k_scale"] == shapes["v_scale"] == (
+        5, cfg.num_layers, 4, 128)
 
 
 # ---------------------------------------------------------------------------
@@ -494,6 +501,87 @@ def test_register_decode_name_clash_with_flush_endpoint(model):
 
 
 # ---------------------------------------------------------------------------
+# Donation: the pool is written in place (PR 26)
+# ---------------------------------------------------------------------------
+
+def test_step_donates_the_pool_columns(model):
+    """The columns handed to a step are gone — deleted by the call —
+    and the pool holds what came back: nothing can read a stale pool,
+    and no second pool is ever resident."""
+    cfg, params = model
+    eng = DecodeEngine("t_donate", cfg, params, DecodeConfig(
+        max_slots=2, page_size=8, max_prompt_len=16, max_new_tokens=8,
+        warmup=False,
+    ))
+    pool = eng.pool
+    old = dict(pool.columns)
+    maxp = pool.max_pages_per_seq
+    cols, nxt = eng._run_step(
+        params, pool.columns, np.zeros(2, np.int32),
+        np.zeros(2, np.int32), np.zeros((2, maxp), np.int32),
+    )
+    assert all(c.is_deleted() for c in old.values())
+    assert not any(c.is_deleted() for c in cols.values())
+    assert np.asarray(nxt).shape == (2,)
+    with pytest.raises(RuntimeError):
+        np.asarray(old["k"])  # "the columns you passed are gone"
+    # prefill donates too
+    pool.columns = cols
+    pool.columns, _first = eng._prefill(
+        params, pool.columns, np.zeros(8, np.int32), np.int32(1),
+        pool.null_table(),
+    )
+    assert all(c.is_deleted() for c in cols.values())
+    eng.stop()
+
+
+@pytest.mark.parametrize("tiers", [
+    {}, {"prefix_cache": True}, {"kv_swap": True},
+    {"prefix_cache": True, "kv_swap": True},
+])
+def test_warmup_ladder_survives_donation(model, tiers):
+    """The warm-up ladder threads the donated pool through every
+    program of the grid (prefill and step buckets, suffix prefill,
+    copy-on-extend, swap extract/restore): the engine comes up holding
+    live columns and serves bit-identically to the oracle."""
+    cfg, params = model
+    eng = DecodeEngine("t_warm_donate", cfg, params, DecodeConfig(
+        max_slots=2, page_size=8, max_prompt_len=16, max_new_tokens=4,
+        **tiers,
+    ))
+    eng.start()
+    try:
+        assert not any(c.is_deleted() for c in eng.pool.columns.values())
+        prompt = _prompts(1, 9, 15, seed=26, vocab=cfg.vocab_size)[0]
+        got = eng.submit({"prompt": prompt}).result(120)["tokens"]
+        np.testing.assert_array_equal(got, _reference(model, prompt, 4))
+    finally:
+        eng.stop(drain=True, timeout=120)
+    eng.pool.check()
+
+
+def test_step_memory_gauges_say_the_pool_is_aliased(model):
+    """``tftpu_decode_step_alias_bytes`` = the pool's bytes (every
+    column written in place) and ``tftpu_decode_step_temp_bytes`` is
+    set, both read from the widest step executable's memory plan. (At
+    this toy size on the CPU the interpreter's temporaries exceed the
+    pool; tests/test_decode_compile.py holds the real step to "well
+    under one pool".)"""
+    cfg, params = model
+    eng = DecodeEngine("t_gauges", cfg, params, DecodeConfig(
+        max_slots=4, page_size=8, max_prompt_len=16, max_new_tokens=8,
+    ))
+    eng.start()
+    try:
+        assert eng._step_memory_bucket == eng._slot_buckets[-1]
+        assert sm.DECODE_STEP_ALIAS_BYTES.value == gen.paged_kv_nbytes(
+            eng.pool.columns)
+        assert sm.DECODE_STEP_TEMP_BYTES.value > 0
+    finally:
+        eng.stop(drain=True, timeout=120)
+
+
+# ---------------------------------------------------------------------------
 # Observability
 # ---------------------------------------------------------------------------
 
@@ -509,6 +597,8 @@ def test_decode_metrics_preregistered():
         "tftpu_decode_free_pages",
         "tftpu_decode_preemptions_total",
         "tftpu_decode_evictions_total",
+        "tftpu_decode_step_alias_bytes",
+        "tftpu_decode_step_temp_bytes",
     ):
         assert want in names, f"{want} not pre-registered"
     assert set(sm.DECODE_STEPS) == {"prefill", "decode"}

@@ -362,26 +362,30 @@ def test_ragged_rows_outs_zero_rows_returns_typed_empties():
 
 @pytest.mark.parametrize(
     "S,maxp,page,nh,hd",
-    [(1, 1, 4, 2, 8), (5, 3, 8, 4, 16), (8, 2, 16, 2, 4)],
+    [(1, 1, 4, 2, 8), (5, 3, 8, 4, 16), (8, 2, 16, 2, 4),
+     # heads that share a 128-lane block (GPT-2's 64), straddle blocks
+     # (96) and fill whole blocks (128): _head_sums/_head_spread's cases
+     (3, 2, 16, 12, 64), (2, 2, 8, 3, 96), (2, 2, 8, 2, 128)],
 )
 def test_paged_decode_attention_bit_identical(S, maxp, page, nh, hd):
     """Kernel vs its same-tiling emulation (bitwise) and vs the XLA
     gather→dequant→attend chain (float tolerance) across slot/page
-    mixes — including a padding slot with an all-null table."""
+    mixes — including a padding slot with an all-null table. The scale
+    rows' padding lanes hold garbage: no output may read them."""
     rng = np.random.default_rng(S * 7 + maxp)
     P, L = maxp * S + 1, 2
     q = jnp.asarray(rng.standard_normal((S, nh, hd)), jnp.float32)
     kp = jnp.asarray(
-        rng.integers(-127, 128, (P, L, nh, page, hd)), jnp.int8
+        rng.integers(-127, 128, (P, L, page, nh * hd)), jnp.int8
     )
     vp = jnp.asarray(
-        rng.integers(-127, 128, (P, L, nh, page, hd)), jnp.int8
+        rng.integers(-127, 128, (P, L, page, nh * hd)), jnp.int8
     )
     ks = jnp.asarray(
-        rng.uniform(0.01, 0.1, (P, L, nh, page, 1)), jnp.float32
+        rng.uniform(0.01, 0.1, (P, L, page, kda.SCALE_LANES)), jnp.float32
     )
     vs = jnp.asarray(
-        rng.uniform(0.01, 0.1, (P, L, nh, page, 1)), jnp.float32
+        rng.uniform(0.01, 0.1, (P, L, page, kda.SCALE_LANES)), jnp.float32
     )
     tables = jnp.asarray(
         rng.integers(1, P, (S, maxp)), jnp.int32
@@ -411,8 +415,8 @@ def test_ops_attention_paged_wrapper():
 
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((2, 2, 4)), jnp.float32)
-    kp = jnp.asarray(rng.integers(-5, 5, (3, 1, 2, 4, 4)), jnp.int8)
-    ks = jnp.ones((3, 1, 2, 4, 1), jnp.float32)
+    kp = jnp.asarray(rng.integers(-5, 5, (3, 1, 4, 2 * 4)), jnp.int8)
+    ks = jnp.ones((3, 1, 4, kda.SCALE_LANES), jnp.float32)
     tables = jnp.asarray([[1, 2], [0, 0]], jnp.int32)
     pos = jnp.asarray([5, 0], jnp.int32)
     got = paged_decode_attention(
