@@ -10,10 +10,11 @@ serializes scatter. This package holds the purpose-built kernels:
 * :mod:`.segment_reduce` — one fused pallas dispatch computing every
   (column, op) of a keyed reduction: sum/mean via the one-hot MXU
   contraction, min/max via masked VPU reductions, sorted-or-not ids.
-* :mod:`.decode_attention` — paged int8-KV decode attention: per-slot
-  pages stream HBM→VMEM through the page table (scalar-prefetch index
-  maps), dequantize in-register, and the attention math runs in the
-  same kernel — the gather→dequant→attend chain of
+* :mod:`.decode_attention` — paged int8-KV decode attention: the
+  pages a slot's context holds stream HBM→VMEM through the
+  scalar-prefetched page table, a chunk of them a fold, dequantize
+  in-register, and the attention math runs in the same kernel — the
+  gather→dequant→attend chain of
   ``models/generation.paged_decode_step_fn`` becomes ONE kernel with no
   materialized ``[S, pages, page, heads*hd]`` copy, reading the
   resident pool columns in the layout the KV write leaves them in.

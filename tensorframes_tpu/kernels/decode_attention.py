@@ -5,53 +5,68 @@ The XLA lowering of ``models/generation.paged_decode_step_fn`` runs
 decode attention as a chain: gather every slot's pages into a
 materialized ``[S, pages, page, heads*hd]`` HBM copy, dequantize, and
 attend. Decode is HBM-bandwidth-bound, so that copy IS the cost. This
-kernel fuses the chain: the grid walks ``(slot, page-table entry)``,
-each page streams HBM→VMEM **as int8** through a scalar-prefetched
-page-table index map (the vLLM paged-attention shape), scales ride
-along, and each page folds into a per-slot **online softmax** (running
-max, denominator and context accumulator in VMEM scratch). Nothing
-gathered ever touches HBM and nothing wider than one page is ever held
-dequantized.
+kernel fuses the chain and does work only where a slot has context.
+
+The walk (PR 29). The grid runs over slots alone. The pool columns
+stay in HBM (``pl.ANY``); for each slot an in-kernel loop with the
+slot's own trip count folds *chunks* of ``G`` table entries (about 128
+positions, :func:`chunk_pages`) into a per-slot **online softmax**:
+``pos // (G*page) + 1`` chunks, one for a padding slot. A chunk's pages
+are copied HBM→VMEM **as int8** by one async copy per page and column,
+page ids from the scalar-prefetched table, into one half of a double
+buffer while the other half is folded; under a slot's last fold the
+next slot's first chunk streams in. Only pages the context reaches are
+copied: the rows of a chunk beyond the slot's position keep whatever
+the buffer held and are *selected* out of both products (never
+multiplied by a zero weight), so no page beyond a context is ever read
+and a table entry beyond it may point anywhere. The engine counts the
+table entries the chunks cover against the whole table's
+(``tftpu_decode_attn_pages_walked_total`` / ``..._grid_total``): the
+share of a whole-table walk that the contexts make necessary.
+
+The fold is two matmuls with heads on sublanes and the chunk's
+positions on lanes. Scores: ``q`` is laid out as ``[head_rows,
+heads*hd]`` with head ``h``'s values on row ``h`` (zeros elsewhere) and
+contracted with the chunk's int8 key rows — every head's scores in one
+MXU pass, as ``[head_rows, rows]``. Context: the weights times the
+value rows give ``[head_rows, heads*hd]``, of which row ``h`` is
+meaningful on head ``h``'s lanes; the diagonal blocks are picked once
+per slot. The matmuls run in bfloat16 with float32 accumulation and
+round nothing: int8 rows are exact in bfloat16, and a float32 operand
+(``q``, the softmax weights times the value scales) goes in as three
+bfloat16 pieces that sum back to it (:func:`_split3`), so every product
+is exact and only the order of the float32 sums differs from the plain
+formulation. The pool's scale rows ``[rows, SCALE_LANES]`` (head ``h``
+in lane ``h``) are transposed to that layout in-kernel.
 
 One physical layout (PR 26). The kernel's operands ARE the resident
 pool columns of ``models/generation.init_paged_kv``: k/v
 ``[P, L, page, heads*hd]`` int8 and scales ``[P, L, page, SCALE_LANES]``
-float32, read through ``(1, 1, page, ·)`` blocks. Their row-major
-default layout is the one the step's KV scatter writes and the one
-Mosaic reads, so no program converts a pool column: the earlier
-``[P, L, heads, page, hd]`` shape cost a whole-pool layout copy per
-column per layer (the ``(page, hd)`` and ``(page, 1)`` minor dims pad
-to the 128-lane tile, and XLA keeps such an array in another layout
-than Mosaic demands).
+float32. Their row-major default layout is the one the step's KV
+scatter writes and the one Mosaic copies ``[page, ·]`` slabs from, so no
+program converts a pool column.
 
-Mosaic shape discipline: every array in the body is 2-D
-``[rows, lanes]`` with the page position on sublanes. Heads lie side
-by side on the lanes of a k/v row, and per-head quantities (scores,
-weights, running max and denominator) are *compact* ``[rows,
-SCALE_LANES]`` arrays with head ``h`` in lane ``h`` — the layout the
-pool's scale rows have, so scores and scales multiply with no
-relayout. :func:`_head_sums` folds a ``[rows, heads*hd]`` array to
-compact form (masked lane reductions over 128-lane blocks) and
-:func:`_head_spread` spreads a compact array back over each head's
-``hd`` lanes; both use only aligned 128-lane slices, lane iotas and
-selects (no lane-offset slice, no reshape, no dynamic-offset store).
-``q`` enters as ``[S, 1, heads*hd]`` float32 and the context leaves the
-same way; the activation-dtype casts happen outside the kernel.
+Mosaic under this package's x64: a literal index or divisor traces
+i64, which Mosaic cannot legalize, so indices are ``np.int32`` scalars,
+zeros derive from a grid index (``s - s``), and integer division is
+``lax.div`` on int32 (``//`` and ``%`` trace through an i64 helper).
 
 Equality gates: the kernel is bit-identical on the CPU pallas
-interpreter to :func:`paged_attention_emulation` — the same per-page
-update (:func:`_page_update`) folded in the same order in plain jnp —
-and agrees with the whole-horizon XLA chain
+interpreter to :func:`paged_attention_emulation` — the same chunk
+update (:func:`_chunk_update`) folded over the same chunks in the same
+order in plain jnp — and agrees with the whole-horizon XLA chain
 (:func:`paged_attention_reference`, the production non-kernel
 lowering) to float tolerance: an online softmax reassociates the
-denominator, so the two are not bitwise equal. The engine-level gates
-(batched==solo, preemption replay) hold whichever lowering the cost
-model picks because the choice is made once per engine, not per step.
+denominator, so the two are not bitwise equal. ``G`` depends on the
+page size and the table's width only, never on the slot count, so a
+solo step and a batched step fold a slot's chunks identically: the
+engine-level gates (batched==solo, preemption replay) hold, and they
+hold whichever lowering the cost model picks because the choice is made
+once per engine, not per step.
 
-Null-page handling is inherited unchanged: padding slots carry
-all-null tables (every gathered page is page 0) and real slots mask to
-``position <= pos``, so the null page's garbage never reaches an
-unmasked score — the same invariant the XLA chain relies on.
+Null-page handling is inherited: padding slots carry all-null tables
+and position 0, so they fold the null page's first row (the row the
+step's own scatter just wrote) and nothing else.
 """
 
 from __future__ import annotations
@@ -66,85 +81,116 @@ from jax import lax
 
 _NEG = -1e30
 
-# Lanes of a pool scale row (and of every compact per-head array): one
-# TPU vector register's width, so a page's ``[page, SCALE_LANES]`` scale
-# block is whole (8, 128) float32 tiles. Lane ``h < heads`` holds head
-# ``h``; the lanes beyond are padding that no output reads.
+# Lanes of a pool scale row: one TPU vector register's width, so a
+# page's ``[page, SCALE_LANES]`` scale block is whole (8, 128) float32
+# tiles. Lane ``h < heads`` holds head ``h``; the lanes beyond are
+# padding that no output reads.
 SCALE_LANES = 128
 
 
-def _head_sums(x, nh: int, hd: int):
-    """``[rows, nh*hd]`` → compact ``[rows, SCALE_LANES]``: lane ``h``
-    holds the sum over head ``h``'s ``hd`` lanes (lanes >= nh hold 0).
-    One masked lane reduction per head and 128-lane block it touches."""
-    width = x.shape[-1]
-    lane = lax.broadcasted_iota(jnp.int32, (1, SCALE_LANES), 1)
-    out = jnp.zeros((x.shape[0], SCALE_LANES), jnp.float32)
-    for h in range(nh):
-        lo, hi = h * hd, (h + 1) * hd
-        total = None
-        for b in range(lo // SCALE_LANES, (hi - 1) // SCALE_LANES + 1):
-            start = b * SCALE_LANES
-            blk = x[:, start:min(start + SCALE_LANES, width)]
-            w = blk.shape[1]
-            if start < lo or start + w > hi:
-                at = start + lax.broadcasted_iota(jnp.int32, (1, w), 1)
-                blk = jnp.where((at >= lo) & (at < hi), blk,
-                                np.float32(0))
-            part = jnp.sum(blk, axis=-1, keepdims=True)
-            total = part if total is None else total + part
-        out = jnp.where(lane == h, total, out)
-    return out
+def chunk_pages(page: int, maxp: int) -> int:
+    """Table entries folded per online-softmax update: about 128
+    positions (the lane width of the scores), never more than the table
+    holds. A function of ``page`` and ``maxp`` alone — never of the
+    slot count — so the solo step and every batched bucket fold the
+    same chunks in the same order (the engine's batched==solo
+    bit-identity)."""
+    return min(max(1, 128 // int(page)), int(maxp))
 
 
-def _head_spread(c, nh: int, hd: int):
-    """Compact ``[rows, SCALE_LANES]`` → ``[rows, nh*hd]``: head ``h``'s
-    value (lane ``h``) over its ``hd`` lanes — the inverse placement of
-    :func:`_head_sums`. Exact: a lane is picked, never summed with
-    another value."""
-    width = nh * hd
-    lane = lax.broadcasted_iota(jnp.int32, (1, SCALE_LANES), 1)
-    cols = [
-        jnp.sum(jnp.where(lane == h, c, np.float32(0)), axis=-1,
-                keepdims=True)
-        for h in range(nh)
-    ]
-    blocks = []
-    for start in range(0, width, SCALE_LANES):
-        w = min(SCALE_LANES, width - start)
-        at = start + lax.broadcasted_iota(jnp.int32, (1, w), 1)
-        first = start // hd
-        blk = jnp.broadcast_to(cols[first], (c.shape[0], w))
-        for h in range(first + 1, (start + w - 1) // hd + 1):
-            blk = jnp.where(at >= h * hd, cols[h], blk)
-        blocks.append(blk)
-    return blocks[0] if len(blocks) == 1 else jnp.concatenate(
-        blocks, axis=-1
-    )
+def pages_walked(pos, page: int, maxp: int):
+    """Table entries covered by the chunks the kernel folds for slots at
+    positions ``pos`` (a numpy array): a slot's context is ``pos + 1``
+    positions, so it folds ``pos // (G*page) + 1`` chunks of ``G``
+    entries (the last chunk of a table that ``G`` does not divide is
+    short); a padding slot (``pos`` 0) folds one. The one-page-a-step
+    grid this walk replaced ran ``maxp`` entries for every slot."""
+    g = chunk_pages(page, maxp)
+    return np.minimum((pos // (g * page) + 1) * g, maxp)
 
 
-def _page_update(q, k8, v8, ks, vs, valid, m, l, acc, sm_scale: float,
-                 nh: int, hd: int):
-    """Fold one KV page into a slot's online softmax — THE shared math
-    of the kernel body and the plain-jnp emulation (same ops, same
-    order, same dtypes, so the two are bit-identical on CPU).
+def _head_rows(nh: int) -> int:
+    """Heads padded to whole bfloat16 tiles of 16 sublanes."""
+    return -(-nh // 16) * 16
 
-    ``q`` [1, nh*hd] f32; ``k8``/``v8`` [page, nh*hd] int8; ``ks``/
-    ``vs`` [page, SCALE_LANES] f32; ``valid`` [page, 1] bool; running
-    ``m``/``l`` compact [1, SCALE_LANES] and ``acc`` [1, nh*hd], all
-    f32."""
+
+def _own_lanes(nh: int, hd: int):
+    """``[head_rows, nh*hd]`` bool: row ``h`` owns head ``h``'s lanes
+    (the rows beyond ``nh`` own none)."""
+    shape = (_head_rows(nh), nh * hd)
+    head = lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= head * hd) & (lane < head * hd + hd)
+
+
+def _split3(x):
+    """float32 ``[n, ·]`` → bfloat16 ``[3n, ·]``: three pieces that sum
+    back to ``x`` exactly (8 + 8 + 8 significand bits), stacked on the
+    rows — what lets a one-pass bfloat16 matmul carry a float32 operand
+    unrounded. :func:`_sum3` adds the three row blocks of the product."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    a = x.astype(bf16)
+    r = x - a.astype(f32)
+    b = r.astype(bf16)
+    c = (r - b.astype(f32)).astype(bf16)
+    return jnp.concatenate([a, b, c], axis=0)
+
+
+def _sum3(x):
+    n = x.shape[0] // 3
+    return (x[:n] + x[n:2 * n]) + x[2 * n:]
+
+
+def _q_rows(q, nh: int, hd: int):
+    """``q`` [1, nh*hd] f32 → ``[3*head_rows, nh*hd]`` bf16: row ``h``
+    holds head ``h``'s values on its own lanes and 0 elsewhere, so one
+    matmul against a chunk's key rows gives every head's scores."""
+    return _split3(jnp.where(_own_lanes(nh, hd), q, np.float32(0)))
+
+
+def _fold_init(nh: int, hd: int):
+    """A slot's running max, denominator ``[head_rows, 1]`` and context
+    accumulator ``[head_rows, nh*hd]`` before its first chunk."""
     f32 = jnp.float32
-    s = _head_sums(q * k8.astype(f32), nh, hd) * sm_scale
-    s = jnp.where(valid, s * ks, np.float32(_NEG))
-    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+    hr = _head_rows(nh)
+    return (jnp.full((hr, 1), _NEG, f32), jnp.zeros((hr, 1), f32),
+            jnp.zeros((hr, nh * hd), f32))
+
+
+def _chunk_update(qx, k, v, ks, vs, valid, m, l, acc, sm_scale: float,
+                  nh: int):
+    """Fold one chunk of KV rows into a slot's online softmax — THE
+    shared math of the kernel body and the plain-jnp emulation (same
+    ops, same order, same dtypes, so the two are bit-identical on CPU).
+
+    ``qx`` from :func:`_q_rows`; ``k``/``v`` [rows, nh*hd] bf16 (the
+    int8 rows, converted: exact); ``ks``/``vs`` [rows, SCALE_LANES]
+    f32; ``valid`` [1, rows] bool; running ``m``/``l`` [head_rows, 1]
+    and ``acc`` [head_rows, nh*hd] f32 (row ``h`` meaningful on head
+    ``h``'s lanes). A row that is not ``valid`` may hold anything (a
+    page the walk never copied: stale VMEM, NaN scales): its score and
+    its weight are selected away, not multiplied by 0."""
+    f32 = jnp.float32
+    hr = _head_rows(nh)
+    s = _sum3(lax.dot_general(
+        qx, k, (((1,), (1,)), ((), ())), preferred_element_type=f32
+    )) * sm_scale                                     # [head_rows, rows]
+    s = jnp.where(valid, s * ks.T[:hr], np.float32(_NEG))
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
-    l_new = l * corr + jnp.sum(p, axis=0, keepdims=True)
-    pv = _head_spread(p * vs, nh, hd) * v8.astype(f32)
-    acc_new = acc * _head_spread(corr, nh, hd) + jnp.sum(
-        pv, axis=0, keepdims=True
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    w = jnp.where(valid, p * vs.T[:hr], np.float32(0))
+    o = _sum3(jnp.dot(_split3(w), v, preferred_element_type=f32))
+    return m_new, l_new, acc * corr + o
+
+
+def _fold_finish(l, acc, nh: int, hd: int):
+    """``[1, nh*hd]`` context: each head's own lanes of its row."""
+    return jnp.sum(
+        jnp.where(_own_lanes(nh, hd), acc / l, np.float32(0)),
+        axis=0, keepdims=True,
     )
-    return m_new, l_new, acc_new
 
 
 def _check_pool(q, k_pages, k_scale):
@@ -168,7 +214,7 @@ def paged_decode_attention(
     v_pages: jnp.ndarray,    # [P, L, page, nh*hd] int8
     k_scale: jnp.ndarray,    # [P, L, page, SCALE_LANES] f32
     v_scale: jnp.ndarray,    # [P, L, page, SCALE_LANES] f32
-    layer: int,              # static layer index
+    layer: int,              # layer index
     tables: jnp.ndarray,     # [S, maxp] int32 page tables
     pos: jnp.ndarray,        # [S] int32 current positions
     interpret: Optional[bool] = None,
@@ -177,72 +223,129 @@ def paged_decode_attention(
     ``[S, nh, hd]`` context in ``q.dtype``. Traceable (callers embed it
     in the jitted decode step); ``interpret`` defaults to the backend's
     :func:`tensorframes_tpu.kernels.interpret_mode`."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     from . import interpret_mode
 
     if interpret is None:
         interpret = interpret_mode()
     _check_pool(q, k_pages, k_scale)
+    return _paged_walk(
+        np.asarray([layer], np.int32), tables.astype(jnp.int32),
+        pos.astype(jnp.int32), q, k_pages, v_pages, k_scale, v_scale,
+        interpret=bool(interpret),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
+                v_scale, *, interpret: bool):
+    """The kernel call. Jitted with the layer an operand so that a
+    step's twelve layers trace and lower ONE kernel: traced per layer,
+    the kernel was most of what ``server.start()`` spends on a warm
+    start (the AOT store's key needs each program's jaxpr)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
     S, nh, hd = q.shape
     width = nh * hd
     page = int(k_pages.shape[2])
     maxp = int(tables.shape[1])
-    li = int(layer)
-    f32 = jnp.float32
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, np.int32
     sm_scale = 1.0 / float(np.sqrt(hd))
+    G = chunk_pages(page, maxp)
+    rows = G * page
 
-    def kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-               o_ref, m_ref, l_ref, acc_ref):
+    def kernel(layer_ref, tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, ks_hbm,
+               vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, half_ref):
         s = pl.program_id(0)
-        j = pl.program_id(1)
+        zero = s - s
+        li = layer_ref[0]
+        pos_s = pos_ref[s]
+        columns = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                   (vs_hbm, vs_buf))
 
-        @pl.when(j == 0)
-        def _init():
-            m_ref[...] = jnp.full(m_ref.shape, _NEG, f32)
-            l_ref[...] = jnp.zeros(l_ref.shape, f32)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+        def chunk_copies(slot, c, half, start):
+            """Start, or await, the copies of slot ``slot``'s chunk
+            ``c`` into buffer ``half``: one copy per column and page the
+            slot's context reaches, none beyond it — so a chunk past
+            the slot's last is no copy at all."""
+            last = lax.div(pos_ref[slot], i32(page))
+            for g in range(G):
+                j = c * G + g
 
-        kpos = j * page + lax.broadcasted_iota(jnp.int32, (page, 1), 0)
-        m_new, l_new, acc_new = _page_update(
-            q_ref[0], k_ref[0, 0], v_ref[0, 0], ks_ref[0, 0],
-            vs_ref[0, 0], kpos <= pos_ref[s],
-            m_ref[...], l_ref[...], acc_ref[...], sm_scale, nh, hd,
-        )
-        m_ref[...] = m_new
-        l_ref[...] = l_new
-        acc_ref[...] = acc_new
+                @pl.when(j <= last)
+                def _():
+                    # awaiting needs the copy's size, not its source
+                    pg = tbl_ref[slot, j] if start else zero
+                    for i, (hbm, buf) in enumerate(columns):
+                        cp = pltpu.make_async_copy(
+                            hbm.at[pg, li], buf.at[half, i32(g)],
+                            sem.at[half, i32(i)],
+                        )
+                        cp.start() if start else cp.wait()
 
-        @pl.when(j == maxp - 1)
-        def _finish():
-            o_ref[0] = acc_new / _head_spread(l_new, nh, hd)
+        @pl.when(s == 0)
+        def _first():
+            half_ref[0] = zero
+            chunk_copies(s, zero, zero, True)
 
-    # Every index-map component derives from a grid index (``j - j``
-    # zeros): this package enables x64 at import, under which literal
-    # ints trace i64 beside the i32 grid index and Mosaic fails to
-    # legalize the mixed-type func.return (the ops/segment.py lesson).
-    def page_map(s, j, tbl, p):
-        return (tbl[s, j], (j - j) + li, j - j, j - j)
+        half0 = half_ref[0]
+        n = lax.div(pos_s, i32(rows)) + 1
+        half_ref[0] = (half0 + n) & 1
+        qx = _q_rows(q_ref[0], nh, hd)
 
-    def slot_map(s, j, tbl, p):
-        return (s, j - j, j - j)
+        def fold(c, carry):
+            half = (half0 + c) & 1
+            # look ahead into the other half, which chunk c-1 has left:
+            # this slot's next chunk or, under its last (where that is
+            # no copy at all), the next slot's first
+            chunk_copies(s, c + 1, 1 - half, True)
 
+            @pl.when((c == n - 1) & (s + 1 < S))
+            def _next_slot():
+                chunk_copies(jnp.minimum(s + 1, S - 1), zero, 1 - half,
+                             True)
+
+            chunk_copies(s, c, half, False)
+
+            def chunk(buf, dtype):
+                return jnp.concatenate(
+                    [buf[half, i32(g)].astype(dtype) for g in range(G)],
+                    axis=0,
+                )
+
+            kpos = c * rows + lax.broadcasted_iota(
+                jnp.int32, (1, rows), 1
+            )
+            return _chunk_update(
+                qx, chunk(k_buf, bf16), chunk(v_buf, bf16),
+                chunk(ks_buf, f32), chunk(vs_buf, f32), kpos <= pos_s,
+                *carry, sm_scale, nh,
+            )
+
+        _, l, acc = lax.fori_loop(zero, n, fold, _fold_init(nh, hd))
+        o_ref[0] = _fold_finish(l, acc, nh, hd)
+
+    def slot_map(s, lay, tbl, p):
+        return (s, s - s, s - s)
+
+    pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, maxp),
-        in_specs=[
-            pl.BlockSpec((1, 1, width), slot_map),
-            pl.BlockSpec((1, 1, page, width), page_map),
-            pl.BlockSpec((1, 1, page, width), page_map),
-            pl.BlockSpec((1, 1, page, SCALE_LANES), page_map),
-            pl.BlockSpec((1, 1, page, SCALE_LANES), page_map),
-        ],
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, 1, width), slot_map), pool, pool,
+                  pool, pool],
         out_specs=pl.BlockSpec((1, 1, width), slot_map),
         scratch_shapes=[
-            pltpu.VMEM((1, SCALE_LANES), f32),
-            pltpu.VMEM((1, SCALE_LANES), f32),
-            pltpu.VMEM((1, width), f32),
+            # the double buffer: [half, page of the chunk, page, ·],
+            # each copy's target a whole [page, ·] slab
+            pltpu.VMEM((2, G, page, width), jnp.int8),
+            pltpu.VMEM((2, G, page, width), jnp.int8),
+            pltpu.VMEM((2, G, page, SCALE_LANES), f32),
+            pltpu.VMEM((2, G, page, SCALE_LANES), f32),
+            pltpu.SemaphoreType.DMA((2, 4)),
+            # which half holds this slot's first chunk (carried from
+            # the slot before, which started its copies)
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
@@ -252,9 +355,8 @@ def paged_decode_attention(
         interpret=bool(interpret),
         name="paged_decode_attention",
     )(
-        tables.astype(jnp.int32), pos.astype(jnp.int32),
-        q.astype(f32).reshape(S, 1, width), k_pages, v_pages, k_scale,
-        v_scale,
+        layer, tables, pos, q.astype(f32).reshape(S, 1, width), k_pages,
+        v_pages, k_scale, v_scale,
     )
     return out.reshape(S, nh, hd).astype(q.dtype)
 
@@ -264,40 +366,49 @@ def paged_attention_emulation(
     q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos
 ):
     """Plain-jnp emulation of the kernel's exact computation — the
-    bit-identity oracle: the same :func:`_page_update` folded over the
-    page table in the same order, no pallas anywhere. Jitted and
-    written as loops (slots mapped, pages a ``fori_loop``), because the
-    interpreter runs the grid as a loop around ONE compiled body:
-    unrolled or op-by-op, XLA:CPU fuses a page's update with its
-    neighbours and rounds ``acc * corr + sum`` differently."""
+    bit-identity oracle: the same :func:`_chunk_update` folded over the
+    same chunks of the page table in the same order, no pallas
+    anywhere. Jitted and written as loops (slots mapped, chunks a
+    ``fori_loop`` with the slot's trip count), as the interpreter runs
+    the grid: a loop around ONE compiled body."""
     _check_pool(q, k_pages, k_scale)
     S, nh, hd = q.shape
     width = nh * hd
     page = int(k_pages.shape[2])
     maxp = int(tables.shape[1])
     li = int(layer)
-    f32 = jnp.float32
+    f32, bf16 = jnp.float32, jnp.bfloat16
     sm_scale = 1.0 / float(np.sqrt(hd))
     qf = q.astype(f32).reshape(S, 1, width)
+    G = chunk_pages(page, maxp)
+    rows = G * page
 
     def slot(s):
-        def fold(j, carry):
-            pg = tables[s, j]
-            kpos = j * page + lax.broadcasted_iota(
-                jnp.int32, (page, 1), 0
-            )
-            return _page_update(
-                qf[s], k_pages[pg, li], v_pages[pg, li],
-                k_scale[pg, li], v_scale[pg, li], kpos <= pos[s],
-                *carry, sm_scale, nh, hd,
+        qx = _q_rows(qf[s], nh, hd)
+
+        def fold(c, carry):
+            # entries past the slot's last page are never copied by the
+            # kernel; whatever stands there is selected away, so the
+            # last real page does as well as any
+            pgs = tables[s, jnp.minimum(c * G + jnp.arange(G),
+                                        pos[s] // page)]
+            kpos = c * rows + lax.broadcasted_iota(
+                jnp.int32, (1, rows), 1
             )
 
-        _, l, acc = lax.fori_loop(0, maxp, fold, (
-            jnp.full((1, SCALE_LANES), _NEG, f32),
-            jnp.zeros((1, SCALE_LANES), f32),
-            jnp.zeros((1, width), f32),
-        ))
-        return acc / _head_spread(l, nh, hd)
+            def chunk(col, dtype):
+                return col[pgs, li].reshape(rows, -1).astype(dtype)
+
+            return _chunk_update(
+                qx, chunk(k_pages, bf16), chunk(v_pages, bf16),
+                chunk(k_scale, f32), chunk(v_scale, f32),
+                kpos <= pos[s], *carry, sm_scale, nh,
+            )
+
+        _, l, acc = lax.fori_loop(
+            0, pos[s] // rows + 1, fold, _fold_init(nh, hd)
+        )
+        return _fold_finish(l, acc, nh, hd)
 
     out = lax.map(slot, jnp.arange(S))
     return out.reshape(S, nh, hd).astype(q.dtype)

@@ -343,15 +343,16 @@ def paged_decode_attention(
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused paged int8-KV decode attention (ISSUE 12): one kernel
-    gathers each slot's pages through its page table (scalar-prefetch
-    index maps, pages stream HBM→VMEM as int8), dequantizes
-    in-register, and computes the masked softmax attention — the
-    public face of ``kernels/decode_attention.paged_decode_attention``.
+    walks the pages each slot's context holds through its page table
+    (scalar-prefetched; pages stream HBM→VMEM as int8, a chunk of them
+    a fold), dequantizes in-register, and computes the masked softmax
+    attention — the public face of
+    ``kernels/decode_attention.paged_decode_attention``.
     ``q`` [slots, heads, head_dim]; the pool arrays are the
     ``models/generation.init_paged_kv`` columns (k/v ``[pages, layers,
     page, heads*head_dim]`` int8, scales ``[pages, layers, page, 128]``
     float32), read where they lie. Bit-identical on the
-    CPU interpreter to its same-tiling emulation and within float
+    CPU interpreter to its same-chunks emulation and within float
     tolerance of the XLA gather→dequant→attend chain (asserted in
     tests). The serving decode engine selects it per engine via the
     cost model (``plan/rules.decide_decode_attention``)."""

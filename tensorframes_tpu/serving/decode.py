@@ -184,9 +184,11 @@ class DecodeEngine:
     def __init__(self, name: str, model_cfg, params,
                  config: Optional[DecodeConfig] = None):
         from ..compilecache import decode_warmup_grid
+        from ..kernels.decode_attention import pages_walked
         from ..models import generation as gen
         from ..ops.executor import aot_jit
 
+        self._pages_walked = pages_walked
         self.name = name
         self.cfg = model_cfg
         self.params = params
@@ -1222,8 +1224,18 @@ class DecodeEngine:
         self._pool.columns = cols
         nxt = np.asarray(nxt)
         m.DECODE_STEPS["decode"].inc()
+        # the kernel folds the chunks each row's context reaches; the
+        # XLA chain gathers and attends the whole table
+        walked = grid = sb * maxp
+        if self._attn_kernel == "pallas":
+            walked = int(self._pages_walked(
+                pos, self._pool.page_size, maxp
+            ).sum())
+        m.DECODE_ATTN_PAGES_WALKED.inc(walked)
+        m.DECODE_ATTN_PAGES_GRID.inc(grid)
         if _events.TRACER.enabled:
-            args = {"slots": n, "bucket": sb}
+            args = {"slots": n, "bucket": sb, "pages_walked": walked,
+                    "pages_grid": grid}
             rids = [s.req.trace_id for s in active if s.req.trace_id]
             if rids:
                 args["request_ids"] = rids[:16]

@@ -29,6 +29,7 @@ __all__ = [
     "REQUEST_LATENCY", "QUEUE_WAIT", "DISPATCH_SECONDS",
     "DEADLINE_EXPIRED", "DISPATCH_ERRORS", "rejected",
     "DECODE_PHASES", "DECODE_TOKENS", "DECODE_STEPS", "DECODE_TTFT",
+    "DECODE_ATTN_PAGES_WALKED", "DECODE_ATTN_PAGES_GRID",
     "DECODE_SLOTS", "DECODE_FREE_PAGES", "DECODE_PREEMPTIONS",
     "DECODE_EVICTIONS",
     "KVSWAP_OUTS", "KVSWAP_RESUMES", "KVSWAP_FALLBACKS", "KVSWAP_BYTES",
@@ -158,6 +159,19 @@ DECODE_STEPS: Dict[str, Counter] = {
     )
     for p in DECODE_PHASES
 }
+DECODE_ATTN_PAGES_WALKED = _counter(
+    "tftpu_decode_attn_pages_walked_total",
+    "Page-table entries covered by the chunks the decode-attention "
+    "kernel folds, summed over the rows of every decode step's slot "
+    "bucket (a padding row folds one chunk): follows the slots' "
+    "contexts",
+)
+DECODE_ATTN_PAGES_GRID = _counter(
+    "tftpu_decode_attn_pages_grid_total",
+    "Page-table entries of every decode step's slot bucket (bucket x "
+    "max pages a sequence): what a walk of the whole table costs; "
+    "walked / grid is the share of it the kernel still runs",
+)
 DECODE_TTFT = _histogram(
     "tftpu_decode_ttft_seconds",
     "Time to first token: submit to the prompt's prefill completing "

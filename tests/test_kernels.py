@@ -360,40 +360,70 @@ def test_ragged_rows_outs_zero_rows_returns_typed_empties():
 # paged decode attention
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize(
-    "S,maxp,page,nh,hd",
-    [(1, 1, 4, 2, 8), (5, 3, 8, 4, 16), (8, 2, 16, 2, 4),
-     # heads that share a 128-lane block (GPT-2's 64), straddle blocks
-     # (96) and fill whole blocks (128): _head_sums/_head_spread's cases
-     (3, 2, 16, 12, 64), (2, 2, 8, 3, 96), (2, 2, 8, 2, 128)],
-)
-def test_paged_decode_attention_bit_identical(S, maxp, page, nh, hd):
-    """Kernel vs its same-tiling emulation (bitwise) and vs the XLA
-    gather→dequant→attend chain (float tolerance) across slot/page
-    mixes — including a padding slot with an all-null table. The scale
-    rows' padding lanes hold garbage: no output may read them."""
-    rng = np.random.default_rng(S * 7 + maxp)
-    P, L = maxp * S + 1, 2
+def _paged_case(rng, S, maxp, page, nh, hd, pos=None, padding=1, L=2):
+    """A random pool, distinct pages per slot, the last ``padding``
+    slots padding slots (all-null table, position 0). ``pos``: the live
+    slots' positions (random when None). The scale rows' padding lanes
+    hold garbage: no output may read them."""
+    P = maxp * S + 1
     q = jnp.asarray(rng.standard_normal((S, nh, hd)), jnp.float32)
-    kp = jnp.asarray(
-        rng.integers(-127, 128, (P, L, page, nh * hd)), jnp.int8
+    kp, vp = (
+        jnp.asarray(rng.integers(-127, 128, (P, L, page, nh * hd)),
+                    jnp.int8)
+        for _ in range(2)
     )
-    vp = jnp.asarray(
-        rng.integers(-127, 128, (P, L, page, nh * hd)), jnp.int8
-    )
-    ks = jnp.asarray(
-        rng.uniform(0.01, 0.1, (P, L, page, kda.SCALE_LANES)), jnp.float32
-    )
-    vs = jnp.asarray(
-        rng.uniform(0.01, 0.1, (P, L, page, kda.SCALE_LANES)), jnp.float32
+    ks, vs = (
+        jnp.asarray(
+            rng.uniform(0.01, 0.1, (P, L, page, kda.SCALE_LANES)),
+            jnp.float32,
+        )
+        for _ in range(2)
     )
     tables = jnp.asarray(
-        rng.integers(1, P, (S, maxp)), jnp.int32
-    ).at[-1].set(0)  # padding slot: all-null table
+        1 + rng.permutation(P - 1).reshape(S, maxp), jnp.int32
+    ).at[S - padding:].set(0)  # padding slots: all-null tables
+    if pos is None:
+        pos = rng.integers(0, maxp * page, S)
     pos = jnp.asarray(
-        rng.integers(0, maxp * page, S), jnp.int32
-    ).at[-1].set(0)
-    for li in range(L):
+        np.resize(np.asarray(pos), S), jnp.int32
+    ).at[S - padding:].set(0)
+    return q, kp, vp, ks, vs, tables, pos
+
+
+@pytest.mark.parametrize(
+    "S,maxp,page,nh,hd,pos",
+    [(1, 1, 4, 2, 8, None), (5, 3, 8, 4, 16, None),
+     (8, 2, 16, 2, 4, None),
+     # heads that share a 128-lane block (GPT-2's 64), straddle blocks
+     # (96) and fill whole blocks (128)
+     (3, 2, 16, 12, 64, None), (2, 2, 8, 3, 96, None),
+     (2, 2, 8, 2, 128, None),
+     # the walk (page 16: chunks of 8 pages, 128 positions). A context
+     # ending inside a chunk, and inside that chunk's first page
+     (3, 24, 16, 2, 8, [200, 130]),
+     # ... exactly on a chunk edge: the last row of a chunk, the first
+     # row of the next
+     (4, 24, 16, 2, 8, [127, 128, 255]),
+     # ... at the full table
+     (3, 16, 16, 2, 8, [255, 254]),
+     # a table the chunk does not divide (8 + 2 pages), walked to its end
+     (3, 10, 16, 2, 8, [159, 129]),
+     # a table shorter than a chunk (page 8 asks for 16, the table has 5)
+     (3, 5, 8, 2, 8, [39, 17]),
+     # a bucket that is mostly padding slots: one live slot of eight
+     (8, 12, 16, 2, 8, [150])],
+)
+def test_paged_decode_attention_bit_identical(S, maxp, page, nh, hd, pos):
+    """Kernel vs its same-chunks emulation (bitwise) and vs the XLA
+    gather→dequant→attend chain (float tolerance) across slot/page
+    mixes and context lengths — including padding slots with all-null
+    tables."""
+    rng = np.random.default_rng(S * 7 + maxp)
+    q, kp, vp, ks, vs, tables, pos = _paged_case(
+        rng, S, maxp, page, nh, hd, pos,
+        padding=1 if pos is None else S - len(pos),
+    )
+    for li in range(kp.shape[1]):
         got = np.asarray(kda.paged_decode_attention(
             q, kp, vp, ks, vs, li, tables, pos, interpret=True
         ))
@@ -408,6 +438,68 @@ def test_paged_decode_attention_bit_identical(S, maxp, page, nh, hd):
             got, ref, rtol=1e-5, atol=1e-5 * np.abs(ref).max(),
             err_msg=f"layer {li} vs XLA chain",
         )
+
+
+def test_paged_decode_attention_reads_no_page_beyond_the_context():
+    """Pages that no live position reaches are never read: with NaN
+    scales and saturated int8 rows on every such page, on the rows of a
+    slot's last page beyond its position, and every table entry beyond
+    a slot's last page pointing at such a page, the output is finite
+    and bitwise what clean pages give. (A kernel that folds the whole
+    table fails this: a masked weight of 0 times a NaN scale.)"""
+    S, maxp, page, nh, hd = 5, 20, 16, 2, 8
+    rng = np.random.default_rng(29)
+    live_pos = [0, 15, 16, 200, 319]
+    q, kp, vp, ks, vs, tables, pos = _paged_case(
+        rng, S + 1, maxp, page, nh, hd, live_pos
+    )
+    tables, pos_np = np.array(tables), np.asarray(pos)
+    reached = np.zeros(kp.shape[0], bool)
+    reached[0] = True                       # the padding slot's null page
+    for s, p in enumerate(pos_np[:S]):
+        reached[tables[s, :p // page + 1]] = True
+    spare = np.flatnonzero(~reached)
+    for s, p in enumerate(pos_np):
+        # beyond the context: every entry points at an unreached page
+        beyond = maxp - (p // page + 1)
+        tables[s, p // page + 1:] = rng.choice(spare, beyond)
+    poison = ~reached
+    dirty = {
+        "kp": np.array(kp), "vp": np.array(vp),
+        "ks": np.array(ks), "vs": np.array(vs),
+    }
+    dirty["kp"][poison] = 127
+    dirty["vp"][poison] = -127
+    dirty["ks"][poison] = np.nan
+    dirty["vs"][poison] = np.nan
+    for s, p in enumerate(pos_np):          # rows beyond the position
+        pg = tables[s, p // page]
+        dirty["ks"][pg, :, p % page + 1:] = np.nan
+        dirty["vs"][pg, :, p % page + 1:] = np.nan
+    tables = jnp.asarray(tables)
+    for li in range(kp.shape[1]):
+        clean = np.asarray(kda.paged_decode_attention(
+            q, kp, vp, ks, vs, li, tables, pos, interpret=True
+        ))
+        got = np.asarray(kda.paged_decode_attention(
+            q, *(jnp.asarray(dirty[c]) for c in ("kp", "vp", "ks", "vs")),
+            li, tables, pos, interpret=True,
+        ))
+        assert np.isfinite(got).all(), f"layer {li}"
+        _assert_eq(got, clean, f"layer {li}: poisoned vs clean pages")
+
+
+@pytest.mark.parametrize(
+    "page,maxp,pos,walked",
+    [(16, 64, [0, 127, 128, 1023], [8, 8, 16, 64]),   # the serving cell
+     (16, 10, [0, 128, 159], [8, 10, 10]),            # 8 + 2 pages
+     (8, 5, [0, 39], [5, 5]),                         # table < chunk
+     (4, 8, [3, 4, 31], [8, 8, 8])],                  # page 4: 32 a chunk
+)
+def test_pages_walked_follows_the_chunks(page, maxp, pos, walked):
+    got = kda.pages_walked(np.asarray(pos), page, maxp)
+    assert got.tolist() == walked
+    assert kda.chunk_pages(page, maxp) == min(128 // page, maxp)
 
 
 def test_ops_attention_paged_wrapper():
@@ -473,6 +565,61 @@ def test_decode_engine_forced_kernel_matches_oracle(forced):
             gen.generate(cfg, params, p[None, :], new, kv_quant=True)
         )
         _assert_eq(forced_outs[i], oracle, f"req {i} vs oracle")
+
+
+def test_decode_attn_page_counters_follow_the_contexts(forced):
+    """``tftpu_decode_attn_pages_walked_total`` counts the table entries
+    the kernel's chunks cover at each step's positions, ``..._grid_total``
+    the whole table of the step's bucket; ``decode.step`` carries the
+    same two numbers. One request at a time: positions known."""
+    from tensorframes_tpu.models import generation as gen
+    from tensorframes_tpu.models import transformer as tr
+    from tensorframes_tpu.observability import events
+    from tensorframes_tpu.serving import metrics as sm
+    from tensorframes_tpu.serving.decode import (
+        DecodeConfig, DecodeEngine,
+    )
+
+    cfg = gen.gpt_tiny(max_seq_len=160)
+    params = tr.init_params(cfg, seed=0)
+    page, new, plens = 8, 8, (100, 124, 130)
+    eng = DecodeEngine("kern-walk", cfg, params, DecodeConfig(
+        max_slots=2, page_size=page, max_prompt_len=136,
+        max_new_tokens=new,
+    ))
+    maxp = -(-(136 + new) // page)                       # 18 entries
+    assert kda.chunk_pages(page, maxp) == 16             # 128 positions
+    rng = np.random.default_rng(5)
+    eng.start()
+    try:
+        w0 = sm.DECODE_ATTN_PAGES_WALKED.value
+        g0 = sm.DECODE_ATTN_PAGES_GRID.value
+        events.clear()
+        events.enable()
+        for plen in plens:
+            eng.call({"prompt": rng.integers(
+                0, cfg.vocab_size, (plen,)).astype(np.int32)}, timeout=300)
+        events.disable()
+        walked = sm.DECODE_ATTN_PAGES_WALKED.value - w0
+        grid = sm.DECODE_ATTN_PAGES_GRID.value - g0
+    finally:
+        events.disable()
+        eng.stop(drain=True, timeout=120)
+    # the prefill gives a request's first token; each later token is one
+    # step at the position it writes: plen, plen + 1, ...
+    # (the other rows of the smallest slot bucket are padding: one
+    # chunk each)
+    positions = [plen + i for plen in plens for i in range(new - 1)]
+    bucket = eng._slot_buckets[0]
+    want = sum((16 if p < 128 else maxp) + (bucket - 1) * 16
+               for p in positions)
+    assert (walked, grid) == (want, len(positions) * bucket * maxp)
+    assert 0 < walked < grid
+    steps = [e["args"] for e in events.to_chrome_trace()["traceEvents"]
+             if e.get("name") == "decode.step" and e.get("ph") == "X"]
+    assert len(steps) == len(positions)
+    assert sum(a["pages_walked"] for a in steps) == walked
+    assert sum(a["pages_grid"] for a in steps) == grid
 
 
 def test_decode_engine_mosaic_failure_surfaces(forced):
