@@ -1,11 +1,4 @@
-"""Pallas straggler kernels, selected per segment by the plan cost model.
-
-The bench trajectory names three hot paths the default XLA lowerings
-leave on the table (ROADMAP #6): ragged ``map_rows`` (~12M rows/s vs
-1B+ for fixed-shape add3), decode attention (~17k tokens/s at 512 seq —
-the steady-state inner loop of the serving decode engine), and the
-segment reduce PR 7 routed to a host ``np.bincount`` because XLA:CPU
-serializes scatter. This package holds the purpose-built kernels:
+"""Pallas kernels for the hot paths XLA's default lowerings leave slow.
 
 * :mod:`.segment_reduce` — one fused pallas dispatch computing every
   (column, op) of a keyed reduction: sum/mean via the one-hot MXU
@@ -13,38 +6,32 @@ serializes scatter. This package holds the purpose-built kernels:
 * :mod:`.decode_attention` — paged int8-KV decode attention: the
   pages a slot's context holds stream HBM→VMEM through the
   scalar-prefetched page table, a chunk of them a fold, dequantize
-  in-register, and the attention math runs in the same kernel — the
-  gather→dequant→attend chain of
-  ``models/generation.paged_decode_step_fn`` becomes ONE kernel with no
+  in-register, and the attention math runs in the same kernel — no
   materialized ``[S, pages, page, heads*hd]`` copy, reading the
   resident pool columns in the layout the KV write leaves them in.
-* :mod:`.ragged_gather` — ragged row staging on device: cells move as
-  one flat buffer + offsets, and the kernel scatters each shape
-  group's rows into its padded batch in VMEM, replacing the per-group
-  host ``np.stack`` + transfer of the ragged ``map_rows`` path.
 
-**Selection is a counted cost-model decision** (``plan/rules.py``:
-``decide_segment_reduce`` / ``decide_decode_attention`` /
-``decide_ragged_gather`` → ``pallas_*`` decision values), never an
-unconditional dispatch. Which kernels a backend may select is ONE
-table, :func:`selectable` — the ``decide_*`` functions and
-``chip_smoke.py`` both read it: on a TPU the kernels Mosaic compiles
-(:data:`TPU_SELECTABLE`), everywhere under ``TFTPU_PALLAS_FORCE=1``
-(tests use it — the CPU pallas interpreter runs the kernels there, so
-tier-1 stays green under ``JAX_PLATFORMS=cpu``). ``TFTPU_PALLAS=0``
-removes them from every decision, and so does the manual switch
-:func:`tensorframes_tpu.ops.segment.disable_pallas` (it invalidates
-the fused-program cache, and the compile-cache fingerprint carries
-:func:`fingerprint_token`, so no stale executable survives a flip).
-Nothing trips that switch automatically: a kernel Mosaic refuses
-raises at the call site.
+**One rule decides whether a kernel runs: what the process can observe
+about its backend, and the call's own operands.** :func:`selectable`
+is the whole of the first half — the kernels are enabled
+(``TFTPU_PALLAS``, and the manual switch
+:func:`tensorframes_tpu.ops.segment.disable_pallas`), and either the
+backend is a TPU (Mosaic compiles every registered kernel; one it
+refuses raises at the call site, nothing falls back) or the test hook
+``TFTPU_PALLAS_FORCE=1`` puts them on the CPU pallas interpreter. The
+second half is the kernel's own ``eligible`` / shape check. The call
+sites ask here — ``ops.attention.paged_decode_attention`` at trace
+time, ``plan/rules.decide_segment_reduce`` per reduction — and
+``chip_smoke.py`` checks the dispatch counters against the same table.
+Nothing is timed to choose a kernel and nothing about the choice is
+persisted; the compile-cache fingerprint carries
+:func:`fingerprint_token`, so no executable survives a change of the
+answer.
 
-Every kernel is **bit-identity-gated**: against its plain-jnp
-same-tiling reference emulation always (exact by construction — the
-gate that catches indexing/masking/dequant bugs), and against the
-XLA/host reference wherever exactness is structural (min/max, integer
-sums, and the decode-attention chain, which the pallas interpreter
-reproduces bit-for-bit on CPU).
+Every kernel is gated against its plain-jnp same-tiling emulation
+bitwise (what catches indexing, masking and dequant bugs) and against
+the XLA/host reference: exactly where that is structural (min/max,
+integer sums), to float tolerance for the decode attention's online
+softmax.
 """
 
 from __future__ import annotations
@@ -58,7 +45,6 @@ from ..utils import is_tpu_backend
 
 __all__ = [
     "KERNELS",
-    "TPU_SELECTABLE",
     "enabled",
     "selectable",
     "force_active",
@@ -68,18 +54,10 @@ __all__ = [
     "build_timer",
 ]
 
-#: The registered kernel names — one counted dispatch series each, and
-#: the vocabulary of the ``pallas_*`` cost-model decision values.
-KERNELS = ("segment_reduce", "decode_attn", "ragged_gather")
-
-#: The kernels Mosaic compiles on a TPU (v5e, jax 0.9.0 / libtpu
-#: 0.0.34 — the chip run recorded in CHANGES.md PR 21). A kernel not
-#: listed here is never selected on a TPU; ``chip_smoke.py`` requires a
-#: non-zero dispatch count for every kernel that is. ``ragged_gather``
-#: is out: its ``(1, length)`` output block over ``[g, length]`` breaks
-#: the TPU lowering's (8, 128) block rule, and its 1-D HBM slice at an
-#: element offset fails ``tpu.memref_slice`` verification.
-TPU_SELECTABLE = ("segment_reduce", "decode_attn")
+#: The registered kernel names — one counted dispatch series each.
+#: Every one compiles under Mosaic (v5e, jax 0.9.0 / libtpu 0.0.34);
+#: ``chip_smoke.py`` requires a non-zero dispatch count for each.
+KERNELS = ("segment_reduce", "decode_attn")
 
 # Pre-registered at import (the `# kernels |` bench summary and the
 # exposition must always carry the family — a process that never
@@ -129,16 +107,12 @@ def force_active() -> bool:
 
 
 def selectable(kernel: str) -> bool:
-    """May the cost model select ``kernel`` on this backend right now?
-    The single table behind every ``decide_*`` function and the chip
-    smoke's dispatch check."""
+    """May ``kernel`` run on this backend right now? The one table the
+    call sites and the chip smoke's dispatch check read: enabled, and
+    either a TPU or the interpreter forced by the test hook."""
     if kernel not in KERNELS:
         raise KeyError(f"unknown kernel {kernel!r}; known: {KERNELS}")
-    if not enabled():
-        return False
-    if force_active():
-        return True
-    return is_tpu_backend() and kernel in TPU_SELECTABLE
+    return enabled() and (force_active() or is_tpu_backend())
 
 
 def interpret_mode() -> bool:

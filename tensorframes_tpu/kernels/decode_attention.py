@@ -61,8 +61,9 @@ denominator, so the two are not bitwise equal. ``G`` depends on the
 page size and the table's width only, never on the slot count, so a
 solo step and a batched step fold a slot's chunks identically: the
 engine-level gates (batched==solo, preemption replay) hold, and they
-hold whichever lowering the cost model picks because the choice is made
-once per engine, not per step.
+hold on either lowering because every step of a process traces the same
+one (``ops.attention.paged_decode_attention`` asks
+``kernels.selectable`` where the step is traced).
 
 Null-page handling is inherited: padding slots carry all-null tables
 and position 0, so they fold the null page's first row (the row the

@@ -569,8 +569,7 @@ def paged_page_ops_fns(max_pages: int):
 
 
 def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
-                         max_pages: int,
-                         attn_kernel: Optional[str] = None):
+                         max_pages: int):
     """Build the batched decode step: ``fn(params, pool, tokens[S],
     pos[S], tables[S, max_pages]) -> (pool, next_tokens[S])``.
 
@@ -585,18 +584,17 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
     ``pool`` donated: the writes are scatters of whole rows into the
     resident columns, in place.
 
-    ``attn_kernel="pallas"`` replaces the gather→dequant→attend chain
-    with the fused paged int8-KV pallas kernel
-    (:func:`tensorframes_tpu.kernels.decode_attention.paged_decode_attention`
-    — pages stream HBM→VMEM through the page table and dequantize
-    in-register; no materialized gather copy). The choice is a counted
-    cost-model decision made ONCE per engine
-    (``plan/rules.decide_decode_attention``), so batched and solo
-    steps always trace the same lowering and the bit-identity gates
-    hold either way.
+    The attention itself is
+    :func:`tensorframes_tpu.ops.attention.paged_decode_attention`: the
+    fused paged int8-KV pallas kernel where the backend can run it
+    (``kernels.selectable("decode_attn")``, asked at trace time), the
+    gather→dequant→attend chain elsewhere. Batched and solo steps of
+    one process trace the same one, so the bit-identity gates hold on
+    either.
     """
 
     def step(params, pool, tokens, pos, tables):
+        from ..ops.attention import paged_decode_attention as _paged_attn
         from ..ops.quantize import matmul as _mm
 
         (S,) = tokens.shape
@@ -628,37 +626,15 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
                     pool, li, wpg, woff,
                     _pool_rows(kq, ks[..., 0]), _pool_rows(vq, vs[..., 0]),
                 )
-            if attn_kernel == "pallas":
-                # fused paged-attention kernel: the page gather, int8
-                # dequant, and masked softmax-attend run in ONE pallas
-                # dispatch (write above first, so slot j still attends
-                # its own current token)
-                from ..kernels.decode_attention import (
-                    paged_decode_attention,
-                )
-
-                with jax.named_scope(f"layer_{li}/attn"):
-                    ctx = paged_decode_attention(
-                        q, pool["k"], pool["v"],
-                        pool["k_scale"], pool["v_scale"],
-                        li, tables, pos,
-                    ).reshape(S, h)
-            else:
-                # paged KV gather: each slot pulls its own pages (write
-                # above first, so slot j attends its own current token).
-                # ONE implementation serves both the production XLA
-                # lowering and the kernel's bit-identity oracle — they
-                # cannot drift apart
-                from ..kernels.decode_attention import (
-                    paged_attention_reference,
-                )
-
-                with jax.named_scope(f"layer_{li}/attn"):
-                    ctx = paged_attention_reference(
-                        q, pool["k"], pool["v"],
-                        pool["k_scale"], pool["v_scale"],
-                        li, tables, pos,
-                    ).reshape(S, h)
+            # paged attention, after the write above so slot j attends
+            # its own current token; ops.attention picks the kernel or
+            # the XLA chain from kernels.selectable at trace time
+            with jax.named_scope(f"layer_{li}/attn"):
+                ctx = _paged_attn(
+                    q, pool["k"], pool["v"],
+                    pool["k_scale"], pool["v_scale"],
+                    li, tables, pos,
+                ).reshape(S, h)
             with jax.named_scope(f"layer_{li}/attn"):
                 x = x + _mm(ctx, p["attn"]["out"])
             with jax.named_scope(f"layer_{li}/mlp"):

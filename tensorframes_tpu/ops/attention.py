@@ -21,8 +21,8 @@ Three implementations, one contract (``[batch, heads, seq, head_dim]``):
   exactly dense attention.
 
 Serving decode adds a fourth: :func:`paged_decode_attention` — the
-fused paged int8-KV kernel (``kernels/decode_attention.py``) behind
-the same public surface, selected per engine by the plan cost model.
+fused paged int8-KV kernel (``kernels/decode_attention.py``) where the
+backend runs it (``kernels.selectable``), the XLA gather chain elsewhere.
 """
 
 from __future__ import annotations
@@ -340,30 +340,30 @@ def paged_decode_attention(
     layer: int,
     tables: jnp.ndarray,
     pos: jnp.ndarray,
-    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """Fused paged int8-KV decode attention (ISSUE 12): one kernel
-    walks the pages each slot's context holds through its page table
-    (scalar-prefetched; pages stream HBM→VMEM as int8, a chunk of them
-    a fold), dequantizes in-register, and computes the masked softmax
-    attention — the public face of
-    ``kernels/decode_attention.paged_decode_attention``.
-    ``q`` [slots, heads, head_dim]; the pool arrays are the
-    ``models/generation.init_paged_kv`` columns (k/v ``[pages, layers,
-    page, heads*head_dim]`` int8, scales ``[pages, layers, page, 128]``
-    float32), read where they lie. Bit-identical on the
-    CPU interpreter to its same-chunks emulation and within float
-    tolerance of the XLA gather→dequant→attend chain (asserted in
-    tests). The serving decode engine selects it per engine via the
-    cost model (``plan/rules.decide_decode_attention``)."""
-    from ..kernels.decode_attention import (
-        paged_decode_attention as _kernel,
-    )
+    """One layer's paged int8-KV decode attention for every slot:
+    ``q`` [slots, heads, head_dim] against the
+    ``models/generation.init_paged_kv`` pool columns (k/v ``[pages,
+    layers, page, heads*head_dim]`` int8, scales ``[pages, layers, page,
+    128]`` float32), read where they lie through ``tables`` and masked
+    to ``j <= pos``. Traceable; the decode step embeds it.
 
-    return _kernel(
-        q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos,
-        interpret=interpret,
+    This is where the lowering is chosen, from the backend alone:
+    where ``kernels.selectable("decode_attn")`` holds (a TPU, or the
+    CPU interpreter under the test hook) the fused kernel
+    (``kernels/decode_attention.paged_decode_attention`` — pages stream
+    HBM→VMEM through the page table a chunk a fold and dequantize
+    in-register), elsewhere the XLA gather→dequant→attend chain
+    (``paged_attention_reference``, also the kernel's float oracle).
+    The two agree to float tolerance, not bitwise (asserted in tests)."""
+    from .. import kernels as _kernels
+    from ..kernels import decode_attention as _kda
+
+    attend = (
+        _kda.paged_decode_attention if _kernels.selectable("decode_attn")
+        else _kda.paged_attention_reference
     )
+    return attend(q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos)
 
 
 def dense_attention(

@@ -659,66 +659,6 @@ def _stack_group(col, idx) -> np.ndarray:
     return np.stack([np.asarray(c) for c in cells])
 
 
-def _ragged_gather_plan(cols, input_names, n, program, group_list):
-    """Device-side ragged staging (ISSUE 12): when the cost model
-    selects the pallas ragged-gather kernel
-    (``plan/rules.decide_ragged_gather`` — single 1-D ragged column,
-    kernel-capable backend), the column's cells move ONCE as a flat
-    device buffer and each shape group's padded batch is gathered
-    on-device by ``kernels/ragged_gather.py`` — the per-group host
-    ``np.stack`` + transfer disappears. Returns a
-    ``gather(idx) -> feeds`` closure, or None to keep host staging
-    (the ordinary path — not a counted decision)."""
-    if len(input_names) != 1:
-        return None
-    name = input_names[0]
-    cells = cols[name]
-    if not cells or not all(
-        isinstance(c, np.ndarray) and c.ndim == 1 and c.shape[0] > 0
-        for c in cells
-    ):
-        return None
-    if len({c.dtype for c in cells}) != 1:
-        return None
-    from ..plan import rules as _prules
-    from ..plan import stats as _pstats
-
-    decision = _prules.decide_ragged_gather(
-        n, len(group_list), cells[0].dtype,
-        observed_walls=_pstats.strategy_walls("ragged_gather"),
-    )
-    if decision is None:
-        return None
-    from ..kernels import ragged_gather as _krg
-    from ..plan.lower import _note_decision
-
-    lens = np.fromiter((c.shape[0] for c in cells), np.int64, count=n)
-    if int(lens.sum()) > np.iinfo(np.int32).max:
-        # start offsets ride int32 scalar prefetch; a flat buffer past
-        # 2^31 elements would wrap them — host staging handles it
-        return None
-    starts = np.zeros(n, np.int64)
-    np.cumsum(lens[:-1], out=starts[1:])
-    flat = np.concatenate(cells)
-    spec = program.input(name)
-    if dt.demotion_active() and flat.dtype != spec.dtype.np_dtype:
-        # the x64 demotion boundary, applied once to the flat buffer
-        # instead of per stacked group (mirrors group_feeds)
-        flat = flat.astype(spec.dtype.np_dtype)
-    flat_dev = jax.device_put(flat)
-    _note_decision(decision)
-
-    def gather(idx):
-        g = len(idx)
-        gb = bucket_rows(g)
-        st = np.zeros(gb, np.int32)  # padding rows re-read offset 0;
-        st[:g] = starts[np.asarray(idx)]  # their outputs are sliced off
-        L = int(lens[int(idx[0])])
-        return {name: _krg.ragged_gather_rows(flat_dev, st, L)}
-
-    return gather
-
-
 def _ragged_rows_outs(
     cols: Dict[str, list],
     input_names: Sequence[str],
@@ -748,8 +688,6 @@ def _ragged_rows_outs(
                   if len(g)]
     donate_r = get_config().donate_inputs
     window = max(1, get_config().map_pipeline_depth)
-    gather = _ragged_gather_plan(cols, input_names, n, program,
-                                 group_list)
 
     def group_feeds(idx):
         g = len(idx)
@@ -803,29 +741,8 @@ def _ragged_rows_outs(
     from collections import deque as _deque
 
     outs_list: List[Dict[str, np.ndarray]] = []
-    from ..plan.lower import observe_strategy_wall as _obs_wall
-
     for wave in waves:
-        if gather is not None:
-            t_stage = time.perf_counter()
-            # padded batches materialize ON DEVICE (one flat buffer
-            # moved once, above); rows already bucket-padded. A kernel
-            # failure raises — there is no retry on host staging.
-            staged = [gather(idx) for idx in wave]
-            _obs_wall(
-                "ragged_gather", "pallas_ragged_gather",
-                time.perf_counter() - t_stage,
-            )
-        else:
-            t_stage = time.perf_counter()
-            staged = jax.device_put([group_feeds(idx) for idx in wave])
-            if len(input_names) == 1:
-                # only the single-ragged-column case competes with the
-                # pallas gather — keep the wall table apples-to-apples
-                _obs_wall(
-                    "ragged_gather", "host_stack",
-                    time.perf_counter() - t_stage,
-                )
+        staged = jax.device_put([group_feeds(idx) for idx in wave])
         in_flight_r: _deque = _deque()
         for f in staged:
             # freshly-transferred private copies: donation-safe
@@ -1302,50 +1219,28 @@ def _segment_reduce_best(ops_key, num_groups, val_cols, seg_ids):
     failure in the selected lowering raises; nothing retries on
     another one."""
     from . import segment as _segment
-    from ..plan import stats as _pstats
-    from ..plan.lower import _note_decision, _note_flip, observe_strategy_wall
+    from ..plan.lower import _note_decision
     from ..plan.rules import decide_segment_reduce
 
-    decision = decide_segment_reduce(
-        ops_key, val_cols, num_groups,
-        observed_walls=_pstats.strategy_walls("segment_reduce"),
-    )
+    decision = decide_segment_reduce(ops_key, val_cols, num_groups)
     _note_decision(decision)
-    _note_flip(decision)
     if decision.kind == "host_segment_reduce":
-        t0 = time.perf_counter()
-        out = _segment.segment_reduce_host(
+        return _segment.segment_reduce_host(
             ops_key, num_groups, val_cols, seg_ids
         )
-        observe_strategy_wall(
-            "segment_reduce", "host_segment_reduce",
-            time.perf_counter() - t0,
-        )
-        return out
     if decision.kind == "pallas_segment_reduce":
         from ..kernels import segment_reduce as _ksr
 
-        t0 = time.perf_counter()
-        out = _ksr.segment_reduce_pallas(
+        return _ksr.segment_reduce_pallas(
             ops_key, num_groups, val_cols, seg_ids
         )
-        observe_strategy_wall(
-            "segment_reduce", "pallas_segment_reduce",
-            time.perf_counter() - t0,
-        )
-        return out
-    t0 = time.perf_counter()
     seg_vals = {x: jnp.asarray(val_cols[x]) for x, _ in ops_key}
     # int32 ids: halves the host→HBM id-column transfer; group counts
     # can't exceed int32 — the id space is bounded by row count long
     # before 2^31
     sids = jnp.asarray(np.asarray(seg_ids).astype(np.int32))
     res = run_segment_fast(ops_key, num_groups, seg_vals, sids)
-    out = {x: np.asarray(res[x]) for x, _ in ops_key}
-    observe_strategy_wall(
-        "segment_reduce", "jit_segment_reduce", time.perf_counter() - t0
-    )
-    return out
+    return {x: np.asarray(res[x]) for x, _ in ops_key}
 
 
 def run_segment_fast(ops_key, num_groups, seg_vals, sids):

@@ -121,11 +121,9 @@ _COST_DECISIONS = {
     for kind in (
         "fuse", "split_single_stage", "epilogue_per_block",
         "epilogue_concat", "bucket_segments", "host_segment_reduce",
-        # kernel selection (ISSUE 12): which lowering serves each
-        # measured straggler — plan/rules.decide_segment_reduce /
-        # decide_decode_attention / decide_ragged_gather
+        # keyed-reduction lowering below the epilogue choice
+        # (plan/rules.decide_segment_reduce)
         "pallas_segment_reduce", "jit_segment_reduce",
-        "pallas_decode_attn", "xla_decode_attn", "pallas_ragged_gather",
         # adaptive optimizer (ISSUE 14): aggregate pushdown below
         # joins, multi-join reordering, and stats-fed re-optimization
         # (plan/rules.plan_pushdown / decide_pushdown /
@@ -189,13 +187,6 @@ _STAGE_WALL = {
 _STRATEGY_WALL_PAIRS = (
     ("fuse", "fuse"), ("fuse", "split_single_stage"),
     ("epilogue", "epilogue_per_block"), ("epilogue", "epilogue_concat"),
-    ("segment_reduce", "host_segment_reduce"),
-    ("segment_reduce", "pallas_segment_reduce"),
-    ("segment_reduce", "jit_segment_reduce"),
-    ("ragged_gather", "pallas_ragged_gather"),
-    ("ragged_gather", "host_stack"),
-    ("decode_attention", "pallas_decode_attn"),
-    ("decode_attention", "xla_decode_attn"),
 )
 _STRATEGY_WALL = {
     pair: _histogram(
@@ -209,14 +200,6 @@ _STRATEGY_WALL = {
 }
 
 
-#: Decisions whose strategies include a pallas kernel: their walls are
-#: unrepresentative under TFTPU_PALLAS_FORCE (the CPU interpreter runs
-#: the kernel orders of magnitude slower than any real backend), so
-#: forced runs must not feed the EWMA table a later unforced run (or a
-#: sidecar-sharing real run) would act on.
-_KERNEL_DECISIONS = ("segment_reduce", "ragged_gather", "decode_attention")
-
-
 def observe_strategy_wall(decision: str, strategy: str,
                           wall_s: float) -> None:
     """Record one observed strategy dispatch wall: the pre-registered
@@ -225,11 +208,6 @@ def observe_strategy_wall(decision: str, strategy: str,
     h = _STRATEGY_WALL.get((decision, strategy))
     if h is not None:
         h.observe(wall_s)
-    if decision in _KERNEL_DECISIONS:
-        from .. import kernels as _kernels
-
-        if _kernels.force_active():
-            return
     _stats.observe_strategy_wall(decision, strategy, wall_s)
 
 

@@ -51,8 +51,6 @@ __all__ = [
     "decide_epilogue",
     "decide_segment_bucket",
     "decide_segment_reduce",
-    "decide_decode_attention",
-    "decide_ragged_gather",
     "reassoc_safe",
     "PushdownPlan",
     "PushdownLevel",
@@ -503,27 +501,15 @@ def decide_epilogue(
 
 
 # ---------------------------------------------------------------------------
-# kernel selection (ISSUE 12): which LOWERING serves each straggler —
-# the pallas kernel, the jitted XLA program, or the host path. Pure
-# decisions; the dispatch sites count them through _note_decision and
-# the compile-cache fingerprint carries kernels.fingerprint_token() so
-# a selection flip can never serve a stale executable.
+# keyed-reduction lowering (ISSUE 12): host bincount, the fused pallas
+# kernel, or the jitted XLA program. A pure decision from the backend
+# (kernels.selectable) and the operands; ops/verbs counts it through
+# _note_decision. The compile-cache fingerprint carries
+# kernels.fingerprint_token(), so a change of what is selectable can
+# never serve a stale executable.
 # ---------------------------------------------------------------------------
 
-def _force_pins_kernels() -> bool:
-    """Under ``TFTPU_PALLAS_FORCE`` the kernel lowering is pinned by the
-    test/bench hook: latency flips must not engage (the hook exists to
-    exercise a SPECIFIC lowering) and interpreted-kernel walls are not
-    representative of any real backend anyway."""
-    from .. import kernels
-
-    return kernels.force_active()
-
-
-def decide_segment_reduce(
-    ops_key, val_cols, num_segments: int,
-    observed_walls: Optional[Dict[str, dict]] = None,
-) -> Decision:
+def decide_segment_reduce(ops_key, val_cols, num_segments: int) -> Decision:
     """Keyed-reduction strategy for one segment: ``host_segment_reduce``
     (CPU bincount — the measured XLA:CPU-scatter escape, unchanged),
     ``pallas_segment_reduce`` (the fused multi-op kernel,
@@ -531,15 +517,9 @@ def decide_segment_reduce(
     jitted scatter program). Order matters: the host path keeps CPU
     float sums (its f64 accumulation is the tighter bound and bincount
     beats interpreted pallas by orders of magnitude); the kernel takes
-    whatever remains eligible on a kernel-capable backend.
-
-    ``observed_walls`` may flip the static choice to an eligible
-    alternative that measured faster — but ONLY when every (op, value
-    dtype) is :func:`reassoc_safe` (min/max, integer sums): those
-    reduce to the same bits under every strategy, so the flip cannot
-    move results. Float sums pin their statically-chosen strategy (the
-    host path's f64 accumulation is not bit-identical to the scatter
-    program's)."""
+    whatever remains eligible where ``kernels.selectable`` allows it.
+    A choice from the backend and the operands alone — nothing timed
+    enters it."""
     from .. import kernels as _kernels
     from ..kernels import segment_reduce as _ksr
     from ..ops.segment import host_segment_eligible
@@ -548,132 +528,27 @@ def decide_segment_reduce(
         "num_groups": int(num_segments),
         "ops": [op for _, op in ops_key],
     }
-    candidates = ["jit_segment_reduce"]
     if host_segment_eligible(ops_key, val_cols):
-        static = Decision(
+        return Decision(
             "host_segment_reduce",
             "CPU backend: bincount's weighted histogram beats XLA's "
             "serialized segment scatter for float sums",
             details,
         )
-        candidates.append("host_segment_reduce")
-    elif _kernels.selectable("segment_reduce") and _ksr.eligible(
+    if _kernels.selectable("segment_reduce") and _ksr.eligible(
         ops_key, val_cols, num_segments
     ):
-        static = Decision(
+        return Decision(
             "pallas_segment_reduce",
             "fused multi-op pallas kernel: every (column, op) partial "
             "in ONE dispatch (one-hot MXU sums, masked VPU min/max) "
             "instead of one scatter per fetch",
             details,
         )
-        candidates.append("pallas_segment_reduce")
-    else:
-        static = Decision(
-            "jit_segment_reduce",
-            "jitted XLA segment program (kernel ineligible or disabled)",
-            details,
-        )
-    all_exact = all(
-        x in val_cols and reassoc_safe(op, val_cols[x].dtype)
-        for x, op in ops_key
-    )
-    if not all_exact or _force_pins_kernels():
-        return static
-    flip = pick_by_observed_wall(static.kind, candidates, observed_walls)
-    if flip is None:
-        return static
-    kind, evidence = flip
-    details = dict(details)
-    details.update(evidence)
     return Decision(
-        kind,
-        f"observed walls: {kind} runs faster than {static.kind} for "
-        "this workload (all ops reassociation-safe — every strategy "
-        "reduces to the same bits)",
+        "jit_segment_reduce",
+        "jitted XLA segment program (kernel ineligible or disabled)",
         details,
-    )
-
-
-def decide_decode_attention(
-    num_heads: int, head_dim: int, page_size: int, max_pages: int,
-    observed_walls: Optional[Dict[str, dict]] = None,
-) -> Decision:
-    """Decode-attention lowering for a serving decode engine, chosen
-    ONCE at engine build (both the batched and the solo step trace the
-    same choice — the batched==solo and preemption-replay bit-identity
-    gates therefore hold whichever side wins). ``observed_walls`` can
-    flip pallas → XLA when recorded step walls show the kernel slower
-    on this host (the kernel is bit-identical to the XLA chain, so the
-    flip cannot move tokens); the reverse flip never engages — XLA is
-    only static when the kernel backend is unavailable."""
-    from .. import kernels as _kernels
-
-    details = {
-        "heads": int(num_heads), "head_dim": int(head_dim),
-        "page_size": int(page_size), "max_pages": int(max_pages),
-    }
-    if _kernels.selectable("decode_attn"):
-        flip = None if _force_pins_kernels() else pick_by_observed_wall(
-            "pallas_decode_attn", ("xla_decode_attn",), observed_walls
-        )
-        if flip is not None:
-            kind, evidence = flip
-            details.update(evidence)
-            return Decision(
-                kind,
-                "observed walls: the XLA gather→dequant→attend chain "
-                "steps faster than the paged kernel on this host "
-                "(bit-identical — the kernel gate proves it)",
-                details,
-            )
-        return Decision(
-            "pallas_decode_attn",
-            "fused paged int8-KV kernel: pages stream HBM→VMEM through "
-            "the scalar-prefetched page table and dequantize "
-            "in-register — no materialized gather copy",
-            details,
-        )
-    return Decision(
-        "xla_decode_attn",
-        "XLA gather→dequant→attend chain (kernels disabled or no "
-        "Mosaic backend)",
-        details,
-    )
-
-
-def decide_ragged_gather(
-    n_rows: int, n_groups: int, cell_dtype,
-    observed_walls: Optional[Dict[str, dict]] = None,
-) -> Optional[Decision]:
-    """Ragged map_rows staging: the pallas flat-buffer gather
-    (``pallas_ragged_gather``) when the single-1-D-ragged-column fast
-    path applies on a kernel-capable backend; None keeps the host
-    ``np.stack`` staging (not a counted decision — it is the ordinary
-    path, not a choice). The caller additionally verifies the cell
-    shapes and the int32 offset bound before acting on the choice.
-    ``observed_walls`` flips the kernel BACK to host staging (returns
-    None) when recorded walls show ``host_stack`` faster — staging is
-    bit-identical either way, so the flip only moves time."""
-    import numpy as _np
-
-    from .. import kernels as _kernels
-
-    if n_rows == 0 or not _kernels.selectable("ragged_gather"):
-        return None
-    if _np.dtype(cell_dtype).kind not in ("f", "i", "u", "b"):
-        return None
-    if not _force_pins_kernels() and pick_by_observed_wall(
-        "pallas_ragged_gather", ("host_stack",), observed_walls
-    ) is not None:
-        return None
-    return Decision(
-        "pallas_ragged_gather",
-        "single 1-D ragged column: cells move as one flat buffer and "
-        "the kernel stages each shape group's padded batch on device "
-        f"({n_groups} shape group(s) — host np.stack and per-group "
-        "transfers eliminated)",
-        {"rows": int(n_rows), "shape_groups": int(n_groups)},
     )
 
 

@@ -67,10 +67,11 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import kernels as _kernels
 from ..observability import events as _events
 from ..observability import flight as _flight
 from ..utils import get_logger
@@ -229,32 +230,16 @@ class DecodeEngine:
             gen.paged_prefill_fn(model_cfg, cfg.page_size, max_pages),
             label=f"decode.prefill[{name}]", donate_argnums=(1,),
         )
-        # decode-attention lowering: a counted cost-model decision made
-        # ONCE per engine (ISSUE 12) — batched and solo steps trace the
-        # same choice, so the batched==solo / preemption-replay
-        # bit-identity gates hold whichever lowering wins. The choice
-        # also reaches the compile-cache fingerprint (kernels token),
-        # so a disable_pallas() flip can never serve a stale executable.
-        from ..plan import stats as _pstats
-        from ..plan.lower import _note_decision, _note_flip
-        from ..plan.rules import decide_decode_attention
-
-        decision = decide_decode_attention(
-            model_cfg.num_heads, model_cfg.head_dim, cfg.page_size,
-            max_pages,
-            observed_walls=_pstats.strategy_walls("decode_attention"),
-        )
-        _note_decision(decision)
-        _note_flip(decision)
-        self._attn_kernel: Optional[str] = (
-            "pallas" if decision.kind == "pallas_decode_attn" else None
-        )
         self._step = aot_jit(
-            gen.paged_decode_step_fn(
-                model_cfg, cfg.page_size, max_pages,
-                attn_kernel=self._attn_kernel,
-            ),
+            gen.paged_decode_step_fn(model_cfg, cfg.page_size, max_pages),
             label=f"decode.step[{name}]", donate_argnums=(1,),
+        )
+        # which attention the step traces is the backend's fact
+        # (ops.attention asks the same table at trace time); kept for
+        # the dispatch counter and the pages-walked accounting
+        self._attn_is_kernel = _kernels.selectable("decode_attn")
+        self._attn_interpreted = (
+            self._attn_is_kernel and _kernels.interpret_mode()
         )
         # widest slot bucket whose step executable's memory plan the
         # tftpu_decode_step_*_bytes gauges show (0: none yet)
@@ -318,6 +303,9 @@ class DecodeEngine:
             max_queue_rows=cfg.max_queue_requests,
         )
         self._slots: List[Optional[_Seq]] = [None] * cfg.max_slots
+        # polled out of the queue, not yet in a slot: a join that
+        # raises must still answer them (_fail_all reads this)
+        self._in_hand: Sequence[_Request] = ()
         self._resume: Dict[_Request, List[int]] = {}
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
@@ -348,26 +336,17 @@ class DecodeEngine:
         self._t_mark = now
 
     def _run_step(self, *args):
-        """Dispatch one batched decode step on the lowering chosen at
-        engine build, and record its wall and kernel dispatch. Returns
-        ``(pool, next_tokens)``; the pool columns passed in (``args[1]``)
-        are donated — deleted by the call — so the caller rebinds
-        ``self._pool.columns`` to the returned ones. A failure raises —
-        the step is never rebuilt on another lowering."""
-        from .. import kernels as _kernels
-        from ..plan.lower import observe_strategy_wall
-
+        """Dispatch one batched decode step and count its kernel
+        dispatch. Returns ``(pool, next_tokens)``; the pool columns
+        passed in (``args[1]``) are donated — deleted by the call — so
+        the caller rebinds ``self._pool.columns`` to the returned ones.
+        A failure raises — the step is never rebuilt on another
+        lowering."""
         t_step = time.perf_counter()
         out = self._step(*args)
         dt = time.perf_counter() - t_step
         if args[2].shape[0] > self._step_memory_bucket:
             self._note_step_memory(args)
-        observe_strategy_wall(
-            "decode_attention",
-            "pallas_decode_attn" if self._attn_kernel is not None
-            else "xla_decode_attn",
-            dt,
-        )
         if _events.TRACER.enabled:
             # the host's enqueue of the step program (key walk, argument
             # transfer, launch): a leaf inside decode.step, whose rest
@@ -376,10 +355,8 @@ class DecodeEngine:
                 "decode.step.enqueue", t_step, dt,
                 args={"endpoint": self.name}, cat="serving",
             )
-        if self._attn_kernel is not None:
-            _kernels.note_dispatch(
-                "decode_attn", _kernels.interpret_mode()
-            )
+        if self._attn_is_kernel:
+            _kernels.note_dispatch("decode_attn", self._attn_interpreted)
         return out
 
     def _note_step_memory(self, args) -> None:
@@ -809,8 +786,10 @@ class DecodeEngine:
             ) if free else ()
             if _events.TRACER.enabled:
                 self._phase("decode.admit", polled=len(polled))
+            self._in_hand = polled
             for req in polled:
                 self._join(req)
+            self._in_hand = ()
             if any(s is not None for s in self._slots):
                 self._decode_step()
                 continue
@@ -834,10 +813,15 @@ class DecodeEngine:
         after the poll returns). The budget is ``num_allocatable`` —
         free pages plus reclaimable refcount-0 shared pages — and a
         host-swapped request claims its SNAPSHOT's page count (it may
-        hold pages past its prompt), not its prompt estimate."""
-        budget = [self._pool.num_allocatable]
+        hold pages past its prompt), not its prompt estimate. The pool
+        is read at the first head request, under the admission lock: a
+        request offered after pages left the pool never sees the pool
+        as it was before."""
+        budget: List[int] = []
 
         def can_take(req: _Request) -> bool:
+            if not budget:
+                budget.append(self._pool.num_allocatable)
             snap = self._swap.get(req)
             if snap is None:
                 # a redriven request adopting a restored swap segment
@@ -1227,7 +1211,7 @@ class DecodeEngine:
         # the kernel folds the chunks each row's context reaches; the
         # XLA chain gathers and attends the whole table
         walked = grid = sb * maxp
-        if self._attn_kernel == "pallas":
+        if self._attn_is_kernel:
             walked = int(self._pages_walked(
                 pos, self._pool.page_size, maxp
             ).sum())
@@ -1406,4 +1390,8 @@ class DecodeEngine:
                 m.DECODE_SLOTS.dec()
                 self._pool.free_seq(s.seq)
                 s.req.future._fail(exc)
+        for req in self._in_hand:
+            if not req.future.done():
+                req.future._fail(exc)
+        self._in_hand = ()
         self._admission.close(drain=False)

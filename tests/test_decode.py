@@ -404,14 +404,109 @@ def test_stop_without_drain_fails_loudly(model):
         max_slots=1, page_size=4, max_prompt_len=8, max_new_tokens=4,
         warmup=False,
     ))
-    eng.start()
     _hog_pool(eng.pool)  # keep requests queued
+    eng.start()
     futs = [eng.submit({"prompt": np.arange(4, dtype=np.int32)})
             for _ in range(2)]
     eng.stop(drain=False, timeout=60)
     for f in futs:
         with pytest.raises(ServingError):
             f.result(10)
+
+
+def test_admission_budget_reads_the_pool_when_a_request_heads_the_queue(
+    model,
+):
+    """The budget of one poll is the pool as it is when the head request
+    is judged, not as it was when the poll was set up: pages that left
+    the pool before a request was offered can never be promised to it
+    (the hog idiom of these tests, from another thread)."""
+    cfg, params = model
+    eng = DecodeEngine("t_budget", cfg, params, DecodeConfig(
+        max_slots=1, page_size=4, max_prompt_len=8, max_new_tokens=4,
+        warmup=False,
+    ))
+    can_take = eng._admit_budget()
+    hogs = _hog_pool(eng.pool)
+    eng._admission.start()
+    try:
+        eng._admission.offer(
+            eng.validate_feeds({"prompt": np.arange(4, dtype=np.int32)}),
+            1, None,
+        )
+        assert eng._admission.poll(1, can_take=can_take) == []
+        _unhog_pool(eng.pool, hogs)
+        assert len(eng._admission.poll(
+            1, can_take=eng._admit_budget())) == 1
+    finally:
+        eng._admission.stop(drain=False, timeout=10)
+        eng.stop()
+
+
+def test_join_failure_answers_the_request_in_hand(model):
+    """A request polled out of the queue whose join raises is in no
+    slot and no queue: the crash guard must still answer it."""
+    cfg, params = model
+    eng = DecodeEngine("t_joinfail", cfg, params, DecodeConfig(
+        max_slots=1, page_size=4, max_prompt_len=8, max_new_tokens=4,
+        warmup=False,
+    ))
+
+    def broken(*args):
+        raise RuntimeError("prefill broke (test)")
+
+    eng._prefill = broken
+    eng.start()
+    try:
+        # one request: the failure closes admission, so a second submit
+        # would race it (answered, or rejected as closed)
+        fut = eng.submit({"prompt": np.arange(4, dtype=np.int32)})
+        with pytest.raises(ServingError, match="prefill broke"):
+            fut.result(10)
+    finally:
+        eng.stop(drain=False, timeout=60)
+
+
+def test_decode_steps_leave_plan_stats_alone(model, tmp_path):
+    """Serving with a compile-cache directory configured: no decode
+    step creates or touches ``planstats/strategy_walls.json`` or counts
+    a sidecar store (the engine times nothing to choose a kernel)."""
+    from tensorframes_tpu.observability.metrics import REGISTRY
+    from tensorframes_tpu.plan import stats as plan_stats
+
+    def stores():
+        return sum(
+            d["value"] for d in REGISTRY.snapshot()
+            if d["name"] == "tftpu_plan_reopt_sidecar_total"
+            and d["labels"].get("event") == "store"
+        )
+
+    cfg, params = model
+    was = tfs.configure().compilation_cache_dir
+    tfs.configure(compilation_cache_dir=str(tmp_path))
+    try:
+        plan_stats.clear_memory()
+        n0 = stores()
+        eng = DecodeEngine("t_nostats", cfg, params, DecodeConfig(
+            max_slots=2, page_size=4, max_prompt_len=8, max_new_tokens=4,
+        ))
+        eng.start()
+        try:
+            steps0 = sm.DECODE_STEPS["decode"].value
+            for plen in (3, 5, 8):
+                out = eng.call(
+                    {"prompt": np.arange(plen, dtype=np.int32)}, timeout=300
+                )
+                assert out["tokens"].shape == (1, 4)
+            assert sm.DECODE_STEPS["decode"].value - steps0 >= 9
+        finally:
+            eng.stop(drain=True, timeout=120)
+        assert stores() == n0
+        assert not (tmp_path / "planstats" / "strategy_walls.json").exists()
+        assert plan_stats.strategy_walls("decode_attention") == {}
+    finally:
+        tfs.configure(compilation_cache_dir=was)
+        plan_stats.clear_memory()
 
 
 def test_engine_config_validation(model):

@@ -71,9 +71,10 @@ def cell(one_chip):
 
 @pytest.fixture()
 def mosaic(monkeypatch):
-    """Lower the kernel for Mosaic, as on the chip: here the backend is
-    the CPU, where the step would embed the pallas interpreter."""
-    monkeypatch.setattr(kernels, "interpret_mode", lambda: False)
+    """Lower the kernel for Mosaic, as on the chip: ``kernels`` is told
+    the backend is a TPU, so the step traces the kernel and not (as on
+    this CPU) the XLA chain or the pallas interpreter."""
+    monkeypatch.setattr(kernels, "is_tpu_backend", lambda: True)
     # a compile for a described chip is written to jax's persistent
     # cache but cannot be read back without one: keep it out
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -124,9 +125,8 @@ def test_step_writes_the_pool_in_place(cell, mosaic):
     outputs aliased onto the whole pool, temporaries far under one
     pool (1.51 GB)."""
     cfg, params, pool, i32 = cell
-    step = gen.paged_decode_step_fn(
-        cfg, PAGE, MAX_PAGES, attn_kernel="pallas"
-    )
+    assert kernels.selectable("decode_attn")
+    step = gen.paged_decode_step_fn(cfg, PAGE, MAX_PAGES)
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pool, i32(SLOTS), i32(SLOTS), i32(SLOTS, MAX_PAGES)
     ).compile()

@@ -22,7 +22,6 @@ import tensorframes_tpu as tfs
 from tensorframes_tpu import configure
 from tensorframes_tpu import kernels
 from tensorframes_tpu.kernels import decode_attention as kda
-from tensorframes_tpu.kernels import ragged_gather as krg
 from tensorframes_tpu.kernels import segment_reduce as ksr
 from tensorframes_tpu.observability.metrics import REGISTRY
 from tensorframes_tpu.ops import segment
@@ -247,91 +246,6 @@ def test_kernel_error_stays_loud(forced, monkeypatch):
     assert segment.pallas_enabled()  # the switch must NOT trip
 
 
-def test_ragged_gather_mosaic_error_surfaces(forced, monkeypatch):
-    """Ragged map_rows with the gather kernel selected: a kernel
-    failure raises out of the verb instead of re-staging on the host."""
-    def boom(*a, **k):
-        raise RuntimeError("Mosaic failed to compile TPU kernel (test)")
-
-    monkeypatch.setattr(krg, "ragged_gather_rows", boom)
-    rows = [{"v": np.arange(n, dtype=np.float32)} for n in (3, 5, 3, 5)]
-    frame = tfs.frame_from_rows(rows, num_blocks=1)
-    program = tfs.compile_program(
-        lambda v: {"s": v.sum()}, frame, block=False
-    )
-    with pytest.raises(RuntimeError, match="Mosaic failed"):
-        tfs.map_rows(program, frame).blocks()
-    assert segment.pallas_enabled()
-
-
-# ---------------------------------------------------------------------------
-# ragged gather
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dtype", ["float32", "float64", "int32"])
-def test_ragged_gather_bit_identical_to_stack(dtype):
-    rng = np.random.default_rng(11)
-    cells = [
-        rng.standard_normal(int(rng.integers(1, 40))).astype(dtype)
-        for _ in range(80)
-    ]
-    lens = np.asarray([len(c) for c in cells])
-    starts = np.zeros(len(cells), np.int64)
-    np.cumsum(lens[:-1], out=starts[1:])
-    flat = np.concatenate(cells)
-    flat_dev = jnp.asarray(flat)
-    for L in np.unique(lens):
-        idx = np.flatnonzero(lens == L)
-        st = starts[idx]
-        got = np.asarray(krg.ragged_gather_rows(
-            flat_dev, st, int(L), interpret=True
-        ))
-        _assert_eq(got, krg.gather_reference(flat, st, int(L)),
-                   f"length {L}")
-    # padding rows re-reading offset 0 (the bucket-pad convention)
-    st = np.zeros(4, np.int32)
-    st[:2] = starts[:2]
-    got = np.asarray(krg.ragged_gather_rows(
-        flat_dev, st, int(lens[0]), interpret=True
-    ))
-    _assert_eq(got, krg.gather_reference(flat, st, int(lens[0])),
-               "padded rows")
-
-
-def test_ragged_gather_rejects_zero_length():
-    with pytest.raises(ValueError, match="length >= 1"):
-        krg.ragged_gather_rows(jnp.zeros(4), np.zeros(2), 0)
-
-
-def test_ragged_map_rows_forced_kernel_bit_identical(forced):
-    before = REGISTRY.counter(
-        "tftpu_plan_cost_decisions_total",
-        labels={"decision": "pallas_ragged_gather"},
-    ).value
-
-    def run():
-        rng = np.random.default_rng(0)
-        lens = rng.choice([3, 5, 8, 13], 150)
-        rows = [{"v": np.arange(n, dtype=np.float32) + 0.25}
-                for n in lens]
-        frame = tfs.frame_from_rows(rows, num_blocks=3)
-        program = tfs.compile_program(
-            lambda v: {"s": v.sum()}, frame, block=False
-        )
-        out = tfs.map_rows(program, frame)
-        return np.concatenate(
-            [np.asarray(b["s"]) for b in out.blocks()]
-        )
-
-    forced_res = run()
-    assert REGISTRY.counter(
-        "tftpu_plan_cost_decisions_total",
-        labels={"decision": "pallas_ragged_gather"},
-    ).value > before
-    configure(pallas_force=False)
-    _assert_eq(run(), forced_res, "ragged map_rows forced vs host")
-
-
 # -- bugfix-sweep pins: zero-row edges of the ragged fallback ---------------
 
 def test_group_rows_by_shape_zero_rows_yields_no_groups():
@@ -502,7 +416,9 @@ def test_pages_walked_follows_the_chunks(page, maxp, pos, walked):
     assert kda.chunk_pages(page, maxp) == min(128 // page, maxp)
 
 
-def test_ops_attention_paged_wrapper():
+def test_ops_attention_paged_wrapper(forced):
+    """The public op is the kernel where the backend can run it (here:
+    the forced interpreter) and the XLA chain where it cannot."""
     from tensorframes_tpu.ops.attention import paged_decode_attention
 
     rng = np.random.default_rng(0)
@@ -511,13 +427,14 @@ def test_ops_attention_paged_wrapper():
     ks = jnp.ones((3, 1, 4, kda.SCALE_LANES), jnp.float32)
     tables = jnp.asarray([[1, 2], [0, 0]], jnp.int32)
     pos = jnp.asarray([5, 0], jnp.int32)
-    got = paged_decode_attention(
-        q, kp, kp, ks, ks, 0, tables, pos, interpret=True
-    )
-    emu = kda.paged_attention_emulation(
-        q, kp, kp, ks, ks, 0, tables, pos
-    )
-    _assert_eq(np.asarray(got), np.asarray(emu), "public wrapper")
+    args = (q, kp, kp, ks, ks, 0, tables, pos)
+    _assert_eq(np.asarray(paged_decode_attention(*args)),
+               np.asarray(kda.paged_attention_emulation(*args)),
+               "public wrapper, kernel")
+    configure(pallas_force=False)
+    _assert_eq(np.asarray(paged_decode_attention(*args)),
+               np.asarray(kda.paged_attention_reference(*args)),
+               "public wrapper, chain")
 
 
 def test_decode_engine_forced_kernel_matches_oracle(forced):
@@ -638,7 +555,7 @@ def test_decode_engine_mosaic_failure_surfaces(forced):
         max_slots=2, page_size=4, max_prompt_len=8, max_new_tokens=3,
         warmup=False,
     ))
-    assert eng._attn_kernel == "pallas"
+    assert eng._attn_is_kernel
 
     def broken(*args):
         raise RuntimeError("Mosaic failed to compile TPU kernel (test)")
@@ -650,7 +567,6 @@ def test_decode_engine_mosaic_failure_surfaces(forced):
             eng.call(
                 {"prompt": np.asarray([1, 2, 3], np.int32)}, timeout=300
             )
-        assert eng._attn_kernel == "pallas"  # not rebuilt on XLA
         assert segment.pallas_enabled()
     finally:
         eng.stop(drain=False, timeout=60)
@@ -660,14 +576,35 @@ def test_decode_engine_mosaic_failure_surfaces(forced):
 # selection, registry, and switches
 # ---------------------------------------------------------------------------
 
+def _traced_step_calls_pallas() -> bool:
+    """Trace a tiny decode step (shapes only) and say whether its jaxpr
+    holds a ``pallas_call``: which attention the step got."""
+    from tensorframes_tpu.models import generation as gen
+    from tensorframes_tpu.models import transformer as tr
+
+    cfg = gen.gpt_tiny()
+    S, page, maxp = 2, 4, 3
+    params = jax.eval_shape(lambda: tr.init_params(cfg, seed=0))
+    pool = jax.eval_shape(
+        lambda: gen.init_paged_kv(cfg, 1 + S * maxp, page)
+    )
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    jaxpr = jax.make_jaxpr(gen.paged_decode_step_fn(cfg, page, maxp))(
+        params, pool, i32(S), i32(S), i32(S, maxp)
+    )
+    return "pallas_call" in str(jaxpr)
+
+
 def test_decisions_on_cpu_default_to_non_pallas():
     cols = {"v": np.zeros(8, np.int32)}
     assert prules.decide_segment_reduce(
         (("v", "reduce_sum"),), cols, 4
     ).kind == "jit_segment_reduce"
-    assert prules.decide_decode_attention(4, 8, 4, 2).kind == \
-        "xla_decode_attn"
-    assert prules.decide_ragged_gather(10, 2, np.float32) is None
+    assert not kernels.selectable("decode_attn")
+    assert not _traced_step_calls_pallas()
 
 
 def test_decisions_under_force_pick_pallas(forced):
@@ -675,11 +612,33 @@ def test_decisions_under_force_pick_pallas(forced):
     assert prules.decide_segment_reduce(
         (("v", "reduce_sum"),), cols, 4
     ).kind == "pallas_segment_reduce"
-    assert prules.decide_decode_attention(4, 8, 4, 2).kind == \
-        "pallas_decode_attn"
-    assert prules.decide_ragged_gather(
-        10, 2, np.float32
-    ).kind == "pallas_ragged_gather"
+    assert kernels.selectable("decode_attn")
+    assert _traced_step_calls_pallas()
+
+
+@pytest.mark.parametrize("force,enabled,favoured,kernel", [
+    (False, True, "pallas_decode_attn", False),   # CPU default: chain
+    (True, True, "xla_decode_attn", True),        # hook: the kernel
+    (True, False, "pallas_decode_attn", False),   # TFTPU_PALLAS=0 wins
+])
+def test_decode_attention_follows_the_backend_alone(
+    forced, force, enabled, favoured, kernel
+):
+    """Which attention a step traces is ``kernels.selectable`` and
+    nothing else: a strategy-wall table that favours the OTHER lowering
+    (what used to flip the choice) changes nothing."""
+    from tensorframes_tpu.plan import stats as plan_stats
+
+    configure(pallas_force=force, pallas_kernels=enabled)
+    for _ in range(4):
+        for kind in ("pallas_decode_attn", "xla_decode_attn"):
+            plan_stats.observe_strategy_wall(
+                "decode_attention", kind,
+                1e-4 if kind == favoured else 10.0,
+            )
+    assert len(plan_stats.strategy_walls("decode_attention")) == 2
+    assert kernels.selectable("decode_attn") is kernel
+    assert _traced_step_calls_pallas() is kernel
 
 
 def test_host_segment_reduce_still_wins_cpu_float_sums(forced):
@@ -694,27 +653,43 @@ def test_host_segment_reduce_still_wins_cpu_float_sums(forced):
 def test_tftpu_pallas_off_removes_kernels_everywhere(forced):
     configure(pallas_kernels=False)
     assert not kernels.enabled()
+    assert not any(kernels.selectable(k) for k in kernels.KERNELS)
     cols = {"v": np.zeros(8, np.int32)}
     assert prules.decide_segment_reduce(
         (("v", "reduce_sum"),), cols, 4
     ).kind == "jit_segment_reduce"
-    assert prules.decide_decode_attention(4, 8, 4, 2).kind == \
-        "xla_decode_attn"
-    assert prules.decide_ragged_gather(
-        10, 2, np.float32
-    ) is None  # the forced fixture restores the prior switch state
+    # the forced fixture restores the prior switch state
+    assert not _traced_step_calls_pallas()
 
 
 def test_selectable_is_the_one_table(forced):
-    """``kernels.selectable`` is what every decide_* function reads:
-    all kernels under the force hook, none on a CPU without it, and the
-    TPU set excludes the gather Mosaic refuses."""
+    """``kernels.selectable`` is what every call site reads: all
+    kernels under the force hook, none on a CPU without it."""
+    assert set(kernels.KERNELS) == {"segment_reduce", "decode_attn"}
     assert all(kernels.selectable(k) for k in kernels.KERNELS)
     configure(pallas_force=False)
     assert not any(kernels.selectable(k) for k in kernels.KERNELS)
-    assert set(kernels.TPU_SELECTABLE) == {"segment_reduce", "decode_attn"}
     with pytest.raises(KeyError):
-        kernels.selectable("nope")
+        kernels.selectable("ragged_gather")
+
+
+def test_every_registered_kernel_is_selectable_on_tpu(monkeypatch):
+    """No registered kernel is one the target cannot select: on a TPU
+    backend every name in ``KERNELS`` is selectable without the hook,
+    and ``TFTPU_PALLAS=0`` still removes them all."""
+    from tensorframes_tpu.config import get_config
+
+    was = get_config().pallas_kernels
+    monkeypatch.setattr(kernels, "is_tpu_backend", lambda: True)
+    try:
+        configure(pallas_kernels=True)
+        assert not kernels.force_active()
+        assert all(kernels.selectable(k) for k in kernels.KERNELS)
+        assert kernels.interpret_mode() is False
+        configure(pallas_kernels=False)
+        assert not any(kernels.selectable(k) for k in kernels.KERNELS)
+    finally:
+        configure(pallas_kernels=was)
 
 
 def test_interpret_mode_refuses_other_backends(monkeypatch):
@@ -746,4 +721,5 @@ def test_kernels_metrics_preregistered():
         for m in REGISTRY.collect()
         if m.name == "tftpu_kernels_dispatch_total"
     }
-    assert labels == set(kernels.KERNELS)
+    assert labels == set(kernels.KERNELS) == {"segment_reduce",
+                                              "decode_attn"}

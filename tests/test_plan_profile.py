@@ -11,8 +11,9 @@ Four surfaces under test:
   ``decide_fuse`` to the per-stage replay on the next execution,
   counted as ``reoptimized``, with bit-identical results; the pure
   ``pick_by_observed_wall`` core honors min-samples and the hysteresis
-  margin; ``decide_epilogue``/``decide_decode_attention`` flip from an
-  injected table and never against the forced-kernel pin;
+  margin; ``decide_epilogue`` flips from an injected table, and only
+  where the ops are exact (kernel choices take no walls at all:
+  ``tests/test_kernels.py``);
 * **sidecar hygiene** — a corrupt ``strategy_walls.json`` quarantines
   (counted + unlinked, decisions fall back to static) and stale entries
   are pruned, mirroring the selectivity-record contract;
@@ -30,7 +31,6 @@ import pytest
 import tensorframes_tpu as tfs
 from tensorframes_tpu.observability import cli, profile
 from tensorframes_tpu.observability.metrics import REGISTRY
-from tensorframes_tpu import kernels
 from tensorframes_tpu.plan import rules
 from tensorframes_tpu.plan import stats as plan_stats
 
@@ -279,27 +279,6 @@ def test_decide_epilogue_flips_only_when_exact():
     assert "latency_flip" not in d.details
 
 
-def test_decide_decode_attention_flip_and_force_pin(monkeypatch):
-    monkeypatch.setattr(kernels, "selectable", lambda kernel: True)
-    monkeypatch.setattr(rules, "_force_pins_kernels", lambda: False)
-    walls = {
-        "pallas_decode_attn": {"ewma_s": 0.02, "n": 4},
-        "xla_decode_attn": {"ewma_s": 0.001, "n": 4},
-    }
-    d = rules.decide_decode_attention(
-        8, 64, 16, 32, observed_walls=walls
-    )
-    assert d.kind == "xla_decode_attn"
-    assert d.details["latency_flip"] is True
-    # TFTPU_PALLAS_FORCE pins the kernel: the flip must never override
-    # the hook that exists to exercise a SPECIFIC lowering
-    monkeypatch.setattr(rules, "_force_pins_kernels", lambda: True)
-    d = rules.decide_decode_attention(
-        8, 64, 16, 32, observed_walls=walls
-    )
-    assert d.kind == "pallas_decode_attn"
-
-
 # ---------------------------------------------------------------------------
 # strategy-wall sidecar hygiene: corrupt → quarantine, stale → pruned
 # ---------------------------------------------------------------------------
@@ -424,8 +403,8 @@ def test_profiler_series_are_preregistered():
     assert ("fuse", "fuse") in pairs
     assert ("fuse", "split_single_stage") in pairs
     assert ("epilogue", "epilogue_concat") in pairs
-    assert ("segment_reduce", "jit_segment_reduce") in pairs
-    assert ("decode_attention", "xla_decode_attn") in pairs
+    # the plan's own decisions only: no kernel choice is timed
+    assert {d for d, _ in pairs} == {"fuse", "epilogue"}
     assert any(
         d["name"] == "tftpu_serving_request_trace_total" for d in snap
     )

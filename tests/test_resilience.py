@@ -455,9 +455,16 @@ def test_verify_audit_mode(tmp_path):
 
 
 def test_orphaned_tmp_gc_on_init(tmp_path):
+    import subprocess
+    import sys
+
     root = tmp_path / "run"
     _save_steps(root, [1])
-    corpse = root / "step_5.tmp9999"
+    # the corpse's owner is a process that has exited and been reaped: a
+    # fixed pid (this test once named 9999) may belong to a live process
+    dead = subprocess.Popen([sys.executable, "-c", "pass"])
+    dead.wait()
+    corpse = root / f"step_5.tmp{dead.pid}"
     corpse.mkdir()
     (corpse / "arrays.npz").write_bytes(b"partial")
     ck = Checkpointer(str(root), backend="npz")

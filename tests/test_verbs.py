@@ -125,16 +125,34 @@ def test_map_rows_scalar():
     assert [r["z"] for r in out] == [float(i * i) for i in range(7)]
 
 
-def test_map_rows_ragged():
+@pytest.mark.parametrize("dtype", ["float32", "float64", "int32"])
+def test_map_rows_ragged(dtype):
     # ragged vectors: the map_rows-only case (core.py:288-289)
     df = tfs.frame_from_rows(
-        [{"y": [1.0]}, {"y": [1.0, 2.0]}, {"y": [1.0, 2.0, 3.0]}]
+        [{"y": np.arange(1, n + 1).astype(dtype)} for n in (1, 2, 3)]
     )
     df = tfs.analyze(df)
     y = tfs.row(df, "y")
     s = tfs.reduce_sum(y, axis=0, name="s")
     out = tfs.map_rows(s, df).collect()
-    assert [r["s"] for r in out] == [1.0, 3.0, 6.0]
+    assert [r["s"] for r in out] == [1, 3, 6]
+    # many rows in four shape groups over three blocks: every group is
+    # stacked on the host, padded to its row bucket and moved in one
+    # wave; each row's result equals numpy's on that row, exactly
+    # (quarter-valued floats and small ints sum without rounding)
+    rng = np.random.default_rng(0)
+    cells = [(np.arange(n) + (0 if dtype == "int32" else 0.25)
+              ).astype(dtype) for n in rng.choice([3, 5, 8, 13], 150)]
+    frame = tfs.frame_from_rows([{"v": c} for c in cells], num_blocks=3)
+    program = tfs.compile_program(
+        lambda v: {"s": v.sum()}, frame, block=False
+    )
+    got = np.concatenate(
+        [np.asarray(b["s"]) for b in tfs.map_rows(program, frame).blocks()]
+    )
+    want = np.asarray([c.sum() for c in cells])  # int32 sums widen
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
 
 
 def test_map_rows_vector_output():
