@@ -197,6 +197,7 @@ def run(cell: harness.Cell, args, t_start: float, devices) -> None:
             traced += 1
             if traced >= trace_passes:
                 capture.close_window()
+                capture.stop_profiler()
         in_trace = capture is not None and capture.state == "window"
         if t1 - t_open >= seconds and not in_trace:
             break

@@ -245,20 +245,33 @@ class DeviceTrace:
     starting has washed out) and closes, the profiler stops; ``reduce``
     reads the xplane once the run is over and deletes it. The annotation
     ``bench.trace_window`` marks the stretch and ties the trace's clock
-    to ``time.perf_counter``."""
+    to ``time.perf_counter``. Starting takes 50 ms on the chip. Stopping
+    takes seconds on an idle host and over a minute beside a loop that
+    keeps the interpreter busy, so a driver whose own thread offers load
+    stops the profiler once the load has ended."""
 
     def __init__(self, tag: str):
         self.dir = os.path.join(WORK_DIR, "trace", tag)
         self._annot = None
-        self.t0 = self.t1 = 0.0
-        self.state = "idle"  # -> profiling -> window -> done
+        #: perf_counter at the call that starts the profiler, at its
+        #: return, and at the traced stretch's open and close
+        self.t_start = self.t_ready = self.t0 = self.t1 = 0.0
+        self.state = "idle"  # -> profiling -> window -> closed -> done
 
     def start_profiler(self) -> None:
         import jax
 
+        self.t_start = time.perf_counter()
         shutil.rmtree(self.dir, ignore_errors=True)
         os.makedirs(self.dir, exist_ok=True)
-        jax.profiler.start_trace(self.dir)
+        # the device planes and the host's own annotations are all that
+        # ``reduce`` reads: no Python call tracing, and of the host's
+        # events only those a program marks itself (level 1)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+        self.t_ready = time.perf_counter()
         self.state = "profiling"
 
     def open_window(self) -> None:
@@ -270,10 +283,13 @@ class DeviceTrace:
         self.state = "window"
 
     def close_window(self) -> None:
-        import jax
-
         self._annot.__exit__(None, None, None)
         self.t1 = time.perf_counter()
+        self.state = "closed"
+
+    def stop_profiler(self) -> None:
+        import jax
+
         jax.profiler.stop_trace()
         self.state = "done"
 
@@ -292,8 +308,12 @@ class DeviceTrace:
                 return None  # a CPU rehearsal: no device plane to read
             window = marks[0]
             to_ns = lambda t: window[0] + (t - self.t0) * 1e9  # noqa: E731
+            # only the spans that reach into the traced stretch: the
+            # window's other spans can label no gap of it
             spans = [(s["name"], to_ns(s["start"]),
-                      to_ns(s["start"] + s["dur"])) for s in host_spans]
+                      to_ns(s["start"] + s["dur"])) for s in host_spans
+                     if s["start"] < self.t1
+                     and s["start"] + s["dur"] > self.t0]
             return trace_reduce.reduce(trace, window, spans)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

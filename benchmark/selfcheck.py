@@ -77,9 +77,9 @@ def check_trace_reduce() -> None:
     check(tr.union([(0, 2), (1, 3), (5, 6)]) == [(0, 3), (5, 6)]
           and tr.gaps([(0, 3), (5, 6)], (0, 8)) == [(3, 5), (6, 8)],
           "union and gaps")
-    check(tr.label_gap((passes[1][0] + 2e5, passes[1][0] + 3e5), spans)
-          == "inner", "a gap is labelled by the innermost covering span")
-    check(tr.label_gap((0.0, 1.0), spans) == "(no host span)",
+    check(tr.label_gaps([(passes[1][0] + 2e5, passes[1][0] + 3e5)], spans)
+          == ["inner"], "a gap is labelled by the innermost covering span")
+    check(tr.label_gaps([(0.0, 1.0)], spans) == ["(no host span)"],
           "a gap no span covers says so")
     check(tr.op_name("%fusion.12 = bf16[8]{0} fusion(%x)") == "fusion.12"
           and tr.program_name("jit_step(123)") == "jit_step", "names")

@@ -48,8 +48,9 @@ def test_int4_gpt2_is_not_correct():
     # served tokens are the reference's own (gap 0 or rounding), the
     # int4 control's first choice lies 0.04-0.09 below the best;
     # the program (bfloat16 activations, int8 weights and KV) reads up to 0.006
-    rows = [readings("gpt2-small.closed-loop", seed) for seed in (21, 22, 23)]
-    limit = cell_limit("gpt2-small.closed-loop", "served_logit_gap")
+    name = "gpt2-small.closed-loop-384"
+    rows = [readings(name, seed) for seed in (21, 22, 23)]
+    limit = cell_limit(name, "served_logit_gap")
     for row in rows:
         assert row["served_tokens"] > 0
         assert row["served_logit_gap"] <= limit < row["control_logit_gap"]

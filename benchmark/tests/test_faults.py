@@ -19,7 +19,7 @@ import pytest
 
 FRAME_1 = "inception-v3.device-frame"
 FRAME_4 = "inception-v3.device-frame-4chip"
-SERVE = "gpt2-small.closed-loop"
+SERVE = "gpt2-small.closed-loop-384"
 
 
 def drive(name, capsys, seed=7, seconds=1.0):

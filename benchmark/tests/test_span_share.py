@@ -15,10 +15,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 NEW = {
-    "gpt2-small.closed-loop": {
+    "gpt2-small.closed-loop-384": {
         "decode_host_share", "decode_admit_ms_p50", "decode_prepare_ms_p50",
         "decode_commit_ms_p50", "decode_enqueue_ms_p50",
-        "prefill_dispatch_ms_p50"},
+        "prefill_dispatch_ms_p50", "client_resubmit_lag_ms_p95",
+        "decode_pages_walked_share", "kv_pool_used_share",
+        "slowest_tenth_rate_share", "collector_share"},
     "inception-v3.device-frame": {
         "frame_host_share", "executor_prepare_ms_p50",
         "reduce_gather_ms_p50", "reduce_fetch_ms_p50"},

@@ -110,19 +110,39 @@ def gaps(busy: Sequence[Interval], window: Interval) -> List[Interval]:
     return out
 
 
-def label_gap(gap: Interval, host_spans: Sequence[Tuple[str, float, float]]
-              ) -> str:
-    """The host span that covers most of ``gap``; of spans that cover it
-    equally (nested ones), the shortest, which is the innermost."""
-    best, best_key = "(no host span)", (0.0, 0.0)
-    for name, a, b in host_spans:
-        cover = min(b, gap[1]) - max(a, gap[0])
-        if cover <= 0:
-            continue
-        key = (cover, -(b - a))
-        if key > best_key:
-            best, best_key = name, key
-    return best
+def label_gaps(idle: Sequence[Interval],
+               host_spans: Sequence[Tuple[str, float, float]]) -> List[str]:
+    """For every gap of ``idle`` the host span that covers most of it;
+    of spans that cover it equally (nested ones) the shortest, which is
+    the innermost, and of equally long ones the first of ``host_spans``;
+    ``"(no host span)"`` where none reaches into it. One sweep: gaps and
+    spans both walked in order of their starts, the spans that still
+    reach into the gap in hand kept in a list. Each span enters and
+    leaves that list once, so the cost is the sorting plus the pairs of
+    gap and span that do overlap (``tests/test_trace_reduce.py`` holds
+    the sweep to one scan of every span for every gap)."""
+    spans = sorted(
+        ((a, b, i, name) for i, (name, a, b) in enumerate(host_spans)
+         if b > a), key=lambda s: s[0])
+    out = ["(no host span)"] * len(idle)
+    live: List[Tuple[float, float, int, str]] = []
+    at = 0
+    for k in sorted(range(len(idle)), key=lambda k: idle[k][0]):
+        lo, hi = idle[k]
+        live = [s for s in live if s[1] > lo]
+        while at < len(spans) and spans[at][0] < hi:
+            if spans[at][1] > lo:
+                live.append(spans[at])
+            at += 1
+        best_key = (0.0, 0.0, 0)
+        for a, b, i, name in live:
+            cover = min(b, hi) - max(a, lo)
+            if cover <= 0:
+                continue
+            key = (cover, -(b - a), -i)
+            if key > best_key:
+                out[k], best_key = name, key
+    return out
 
 
 def reduce(trace: Trace, window: Interval,
@@ -158,8 +178,8 @@ def reduce(trace: Trace, window: Interval,
                 per[name] = per.get(name, 0.0) + (b - a) * 1e-9
     first = trace.devices[0]
     idle = gaps(union([(a, b) for _, a, b in first.ops], window), window)
-    labelled = [(label_gap(g, host_spans), (g[1] - g[0]) * 1e-9)
-                for g in idle]
+    labelled = [(name, (g[1] - g[0]) * 1e-9)
+                for name, g in zip(label_gaps(idle, host_spans), idle)]
     longest = sorted(labelled, key=lambda x: -x[1])[:top // 2]
     sums: Dict[str, float] = {}
     for name, s in labelled:
