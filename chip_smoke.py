@@ -46,7 +46,7 @@ PASS_TIMEOUT_S = 900
 TOTAL_BUDGET_S = 1150
 
 LEGS = ("a_inception", "b_aggregate", "c_ragged", "d_bert", "e_add3",
-        "serving", "no_hidden_fallback")
+        "serving", "experts", "no_hidden_fallback")
 
 
 # ---------------------------------------------------------------------------
@@ -575,6 +575,45 @@ def child_main(args) -> int:
                 "steady_jit_misses": steady,
                 "attn_err": float(f"{err:.3g}"),
                 "attn_scale": round(scale, 2)}
+
+    # -- the served expert layer: grouped-matmul kernel against ragged_dot --
+    @leg("experts")
+    def _():
+        from jax import lax
+
+        from tensorframes_tpu.kernels import expert_matmul as em
+        from tensorframes_tpu.models import moe
+
+        # the sparse-expert decoder's widths on the chip, tiny ones here
+        t, d, f, e, k = (16, 64, 32, 8, 2) if rehearsal \
+            else (128, 2304, 896, 64, 8)
+        rng = np.random.default_rng(5)
+        h = jnp.asarray(rng.standard_normal((t, d)), jnp.bfloat16)
+        router = jnp.asarray(rng.standard_normal((d, e)) * .02, jnp.bfloat16)
+        wg, wu = (jnp.asarray(rng.standard_normal((e, d, f)) * .02,
+                              jnp.bfloat16) for _ in range(2))
+        wd = jnp.asarray(rng.standard_normal((e, f, d)) * .02, jnp.bfloat16)
+        ids, weights = moe.route_topk(h, router, k)
+        # routed_experts traces the kernel exactly where it is selectable
+        got = np.asarray(jax.jit(moe.routed_experts)(
+            h, ids, weights, wg, wu, wd))
+        if "expert_matmul" in selectable:
+            kernels.note_dispatch("expert_matmul", kernels.interpret_mode())
+        order = jnp.argsort(ids.reshape(-1), stable=True)
+        sizes = jnp.bincount(ids.reshape(-1), length=e).astype(jnp.int32)
+        rows = h[order // k]
+        # float32 accumulation on both sides: the same products, folded
+        # a k tile at a time by the kernel
+        want = lax.ragged_dot(rows, wg, sizes,
+                              preferred_element_type=jnp.float32)
+        one = np.asarray(em.grouped_matmul(
+            rows, wg, sizes, interpret=kernels.interpret_mode()
+        ) if "expert_matmul" in selectable else want)
+        err = float(np.abs(one - np.asarray(want)).max())
+        scale = float(np.abs(np.asarray(want)).max())
+        assert np.isfinite(got).all() and err <= 2e-3 * scale, (err, scale)
+        return {"pairs": t * k, "tiling": "x".join(map(str, em.tiling(d, f))),
+                "gmm_err": float(f"{err:.3g}"), "scale": round(scale, 3)}
 
     # -- no hidden fallback: read the registry -------------------------------
     @leg("no_hidden_fallback")

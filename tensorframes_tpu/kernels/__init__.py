@@ -9,6 +9,10 @@
   in-register, and the attention math runs in the same kernel — no
   materialized ``[S, pages, page, heads*hd]`` copy, reading the
   resident pool columns in the layout the KV write leaves them in.
+* :mod:`.expert_matmul` — the served expert layer's grouped matmul:
+  rows sorted by expert against each held expert's matrix, a row tile
+  a grid step (``megablox.gmm`` with tiles for the expert widths), in
+  ``lax.ragged_dot``'s place.
 
 **One rule decides whether a kernel runs: what the process can observe
 about its backend, and the call's own operands.** :func:`selectable`
@@ -19,8 +23,9 @@ backend is a TPU (Mosaic compiles every registered kernel; one it
 refuses raises at the call site, nothing falls back) or the test hook
 ``TFTPU_PALLAS_FORCE=1`` puts them on the CPU pallas interpreter. The
 second half is the kernel's own ``eligible`` / shape check. The call
-sites ask here — ``ops.attention.paged_decode_attention`` at trace
-time, ``plan/rules.decide_segment_reduce`` per reduction — and
+sites ask here — ``ops.attention.paged_decode_attention`` and
+``models/moe.routed_experts`` at trace time,
+``plan/rules.decide_segment_reduce`` per reduction — and
 ``chip_smoke.py`` checks the dispatch counters against the same table.
 Nothing is timed to choose a kernel and nothing about the choice is
 persisted; the compile-cache fingerprint carries
@@ -31,7 +36,9 @@ Every kernel is gated against its plain-jnp same-tiling emulation
 bitwise (what catches indexing, masking and dequant bugs) and against
 the XLA/host reference: exactly where that is structural (min/max,
 integer sums), to float tolerance for the decode attention's online
-softmax.
+softmax. The expert matmul is a library kernel (``megablox.gmm``) with
+this package's tiles: it is gated against ``lax.ragged_dot`` to float32
+rounding, and row for row against itself (batched equals solo).
 """
 
 from __future__ import annotations
@@ -57,7 +64,7 @@ __all__ = [
 #: The registered kernel names — one counted dispatch series each.
 #: Every one compiles under Mosaic (v5e, jax 0.9.0 / libtpu 0.0.34);
 #: ``chip_smoke.py`` requires a non-zero dispatch count for each.
-KERNELS = ("segment_reduce", "decode_attn")
+KERNELS = ("segment_reduce", "decode_attn", "expert_matmul")
 
 # Pre-registered at import (the `# kernels |` bench summary and the
 # exposition must always carry the family — a process that never

@@ -99,15 +99,20 @@ def chunk_pages(page: int, maxp: int) -> int:
     return min(max(1, 128 // int(page)), int(maxp))
 
 
-def pages_walked(pos, page: int, maxp: int):
+def pages_walked(pos, page: int, maxp: int, window: Optional[int] = None):
     """Table entries covered by the chunks the kernel folds for slots at
     positions ``pos`` (a numpy array): a slot's context is ``pos + 1``
     positions, so it folds ``pos // (G*page) + 1`` chunks of ``G``
     entries (the last chunk of a table that ``G`` does not divide is
     short); a padding slot (``pos`` 0) folds one. The one-page-a-step
-    grid this walk replaced ran ``maxp`` entries for every slot."""
+    grid this walk replaced ran ``maxp`` entries for every slot. With a
+    ``window`` the walk starts at the chunk that holds ``pos - (window
+    - 1)``: the chunks before it are not folded."""
     g = chunk_pages(page, maxp)
-    return np.minimum((pos // (g * page) + 1) * g, maxp)
+    if window is None:
+        return np.minimum((pos // (g * page) + 1) * g, maxp)
+    first = np.maximum(pos - (int(window) - 1), 0) // (g * page)
+    return (pos // (g * page) + 1 - first) * g
 
 
 def _head_rows(nh: int) -> int:
@@ -115,12 +120,18 @@ def _head_rows(nh: int) -> int:
     return -(-nh // 16) * 16
 
 
-def _own_lanes(nh: int, hd: int):
-    """``[head_rows, nh*hd]`` bool: row ``h`` owns head ``h``'s lanes
-    (the rows beyond ``nh`` own none)."""
-    shape = (_head_rows(nh), nh * hd)
+def _own_lanes(nh: int, hd: int, group: int = 1):
+    """``[head_rows, (nh // group)*hd]`` bool: row ``h`` owns the lanes
+    of the KV head it reads, ``h // group`` (the rows beyond ``nh`` own
+    none). ``group`` is 1 where every query head has a KV head of its
+    own."""
+    shape = (_head_rows(nh), (nh // group) * hd)
     head = lax.broadcasted_iota(jnp.int32, shape, 0)
     lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    if group > 1:
+        live = head < nh
+        head = lax.div(head, np.int32(group))
+        return live & (lane >= head * hd) & (lane < head * hd + hd)
     return (lane >= head * hd) & (lane < head * hd + hd)
 
 
@@ -149,13 +160,30 @@ def _q_rows(q, nh: int, hd: int):
     return _split3(jnp.where(_own_lanes(nh, hd), q, np.float32(0)))
 
 
-def _fold_init(nh: int, hd: int):
+def _q_rows_grouped(q, nq: int, nkv: int, hd: int):
+    """``q`` [nq, hd] f32 → ``[3*head_rows, nkv*hd]`` bf16 for grouped
+    heads: row ``j`` holds query head ``j``'s values on the lanes of KV
+    head ``j // group`` and 0 elsewhere, so one matmul against a chunk's
+    key rows folds each KV head with its ``group`` query heads."""
+    hr = _head_rows(nq)
+    tiled = jnp.concatenate([q] * nkv, axis=1)
+    if hr > nq:
+        tiled = jnp.concatenate(
+            [tiled, jnp.zeros((hr - nq, nkv * hd), jnp.float32)], axis=0
+        )
+    return _split3(jnp.where(
+        _own_lanes(nq, hd, nq // nkv), tiled, np.float32(0)
+    ))
+
+
+def _fold_init(nh: int, hd: int, group: int = 1):
     """A slot's running max, denominator ``[head_rows, 1]`` and context
-    accumulator ``[head_rows, nh*hd]`` before its first chunk."""
+    accumulator ``[head_rows, (nh // group)*hd]`` before its first
+    chunk."""
     f32 = jnp.float32
     hr = _head_rows(nh)
     return (jnp.full((hr, 1), _NEG, f32), jnp.zeros((hr, 1), f32),
-            jnp.zeros((hr, nh * hd), f32))
+            jnp.zeros((hr, (nh // group) * hd), f32))
 
 
 def _chunk_update(qx, k, v, ks, vs, valid, m, l, acc, sm_scale: float,
@@ -194,12 +222,29 @@ def _fold_finish(l, acc, nh: int, hd: int):
     )
 
 
+def _fold_finish_grouped(l, acc, nq: int, nkv: int, hd: int):
+    """``[nq, hd]`` context for grouped heads: row ``j`` of the
+    accumulator is meaningful on the lanes of KV head ``j // group``."""
+    o = acc / l
+    kv_of_row = lax.div(
+        lax.broadcasted_iota(jnp.int32, (o.shape[0], hd), 0),
+        np.int32(nq // nkv),
+    )
+    out = jnp.zeros((o.shape[0], hd), jnp.float32)
+    for g in range(nkv):
+        out = jnp.where(kv_of_row == g, o[:, g * hd:(g + 1) * hd], out)
+    return out[:nq]
+
+
 def _check_pool(q, k_pages, k_scale):
     S, nh, hd = q.shape
-    if k_pages.ndim != 4 or k_pages.shape[-1] != nh * hd:
+    width = k_pages.shape[-1] if k_pages.ndim == 4 else 0
+    if k_pages.ndim != 4 or width % hd or (
+            width != nh * hd and nh % max(width // hd, 1)):
         raise ValueError(
-            f"k/v pages must be [pages, layers, page_size, heads*head_dim"
-            f"={nh * hd}], got {tuple(k_pages.shape)}"
+            f"k/v pages must be [pages, layers, page_size, kv_heads*"
+            f"head_dim] with the {nh} query heads a multiple of the KV "
+            f"heads (head_dim {hd}), got {tuple(k_pages.shape)}"
         )
     if k_scale.shape != k_pages.shape[:3] + (SCALE_LANES,) \
             or nh > SCALE_LANES:
@@ -211,19 +256,30 @@ def _check_pool(q, k_pages, k_scale):
 
 def paged_decode_attention(
     q: jnp.ndarray,          # [S, nh, hd] activation dtype
-    k_pages: jnp.ndarray,    # [P, L, page, nh*hd] int8
-    v_pages: jnp.ndarray,    # [P, L, page, nh*hd] int8
+    k_pages: jnp.ndarray,    # [P, L, page, kv_heads*hd] int8
+    v_pages: jnp.ndarray,    # [P, L, page, kv_heads*hd] int8
     k_scale: jnp.ndarray,    # [P, L, page, SCALE_LANES] f32
     v_scale: jnp.ndarray,    # [P, L, page, SCALE_LANES] f32
     layer: int,              # layer index
     tables: jnp.ndarray,     # [S, maxp] int32 page tables
     pos: jnp.ndarray,        # [S] int32 current positions
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
+    ring: bool = False,
 ) -> jnp.ndarray:
     """One layer's paged decode attention for every slot: returns the
     ``[S, nh, hd]`` context in ``q.dtype``. Traceable (callers embed it
     in the jitted decode step); ``interpret`` defaults to the backend's
-    :func:`tensorframes_tpu.kernels.interpret_mode`."""
+    :func:`tensorframes_tpu.kernels.interpret_mode`.
+
+    Grouped heads: the pool rows may hold fewer KV heads than ``q`` has
+    query heads (a whole multiple); query head ``j`` reads KV head ``j
+    // group``, and the pool's scale lane ``j`` holds that KV head's
+    scale (the KV write lays a scale out once per query head). With a
+    ``window`` a slot attends positions ``pos - (window - 1) .. pos``
+    and the walk starts at the chunk that holds the first of them. With
+    ``ring`` the table is a ring: logical page ``j`` of a slot's context
+    stands at entry ``j % maxp``."""
     from . import interpret_mode
 
     if interpret is None:
@@ -233,12 +289,14 @@ def paged_decode_attention(
         np.asarray([layer], np.int32), tables.astype(jnp.int32),
         pos.astype(jnp.int32), q, k_pages, v_pages, k_scale, v_scale,
         interpret=bool(interpret),
+        window=None if window is None else int(window), ring=bool(ring),
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "ring"))
 def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
-                v_scale, *, interpret: bool):
+                v_scale, *, interpret: bool, window: Optional[int] = None,
+                ring: bool = False):
     """The kernel call. Jitted with the layer an operand so that a
     step's twelve layers trace and lower ONE kernel: traced per layer,
     the kernel was most of what ``server.start()`` spends on a warm
@@ -247,7 +305,9 @@ def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
     from jax.experimental.pallas import tpu as pltpu
 
     S, nh, hd = q.shape
-    width = nh * hd
+    width = int(k_pages.shape[-1])
+    nkv = width // hd
+    grouped = nkv != nh
     page = int(k_pages.shape[2])
     maxp = int(tables.shape[1])
     f32, bf16, i32 = jnp.float32, jnp.bfloat16, np.int32
@@ -264,19 +324,38 @@ def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
         columns = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
                    (vs_hbm, vs_buf))
 
+        def first_pos(slot):
+            """The first position slot ``slot`` attends (windowed)."""
+            return jnp.maximum(pos_ref[slot] - i32(window - 1), zero)
+
+        def first_chunk(slot):
+            if window is None:
+                return zero
+            return lax.div(first_pos(slot), i32(rows))
+
         def chunk_copies(slot, c, half, start):
             """Start, or await, the copies of slot ``slot``'s chunk
             ``c`` into buffer ``half``: one copy per column and page the
             slot's context reaches, none beyond it — so a chunk past
             the slot's last is no copy at all."""
             last = lax.div(pos_ref[slot], i32(page))
+            if window is not None:
+                first = lax.div(first_pos(slot), i32(page))
             for g in range(G):
                 j = c * G + g
+                wanted = j <= last
+                if window is not None:
+                    wanted = wanted & (j >= first)
 
-                @pl.when(j <= last)
+                @pl.when(wanted)
                 def _():
                     # awaiting needs the copy's size, not its source
-                    pg = tbl_ref[slot, j] if start else zero
+                    if not start:
+                        pg = zero
+                    elif ring:
+                        pg = tbl_ref[slot, lax.rem(j, i32(maxp))]
+                    else:
+                        pg = tbl_ref[slot, j]
                     for i, (hbm, buf) in enumerate(columns):
                         cp = pltpu.make_async_copy(
                             hbm.at[pg, li], buf.at[half, i32(g)],
@@ -287,24 +366,32 @@ def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
         @pl.when(s == 0)
         def _first():
             half_ref[0] = zero
-            chunk_copies(s, zero, zero, True)
+            chunk_copies(s, first_chunk(s), zero, True)
 
         half0 = half_ref[0]
         n = lax.div(pos_s, i32(rows)) + 1
+        c0 = first_chunk(s)
+        if window is not None:
+            n = n - c0
+            lo = first_pos(s)
         half_ref[0] = (half0 + n) & 1
-        qx = _q_rows(q_ref[0], nh, hd)
+        if grouped:
+            qx = _q_rows_grouped(q_ref[0], nh, nkv, hd)
+        else:
+            qx = _q_rows(q_ref[0], nh, hd)
 
-        def fold(c, carry):
-            half = (half0 + c) & 1
+        def fold(r, carry):
+            half = (half0 + r) & 1
+            c = r if window is None else r + c0
             # look ahead into the other half, which chunk c-1 has left:
             # this slot's next chunk or, under its last (where that is
             # no copy at all), the next slot's first
             chunk_copies(s, c + 1, 1 - half, True)
 
-            @pl.when((c == n - 1) & (s + 1 < S))
+            @pl.when((r == n - 1) & (s + 1 < S))
             def _next_slot():
-                chunk_copies(jnp.minimum(s + 1, S - 1), zero, 1 - half,
-                             True)
+                nxt = jnp.minimum(s + 1, S - 1)
+                chunk_copies(nxt, first_chunk(nxt), 1 - half, True)
 
             chunk_copies(s, c, half, False)
 
@@ -317,25 +404,36 @@ def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
             kpos = c * rows + lax.broadcasted_iota(
                 jnp.int32, (1, rows), 1
             )
+            folded = (chunk(k_buf, bf16), chunk(v_buf, bf16),
+                      chunk(ks_buf, f32), chunk(vs_buf, f32))
+            valid = kpos <= pos_s
+            if window is not None:
+                valid = valid & (kpos >= lo)
             return _chunk_update(
-                qx, chunk(k_buf, bf16), chunk(v_buf, bf16),
-                chunk(ks_buf, f32), chunk(vs_buf, f32), kpos <= pos_s,
-                *carry, sm_scale, nh,
+                qx, *folded, valid, *carry, sm_scale, nh,
             )
 
-        _, l, acc = lax.fori_loop(zero, n, fold, _fold_init(nh, hd))
-        o_ref[0] = _fold_finish(l, acc, nh, hd)
+        _, l, acc = lax.fori_loop(
+            zero, n, fold, _fold_init(nh, hd, nh // nkv)
+        )
+        if grouped:
+            o_ref[0] = _fold_finish_grouped(l, acc, nh, nkv, hd)
+        else:
+            o_ref[0] = _fold_finish(l, acc, nh, hd)
 
     def slot_map(s, lay, tbl, p):
         return (s, s - s, s - s)
 
+    # a slot's query and context: one row of all heads' lanes, or with
+    # grouped heads the heads on sublanes (the fold's own layout)
+    qo_block = (1, nh, hd) if grouped else (1, 1, width)
     pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
-        in_specs=[pl.BlockSpec((1, 1, width), slot_map), pool, pool,
+        in_specs=[pl.BlockSpec(qo_block, slot_map), pool, pool,
                   pool, pool],
-        out_specs=pl.BlockSpec((1, 1, width), slot_map),
+        out_specs=pl.BlockSpec(qo_block, slot_map),
         scratch_shapes=[
             # the double buffer: [half, page of the chunk, page, ·],
             # each copy's target a whole [page, ·] slab
@@ -352,19 +450,20 @@ def _paged_walk(layer, tables, pos, q, k_pages, v_pages, k_scale,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, 1, width), f32),
+        out_shape=jax.ShapeDtypeStruct((S,) + qo_block[1:], f32),
         interpret=bool(interpret),
         name="paged_decode_attention",
     )(
-        layer, tables, pos, q.astype(f32).reshape(S, 1, width), k_pages,
-        v_pages, k_scale, v_scale,
+        layer, tables, pos, q.astype(f32).reshape((S,) + qo_block[1:]),
+        k_pages, v_pages, k_scale, v_scale,
     )
     return out.reshape(S, nh, hd).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("layer",))
+@functools.partial(jax.jit, static_argnames=("layer", "window", "ring"))
 def paged_attention_emulation(
-    q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos
+    q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos,
+    window: Optional[int] = None, ring: bool = False,
 ):
     """Plain-jnp emulation of the kernel's exact computation — the
     bit-identity oracle: the same :func:`_chunk_update` folded over the
@@ -374,28 +473,37 @@ def paged_attention_emulation(
     the grid: a loop around ONE compiled body."""
     _check_pool(q, k_pages, k_scale)
     S, nh, hd = q.shape
-    width = nh * hd
+    width = int(k_pages.shape[-1])
+    nkv = width // hd
+    grouped = nkv != nh
     page = int(k_pages.shape[2])
     maxp = int(tables.shape[1])
     li = int(layer)
     f32, bf16 = jnp.float32, jnp.bfloat16
     sm_scale = 1.0 / float(np.sqrt(hd))
-    qf = q.astype(f32).reshape(S, 1, width)
+    qf = q.astype(f32).reshape((S, nh, hd) if grouped else (S, 1, width))
     G = chunk_pages(page, maxp)
     rows = G * page
 
     def slot(s):
-        qx = _q_rows(qf[s], nh, hd)
+        if grouped:
+            qx = _q_rows_grouped(qf[s], nh, nkv, hd)
+        else:
+            qx = _q_rows(qf[s], nh, hd)
+        lo = 0 if window is None else jnp.maximum(pos[s] - (window - 1), 0)
 
         def fold(c, carry):
-            # entries past the slot's last page are never copied by the
-            # kernel; whatever stands there is selected away, so the
-            # last real page does as well as any
-            pgs = tables[s, jnp.minimum(c * G + jnp.arange(G),
-                                        pos[s] // page)]
+            # entries the kernel never copies (past the slot's last
+            # page, before its window's first) are selected away
+            # whatever stands there, so a real page does as well as any
+            j = jnp.clip(c * G + jnp.arange(G), lo // page, pos[s] // page)
+            pgs = tables[s, j % maxp if ring else j]
             kpos = c * rows + lax.broadcasted_iota(
                 jnp.int32, (1, rows), 1
             )
+            valid = kpos <= pos[s]
+            if window is not None:
+                valid = valid & (kpos >= lo)
 
             def chunk(col, dtype):
                 return col[pgs, li].reshape(rows, -1).astype(dtype)
@@ -403,12 +511,15 @@ def paged_attention_emulation(
             return _chunk_update(
                 qx, chunk(k_pages, bf16), chunk(v_pages, bf16),
                 chunk(k_scale, f32), chunk(v_scale, f32),
-                kpos <= pos[s], *carry, sm_scale, nh,
+                valid, *carry, sm_scale, nh,
             )
 
         _, l, acc = lax.fori_loop(
-            0, pos[s] // rows + 1, fold, _fold_init(nh, hd)
+            lo // rows, pos[s] // rows + 1, fold,
+            _fold_init(nh, hd, nh // nkv),
         )
+        if grouped:
+            return _fold_finish_grouped(l, acc, nh, nkv, hd)
         return _fold_finish(l, acc, nh, hd)
 
     out = lax.map(slot, jnp.arange(S))
@@ -416,11 +527,13 @@ def paged_attention_emulation(
 
 
 def paged_attention_reference(
-    q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos
+    q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos,
+    window: Optional[int] = None, ring: bool = False,
 ):
     """The XLA gather→dequant→attend chain — the production non-kernel
     lowering (``paged_decode_step_fn``'s other branch calls it) AND the
-    float oracle the kernel is checked against to tolerance."""
+    float oracle the kernel is checked against to tolerance. Grouped
+    heads, a window and a ring table as the kernel takes them."""
     _check_pool(q, k_pages, k_scale)
     S, nh, hd = q.shape
     page = int(k_pages.shape[2])
@@ -429,6 +542,34 @@ def paged_attention_reference(
     dtype = q.dtype
     li = int(layer)
     neg = jnp.asarray(_NEG, jnp.float32)
+    nkv = int(k_pages.shape[-1]) // hd
+    if nkv != nh or window is not None or ring:
+        g = nh // nkv
+        entry = jnp.arange(maxp)[None, :]
+        if ring:
+            # entry e holds the newest logical page congruent to it
+            last = (pos // page)[:, None]
+            entry = last - (last - entry) % maxp
+        kpos = (entry[:, :, None] * page
+                + jnp.arange(page)[None, None, :]).reshape(-1, C)
+        valid = (kpos >= 0) & (kpos <= pos[:, None])
+        if window is not None:
+            valid = valid & (kpos >= pos[:, None] - (window - 1))
+        pk = k_pages[tables, li].reshape(S, C, nkv, hd)
+        pv = v_pages[tables, li].reshape(S, C, nkv, hd)
+        pks = k_scale[tables, li][..., :nh].reshape(S, C, nh)
+        pvs = v_scale[tables, li][..., :nh].reshape(S, C, nh)
+        scores = jnp.einsum(
+            "nkgd,nckd->nkgc", q.reshape(S, nkv, g, hd), pk.astype(dtype),
+            preferred_element_type=jnp.float32,
+        ).reshape(S, nh, C) / float(np.sqrt(hd))
+        scores = scores * pks.transpose(0, 2, 1)
+        scores = jnp.where(valid[:, None, :], scores, neg)
+        w = jax.nn.softmax(scores, axis=-1)
+        w = (w * pvs.transpose(0, 2, 1)).astype(dtype)
+        return jnp.einsum(
+            "nkgc,nckd->nkgd", w.reshape(S, nkv, g, C), pv.astype(dtype)
+        ).reshape(S, nh, hd)
     valid = jnp.arange(C)[None, :] <= pos[:, None]
     # each slot's pages as one context: [S, maxp, page, ·] → [S, nh, C, ·]
     pk = k_pages[tables, li].reshape(S, C, nh, hd).transpose(0, 2, 1, 3)

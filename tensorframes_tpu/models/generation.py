@@ -649,6 +649,26 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
     return step
 
 
+def served_model(cfg: TransformerConfig, page_size: int, horizon: int):
+    """The transformer family as the decode engine takes it
+    (``models/served.py``): one page kind holding every layer, a page
+    per ``page_size`` positions of context, and all five programs."""
+    from .served import PageKind, ServedModel
+
+    max_pages = -(-int(horizon) // int(page_size))
+    return ServedModel(
+        vocab_size=int(cfg.vocab_size),
+        max_seq_len=int(cfg.max_seq_len),
+        kinds=(PageKind(
+            "kv", max_pages,
+            lambda num_pages: init_paged_kv(cfg, num_pages, page_size)),),
+        prefill=paged_prefill_fn(cfg, page_size, max_pages),
+        step=paged_decode_step_fn(cfg, page_size, max_pages),
+        suffix_prefill=paged_suffix_prefill_fn(cfg, page_size, max_pages),
+        page_ops=paged_page_ops_fns(max_pages),
+    )
+
+
 def generate_program(
     cfg: TransformerConfig,
     params: Dict,

@@ -18,11 +18,22 @@ ring attention did for sequence parallelism. Design is TPU-native
 ``moe_ffn`` (single-device einsum math) and ``moe_ffn_ep`` (shard_map +
 all_to_all) compute the same function when capacity is not exceeded —
 that equivalence is the correctness test.
+
+**The served expert layer is another one**: :func:`route_topk` and
+:func:`routed_experts`, at the end of this file. The switch FFN above
+drops tokens past a capacity and is reached from the training dry-run
+(``__graft_entry__``) and ``tests/test_moe.py`` only; the decode
+engine's programs (``models/sparse_decoder.py``) route every token to
+its top-k experts of all of them, drop none whatever the imbalance, and
+multiply the tokens grouped by expert in grouped matmuls
+(``kernels/expert_matmul.py`` where the backend runs it,
+``lax.ragged_dot`` elsewhere) over the experts this holder has.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -303,3 +314,84 @@ def scoring_program(cfg: MoEConfig, params: Dict):
         return {"moe_out": moe_ffn(cfg, params, features)}
 
     return program
+
+
+# ---------------------------------------------------------------------------
+# The served expert layer: top-k of all experts, no token dropped,
+# grouped matmuls over the experts held (models/sparse_decoder.py)
+# ---------------------------------------------------------------------------
+
+def route_topk(h: jnp.ndarray, router: jnp.ndarray, top_k: int,
+               renormalize: bool = True):
+    """Route ``h`` [tokens, hidden] over ALL experts: softmax of ``h @
+    router`` in float32, the ``top_k`` largest, their weights divided by
+    their sum where ``renormalize``. Returns ``(experts [tokens, top_k]
+    int32, weights [tokens, top_k] float32)``."""
+    logits = jnp.matmul(h, router, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, int(top_k))
+    if renormalize:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def routed_experts(h: jnp.ndarray, experts: jnp.ndarray,
+                   weights: jnp.ndarray, w_gate: jnp.ndarray,
+                   w_up: jnp.ndarray, w_down: jnp.ndarray,
+                   first_expert: int = 0) -> jnp.ndarray:
+    """The part of the expert layer's result that THIS holder's experts
+    give: ``w_gate`` / ``w_up`` [held, hidden, width] and ``w_down``
+    [held, width, hidden] are experts ``first_expert .. first_expert +
+    held`` of the routing's range. Every (token, expert) pair whose
+    expert is held is computed, however uneven the routing; the pairs of
+    absent experts add nothing here (their holders add them, and the
+    shares sum to the whole layer). Pairs are grouped by expert and
+    multiplied in three grouped matmuls (:func:`_grouped_matmul`: one
+    pass over the pairs, the matrices of the experts that got any),
+    gated-SiLU, float32 accumulation; a token's result is the weighted sum over its own
+    experts in top-k order, so a row does not depend on what else is in
+    the batch. ``h`` [tokens, hidden] → float32 [tokens, hidden]."""
+    tokens, top_k = experts.shape
+    held = int(w_gate.shape[0])
+    local = experts.reshape(-1) - int(first_expert)
+    # absent experts sort last and fall outside every group
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    rows = h[order // top_k]
+    matmul = _grouped_matmul()
+    gate, up = matmul(rows, w_gate, sizes), matmul(rows, w_up, sizes)
+    act = (jax.nn.silu(gate) * up).astype(h.dtype)
+    out = matmul(act, w_down, sizes)
+    # the rows of absent experts lie past every group: not computed
+    out = jnp.where((local[order] < held)[:, None], out, 0.0)
+    # back to (token, k) order, then each token's own weighted sum
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    return (out[inverse].reshape(tokens, top_k, -1)
+            * weights[:, :, None]).sum(axis=1)
+
+
+def _grouped_matmul():
+    """``(rows [m, k], w [held, k, n], sizes [held]) -> float32 [m, n]``,
+    the rows sorted by expert: the Pallas kernel where the backend runs
+    it (``kernels.selectable("expert_matmul")``, asked where the
+    program is traced), ``lax.ragged_dot`` elsewhere."""
+    from .. import kernels as _kernels
+
+    if _kernels.selectable("expert_matmul"):
+        from ..kernels.expert_matmul import grouped_matmul
+
+        return functools.partial(grouped_matmul,
+                                 interpret=_kernels.interpret_mode())
+    return functools.partial(lax.ragged_dot,
+                             preferred_element_type=jnp.float32)
+
+
+def expert_counts(experts: jnp.ndarray, live: jnp.ndarray,
+                  num_experts: int) -> jnp.ndarray:
+    """Tokens routed to each expert, over the rows ``live`` marks:
+    int32 [num_experts]."""
+    ids = jnp.where(live[:, None], experts, num_experts).reshape(-1)
+    return jnp.bincount(ids, length=num_experts + 1)[
+        :num_experts].astype(jnp.int32)

@@ -58,6 +58,12 @@ class TransformerConfig:
     def mlp_hidden(self) -> int:
         return self.hidden * self.mlp_ratio
 
+    def served_model(self, page_size: int, horizon: int):
+        """This block as the decode engine takes it (models/served.py)."""
+        from .generation import served_model
+
+        return served_model(self, page_size, horizon)
+
 
 def bert_base(**kw) -> TransformerConfig:
     """BERT-base geometry (12L/768H/12 heads)."""

@@ -75,10 +75,29 @@ def blockwise_attention(
     v: jnp.ndarray,
     causal: bool = False,
     block_size: int = 512,
+    window: Optional[int] = None,
+    k_scale: Optional[jnp.ndarray] = None,
+    v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Memory-efficient attention: lax.scan over k/v chunks with an online
     softmax. Exact (not an approximation); peak memory O(sq · block_size)
-    per head instead of O(sq · sk)."""
+    per head instead of O(sq · sk).
+
+    A causal call may also give a ``window`` (position ``i`` attends
+    ``i - (window - 1) .. i``: only the blocks of that band are
+    computed), fewer KV heads than query heads (a whole multiple: query
+    head ``j`` reads KV head ``j // group``) and per-position scales
+    ``[batch, kv_heads, seq]`` of quantized ``k`` / ``v`` (the key's
+    scale multiplies the scores, the value's the softmax weights, so no
+    dequantized copy is made): :func:`_band_attention`."""
+    if window is not None or k_scale is not None or v_scale is not None \
+            or q.shape[1] != k.shape[1]:
+        if not causal or q.shape[2] != k.shape[2]:
+            raise ValueError(
+                "a window, grouped heads or KV scales need causal=True "
+                "and queries and keys over the same positions"
+            )
+        return _band_attention(q, k, v, block_size, window, k_scale, v_scale)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_size = min(block_size, sk)
@@ -116,6 +135,78 @@ def blockwise_attention(
         step, (o0, m0, l0), (jnp.arange(num_blocks), kb, vb)
     )
     return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+def _band_attention(q, k, v, block_size, window, k_scale, v_scale):
+    """Causal self-attention a query block at a time, each against the
+    KV blocks its band reaches: block ``i`` folds blocks ``first(i) ..
+    i``, ``first`` 0 without a window, so a windowed layer's cost grows
+    with ``seq · window`` and no ``seq × seq`` array exists. Online
+    softmax in float32; scores and weights of one block pair at a time
+    (``[batch, kv_heads, group, block, block]``)."""
+    b, hq, s, d = q.shape
+    hk = k.shape[1]
+    if hq % hk:
+        raise ValueError(f"{hq} query heads over {hk} KV heads")
+    g = hq // hk
+    blk = min(int(block_size), s)
+    nb = -(-s // blk)
+    pad = nb * blk - s
+    f32 = jnp.float32
+    ones = jnp.ones((b, hk, s), f32)
+    ks = ones if k_scale is None else k_scale.astype(f32)
+    vs = ones if v_scale is None else v_scale.astype(f32)
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad)))
+        vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad)))
+    scale = float(1.0 / np.sqrt(d))
+    qg = q.reshape(b, hk, g, nb, blk, d)
+    kb = k.astype(q.dtype).reshape(b, hk, nb, blk, d)
+    vb = v.astype(q.dtype).reshape(b, hk, nb, blk, d)
+    ksb = ks.reshape(b, hk, nb, blk)
+    vsb = vs.reshape(b, hk, nb, blk)
+    at = jnp.arange(blk)
+
+    def q_block(i):
+        qi = lax.dynamic_index_in_dim(qg, i, axis=3, keepdims=False)
+        qpos = i * blk + at
+
+        def fold(j, carry):
+            o, m, l = carry
+            kj = lax.dynamic_index_in_dim(kb, j, axis=2, keepdims=False)
+            vj = lax.dynamic_index_in_dim(vb, j, axis=2, keepdims=False)
+            ksj = lax.dynamic_index_in_dim(ksb, j, axis=2, keepdims=False)
+            vsj = lax.dynamic_index_in_dim(vsb, j, axis=2, keepdims=False)
+            kpos = j * blk + at
+            sc = jnp.einsum("bkgqd,bksd->bkgqs", qi, kj,
+                            preferred_element_type=f32) * scale
+            sc = sc * ksj[:, :, None, None, :]
+            mask = kpos[None, :] <= qpos[:, None]
+            if window is not None:
+                mask = mask & (kpos[None, :] > qpos[:, None] - window)
+            sc = jnp.where(mask, sc, NEG_INF)
+            m_new = jnp.maximum(m, sc.max(axis=-1))
+            p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            w = (p * vsj[:, :, None, None, :]).astype(q.dtype)
+            o = o * corr[..., None] + jnp.einsum(
+                "bkgqs,bksd->bkgqd", w, vj, preferred_element_type=f32)
+            return o, m_new, l * corr + p.sum(axis=-1)
+
+        first = 0 if window is None else jnp.maximum(
+            i * blk - (window - 1), 0) // blk
+        o, _, l = lax.fori_loop(first, i + 1, fold, (
+            jnp.zeros((b, hk, g, blk, d), f32),
+            jnp.full((b, hk, g, blk), NEG_INF, f32),
+            jnp.zeros((b, hk, g, blk), f32)))
+        return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+    out = lax.map(q_block, jnp.arange(nb))      # [nb, b, hk, g, blk, d]
+    out = out.transpose(1, 2, 3, 0, 4, 5).reshape(b, hq, nb * blk, d)
+    return out[:, :, :s]
 
 
 #: q/kv block edge of the upstream pallas flash kernel's default
@@ -340,13 +431,19 @@ def paged_decode_attention(
     layer: int,
     tables: jnp.ndarray,
     pos: jnp.ndarray,
+    window: Optional[int] = None,
+    ring: bool = False,
 ) -> jnp.ndarray:
     """One layer's paged int8-KV decode attention for every slot:
     ``q`` [slots, heads, head_dim] against the
     ``models/generation.init_paged_kv`` pool columns (k/v ``[pages,
     layers, page, heads*head_dim]`` int8, scales ``[pages, layers, page,
     128]`` float32), read where they lie through ``tables`` and masked
-    to ``j <= pos``. Traceable; the decode step embeds it.
+    to ``j <= pos``. Traceable; the decode step embeds it. The pool may
+    hold fewer KV heads than ``q`` has query heads (grouped heads), a
+    ``window`` bounds the positions a slot attends, and ``ring`` says the
+    table is a ring (``kernels/decode_attention.paged_decode_attention``
+    has the three).
 
     This is where the lowering is chosen, from the backend alone:
     where ``kernels.selectable("decode_attn")`` holds (a TPU, or the
@@ -363,7 +460,8 @@ def paged_decode_attention(
         _kda.paged_decode_attention if _kernels.selectable("decode_attn")
         else _kda.paged_attention_reference
     )
-    return attend(q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos)
+    return attend(q, k_pages, v_pages, k_scale, v_scale, layer, tables, pos,
+                  window=window, ring=ring)
 
 
 def dense_attention(

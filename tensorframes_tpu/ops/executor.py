@@ -36,6 +36,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import weakref
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -866,6 +867,11 @@ def _shardings_tree_token(tree) -> object:
     }
 
 
+#: key components an ``_AotJit`` keeps by identity before it drops the
+#: dead ones (a donated buffer leaves one behind each dispatch)
+_LEAF_TOKENS_MAX = 4096
+
+
 class _AotJit:
     """``jax.jit``-shaped callable whose dispatch rides the executor's
     unified AOT pipeline: per-argument-shape/placement keys, explicit
@@ -902,6 +908,35 @@ class _AotJit:
             self._decl["donate_argnums"] = list(donate_argnums)
         self._builds = _KeyedBuildCache()
         self._dispatched: set = set()
+        # id(leaf) -> (weakref to the leaf, default device, its key
+        # component): see _leaf_token
+        self._leaf_tokens: Dict[int, Tuple] = {}
+
+    def _leaf_token(self, v, default_dev) -> Tuple:
+        """One leaf's component of the dispatch key. A device array is
+        immutable (shape, dtype, weak type and placement are fixed for
+        its life), and a served model hands the same hundred weight
+        arrays to every step: their components are kept by identity
+        (a weak reference, so nothing is held alive and a recycled
+        ``id`` never matches) instead of being rebuilt from
+        ``str(dtype)`` and the sharding's descriptor on each dispatch."""
+        kept = isinstance(v, jax.Array) \
+            and not isinstance(v, jax.core.Tracer)
+        if kept:
+            hit = self._leaf_tokens.get(id(v))
+            if hit is not None and hit[0]() is v and hit[1] is default_dev:
+                return hit[2]
+        token = (tuple(int(d) for d in v.shape), str(v.dtype),
+                 bool(getattr(v, "weak_type", False)), _placement_token(v))
+        if kept:
+            if len(self._leaf_tokens) >= _LEAF_TOKENS_MAX:
+                # donated buffers come and go each dispatch: drop the dead
+                live = {k: h for k, h in self._leaf_tokens.items()
+                        if h[0]() is not None}
+                self._leaf_tokens = live \
+                    if len(live) < _LEAF_TOKENS_MAX // 2 else {}
+            self._leaf_tokens[id(v)] = (weakref.ref(v), default_dev, token)
+        return token
 
     def _key(self, leaves, treedef) -> Optional[Tuple]:
         if any(
@@ -917,10 +952,11 @@ class _AotJit:
         # The treedef enters as the OBJECT (hashable, eq-comparable) —
         # stringifying a transformer's param tree repr per step is
         # dispatch overhead the jax.jit C++ fast path never paid.
+        from ..parallel.mesh import default_device
+
+        dev = default_device()
         return (treedef, self._donate) + tuple(
-            (tuple(int(d) for d in v.shape), str(v.dtype),
-             bool(getattr(v, "weak_type", False)), _placement_token(v))
-            for v in leaves
+            self._leaf_token(v, dev) for v in leaves
         )
 
     def _build(self, key: Tuple, args) -> Optional[Callable]:

@@ -186,7 +186,7 @@ class DecodeEngine:
                  config: Optional[DecodeConfig] = None):
         from ..compilecache import decode_warmup_grid
         from ..kernels.decode_attention import pages_walked
-        from ..models import generation as gen
+        from ..models.served import served_model_of
         from ..ops.executor import aot_jit
 
         self._pages_walked = pages_walked
@@ -201,20 +201,43 @@ class DecodeEngine:
                 "max_prompt_len and max_new_tokens must be >= 1"
             )
         horizon = cfg.max_prompt_len + cfg.max_new_tokens
-        if horizon > model_cfg.max_seq_len:
+        # the seam (models/served.py): the model's page kinds and its
+        # programs; the engine asks nothing else of a model
+        self.model = model = served_model_of(
+            model_cfg, cfg.page_size, horizon
+        )
+        if horizon > model.max_seq_len:
             raise ValueError(
                 f"decode horizon {horizon} (max_prompt_len + "
                 f"max_new_tokens) exceeds the model's max_seq_len="
-                f"{model_cfg.max_seq_len}"
+                f"{model.max_seq_len}"
             )
-        max_pages = -(-horizon // cfg.page_size)
+        for flag, have, what in (
+                (cfg.prefix_cache, model.suffix_prefill and model.page_ops,
+                 "prefix_cache=True needs the model's suffix-prefill and "
+                 "page-operation programs"),
+                (cfg.kv_swap, model.page_ops,
+                 "kv_swap=True needs the model's page-operation programs")):
+            if flag and not have:
+                raise ValueError(
+                    f"decode endpoint {name!r}: {what}, and "
+                    f"{type(model_cfg).__name__} gives none (its page "
+                    f"kinds: {[k.name for k in model.kinds]}); serve it "
+                    "without that tier"
+                )
+        self._kinds = model.kinds
+        max_pages = self._kinds[0].entries
         num_pages = cfg.num_pages
         if num_pages is None:
             # auto-size: every slot can hold a full horizon — the
             # no-preemption configuration
             num_pages = 1 + cfg.max_slots * max_pages
+        # a kind past the first (a ring) never fills: every slot's whole
+        # ring fits
         self._pool = PagedKVPool(
-            model_cfg, num_pages, cfg.page_size, max_pages
+            model, num_pages, cfg.page_size,
+            extra_pages={k.name: 1 + cfg.max_slots * k.entries
+                         for k in self._kinds[1:]},
         )
         grid = decode_warmup_grid(cfg.max_slots, cfg.max_prompt_len)
         self._slot_buckets = grid["decode"]
@@ -227,19 +250,22 @@ class DecodeEngine:
         # can read them; only the engine thread (or start()'s warm-up,
         # before that thread exists) ever holds the columns.
         self._prefill = aot_jit(
-            gen.paged_prefill_fn(model_cfg, cfg.page_size, max_pages),
+            model.prefill,
             label=f"decode.prefill[{name}]", donate_argnums=(1,),
         )
         self._step = aot_jit(
-            gen.paged_decode_step_fn(model_cfg, cfg.page_size, max_pages),
+            model.step,
             label=f"decode.step[{name}]", donate_argnums=(1,),
         )
         # which attention the step traces is the backend's fact
         # (ops.attention asks the same table at trace time); kept for
         # the dispatch counter and the pages-walked accounting
         self._attn_is_kernel = _kernels.selectable("decode_attn")
-        self._attn_interpreted = (
-            self._attn_is_kernel and _kernels.interpret_mode()
+        self._step_kernels = tuple(
+            k for k in model.kernels if _kernels.selectable(k)
+        )
+        self._kernels_interpreted = (
+            bool(self._step_kernels) and _kernels.interpret_mode()
         )
         # widest slot bucket whose step executable's memory plan the
         # tftpu_decode_step_*_bytes gauges show (0: none yet)
@@ -253,14 +279,12 @@ class DecodeEngine:
         self._extract = self._restore = self._copy_page = None
         if self._prefix_cache:
             self._suffix_prefill = aot_jit(
-                gen.paged_suffix_prefill_fn(
-                    model_cfg, cfg.page_size, max_pages
-                ),
+                model.suffix_prefill,
                 label=f"decode.suffix_prefill[{name}]",
                 donate_argnums=(1,),
             )
         if self._prefix_cache or self._kv_swap:
-            ex_fn, rs_fn, cp_fn = gen.paged_page_ops_fns(max_pages)
+            ex_fn, rs_fn, cp_fn = model.page_ops
             if self._kv_swap:
                 self._extract = aot_jit(
                     ex_fn, label=f"decode.kvswap.extract[{name}]"
@@ -337,7 +361,9 @@ class DecodeEngine:
 
     def _run_step(self, *args):
         """Dispatch one batched decode step and count its kernel
-        dispatch. Returns ``(pool, next_tokens)``; the pool columns
+        dispatch. Returns what the model's step returns, ``(pool,
+        next_tokens)`` or ``(pool, next_tokens, stats)``
+        (``models/served.py``); the pool columns
         passed in (``args[1]``) are donated — deleted by the call — so
         the caller rebinds ``self._pool.columns`` to the returned ones.
         A failure raises — the step is never rebuilt on another
@@ -355,8 +381,8 @@ class DecodeEngine:
                 "decode.step.enqueue", t_step, dt,
                 args={"endpoint": self.name}, cat="serving",
             )
-        if self._attn_is_kernel:
-            _kernels.note_dispatch("decode_attn", self._attn_interpreted)
+        for kernel in self._step_kernels:
+            _kernels.note_dispatch(kernel, self._kernels_interpreted)
         return out
 
     def _note_step_memory(self, args) -> None:
@@ -476,13 +502,14 @@ class DecodeEngine:
         for tb in self._prefill_buckets:
             pool.columns, _ = self._prefill(
                 self.params, pool.columns, np.zeros(tb, np.int32),
-                np.int32(1), null,
+                np.int32(1), *pool.null_tables(),
             )
         for sb in self._slot_buckets:
-            pool.columns, _ = self._run_step(
+            pool.columns = self._run_step(
                 self.params, pool.columns, np.zeros(sb, np.int32),
-                np.zeros(sb, np.int32), np.zeros((sb, maxp), np.int32),
-            )
+                np.zeros(sb, np.int32),
+                *(np.zeros((sb, k.entries), np.int32) for k in self._kinds),
+            )[0]
         if self._suffix_prefill is not None:
             for tb in self._prefill_buckets:
                 pool.columns, _ = self._suffix_prefill(
@@ -696,7 +723,7 @@ class DecodeEngine:
                 f"non-empty 1-D token vector (or [1, plen]), got shape "
                 f"{prompt.shape}"
             )
-        vocab = int(self.cfg.vocab_size)
+        vocab = int(self.model.vocab_size)
         if prompt.min() < 0 or prompt.max() >= vocab:
             raise ValidationError(
                 f"decode endpoint {self.name!r}: prompt tokens must be "
@@ -817,25 +844,26 @@ class DecodeEngine:
         is read at the first head request, under the admission lock: a
         request offered after pages left the pool never sees the pool
         as it was before."""
-        budget: List[int] = []
+        budget: Dict[str, int] = {}
+        first = self._kinds[0].name
 
         def can_take(req: _Request) -> bool:
             if not budget:
-                budget.append(self._pool.num_allocatable)
+                budget.update({k.name: self._pool.allocatable(k.name)
+                               for k in self._kinds})
             snap = self._swap.get(req)
             if snap is None:
                 # a redriven request adopting a restored swap segment
                 # claims its SNAPSHOT pages too (engine-restart resume)
                 snap = self._adopt_restored(req)
+            # a sequence's demand is reckoned per page kind
+            need = self._pool.demand(int(req.feeds["prompt"].shape[0]))
             if snap is not None:
-                need = int(snap["pages"])
-            else:
-                need = self._pool.pages_needed(
-                    int(req.feeds["prompt"].shape[0])
-                )
-            if need > budget[0]:
+                need[first] = int(snap["pages"])
+            if any(n > budget[kind] for kind, n in need.items()):
                 return False
-            budget[0] -= need
+            for kind, n in need.items():
+                budget[kind] -= n
             return True
 
         return can_take
@@ -945,7 +973,7 @@ class DecodeEngine:
             tables[0] = self._pool.table(seq)
             cols, nxt = self._run_step(
                 self.params, self._pool.columns, tokens, pos, tables
-            )
+            )[:2]
             self._pool.columns = cols
             first = int(np.asarray(nxt)[0])
         elif hit_pages:
@@ -969,7 +997,8 @@ class DecodeEngine:
             self._pool.columns = cols
             first = int(fd)
         else:
-            self._pool.alloc(seq, self._pool.pages_needed(plen))
+            for kind, n in self._pool.demand(plen).items():
+                self._pool.alloc(seq, n, kind)
             tb = self._prefill_bucket(plen)
             padded = np.zeros(tb, np.int32)
             padded[:plen] = prompt
@@ -977,7 +1006,7 @@ class DecodeEngine:
             t_disp = time.perf_counter() if tracing else 0.0
             cols, fd = self._prefill(
                 self.params, self._pool.columns, padded,
-                np.int32(plen), self._pool.table(seq),
+                np.int32(plen), *self._pool.tables(seq),
             )
             self._pool.columns = cols
             first = int(fd)
@@ -1160,27 +1189,30 @@ class DecodeEngine:
         # structural, preemption cannot livelock.
         allocated = preempted = 0
         for s in sorted(self._active(), key=lambda x: x.joined):
-            if s not in self._slots:
-                continue  # preempted by an earlier fault in this pass
-            need = s.pos // self._pool.page_size
-            if need < len(self._pool.seq_pages(s.seq)):
-                continue
-            preempted_self = False
-            while self._pool.num_allocatable < 1:
-                victim = max(self._active(), key=lambda x: x.joined)
-                self._preempt(victim)
-                preempted += 1
-                if victim is s:
-                    preempted_self = True
+            for kind in self._kinds:
+                if preempted and s not in self._slots:
+                    break  # preempted by an earlier fault in this pass
+                # the pages of this kind a context of pos + 1 holds (a
+                # ring kind: never more than its entries)
+                if kind.pages_for(s.pos + 1, self._pool.page_size) \
+                        <= self._pool.held(s.seq, kind.name):
+                    continue
+                preempted_self = False
+                while self._pool.allocatable(kind.name) < 1:
+                    victim = max(self._active(), key=lambda x: x.joined)
+                    self._preempt(victim)
+                    preempted += 1
+                    if victim is s:
+                        preempted_self = True
+                        break
+                if preempted_self:
                     break
-            if preempted_self:
-                continue
-            try:
-                self._pool.alloc(s.seq, 1)
-                allocated += 1
-            except PoolExhaustedError:  # pragma: no cover - guarded above
-                self._preempt(s)
-                preempted += 1
+                try:
+                    self._pool.alloc(s.seq, 1, kind.name)
+                    allocated += 1
+                except PoolExhaustedError:  # pragma: no cover - guarded
+                    self._preempt(s)
+                    preempted += 1
         active = self._active()
         if not active:
             if _events.TRACER.enabled:
@@ -1193,33 +1225,27 @@ class DecodeEngine:
         maxp = self._pool.max_pages_per_seq
         tokens = np.zeros(sb, np.int32)
         pos = np.zeros(sb, np.int32)
-        tables = np.zeros((sb, maxp), np.int32)
+        tables = [np.zeros((sb, k.entries), np.int32) for k in self._kinds]
         for row, s in enumerate(active):
             tokens[row] = s.generated[-1]
             pos[row] = s.pos
-            tables[row] = self._pool.table(s.seq)
+            for table, kind in zip(tables, self._kinds):
+                table[row] = self._pool.table(s.seq, kind.name)
         if _events.TRACER.enabled:
             # page faults, preemption and the host-side build above
             self._phase("decode.prepare", slots=n,
                         pages_allocated=allocated, preempted=preempted)
-        cols, nxt = self._run_step(
-            self.params, self._pool.columns, tokens, pos, tables
+        out = self._run_step(
+            self.params, self._pool.columns, tokens, pos, *tables
         )
-        self._pool.columns = cols
-        nxt = np.asarray(nxt)
+        self._pool.columns = out[0]
+        nxt = np.asarray(out[1])
         m.DECODE_STEPS["decode"].inc()
-        # the kernel folds the chunks each row's context reaches; the
-        # XLA chain gathers and attends the whole table
-        walked = grid = sb * maxp
-        if self._attn_is_kernel:
-            walked = int(self._pages_walked(
-                pos, self._pool.page_size, maxp
-            ).sum())
-        m.DECODE_ATTN_PAGES_WALKED.inc(walked)
-        m.DECODE_ATTN_PAGES_GRID.inc(grid)
+        span = self._count_walk(pos, sb)
+        if len(out) > 2:
+            span.update(self._count_experts(out[2]))
         if _events.TRACER.enabled:
-            args = {"slots": n, "bucket": sb, "pages_walked": walked,
-                    "pages_grid": grid}
+            args = {"slots": n, "bucket": sb, **span}
             rids = [s.req.trace_id for s in active if s.req.trace_id]
             if rids:
                 args["request_ids"] = rids[:16]
@@ -1245,6 +1271,43 @@ class DecodeEngine:
         if _events.TRACER.enabled:
             # token bookkeeping, replay checks and the finishes above
             self._phase("decode.commit", finished=finished)
+
+    def _count_walk(self, pos: np.ndarray, sb: int) -> Dict[str, int]:
+        """Count the page-table entries one step's attention walked, and
+        return the ``decode.step`` span's share of them. The kernel
+        folds the chunks each row's context (or window) reaches; the XLA
+        chain gathers and attends the whole table. A model with one page
+        kind counts on the unlabelled series, as it always has; one with
+        several counts each kind under ``kind=``, and beside it the
+        entries the contexts reach, window or not."""
+        page = self._pool.page_size
+        span: Dict[str, int] = {}
+        reach = int((pos // page + 1).sum())
+        for kind in self._kinds:
+            walked = grid = sb * kind.entries
+            if self._attn_is_kernel:
+                walked = int(self._pages_walked(
+                    pos, page, kind.entries, kind.window
+                ).sum())
+            if len(self._kinds) == 1:
+                m.DECODE_ATTN_PAGES_WALKED.inc(walked)
+                m.DECODE_ATTN_PAGES_GRID.inc(grid)
+                return {"pages_walked": walked, "pages_grid": grid}
+            m.DECODE_ATTN_PAGES_WALKED_BY_KIND[kind.name].inc(walked)
+            m.DECODE_ATTN_PAGES_GRID_BY_KIND[kind.name].inc(grid)
+            m.DECODE_ATTN_PAGES_CONTEXT[kind.name].inc(reach)
+            span[f"{kind.name}_pages_walked"] = walked
+        return span
+
+    @staticmethod
+    def _count_experts(stats) -> Dict[str, int]:
+        """Count what a step's expert layers routed (``stats`` as the
+        model's step returns them, ``models/served.py``)."""
+        counts = np.asarray(stats["expert_counts"])
+        load_max = int(counts.max(axis=1).sum())
+        m.MOE_TOKENS_ROUTED.inc(int(counts.sum()))
+        m.MOE_EXPERT_LOAD_MAX.inc(load_max)
+        return {"experts_max_load": load_max}
 
     def _slot_of(self, s: _Seq) -> int:
         return self._slots.index(s)

@@ -59,6 +59,33 @@ class PoolExhaustedError(RuntimeError):
     unbounded wait."""
 
 
+class _KindPages:
+    """Free list and exclusive ownership of one page kind
+    (``models/served.PageKind``), and the gauge its free pages show on.
+    A sequence's pages of a kind are its own, in the order it got them;
+    sharing and swap are the pool's, over its first kind alone."""
+
+    def __init__(self, kind, num_pages: int, gauge):
+        if num_pages < 1 + kind.entries:
+            # the null page plus one sequence's whole table is the floor:
+            # below it the OLDEST running sequence could page-fault with
+            # nothing left to evict — the livelock the forward-progress
+            # guarantee exists to rule out
+            raise ValueError(
+                f"num_pages={num_pages} cannot hold the null page plus "
+                f"one full sequence ({kind.entries} {kind.name} pages) — "
+                "an undersized pool could stall its own oldest sequence; "
+                "raise num_pages or lower the decode horizon"
+            )
+        self.kind = kind
+        self.num_pages = int(num_pages)
+        self.gauge = gauge
+        self.free: collections.deque = collections.deque(
+            range(1, self.num_pages)
+        )
+        self.owned: Dict[int, List[int]] = {}
+
+
 def _chain_key(prev: bytes, tokens: np.ndarray) -> bytes:
     """One link of the page-granular content address: the hash of a
     page's tokens chained onto the hash of everything before it, so a
@@ -80,36 +107,31 @@ class PagedKVPool:
     engine's scheduling thread (single-threaded by design — the pool is
     not itself locked)."""
 
-    def __init__(self, cfg, num_pages: int, page_size: int,
-                 max_pages_per_seq: int):
-        from ..models.generation import init_paged_kv
+    def __init__(self, model, num_pages: int, page_size: int,
+                 extra_pages: Optional[Dict[str, int]] = None):
+        """``model`` is a ``models.served.ServedModel`` (a configuration
+        gives one: ``cfg.served_model(page_size, horizon)``). Its first
+        page kind is the one ``num_pages`` sizes, prefixes are shared in
+        and sequences are swapped from; ``extra_pages`` gives the page
+        count of each further kind by name."""
+        from . import metrics as m
 
-        if max_pages_per_seq < 1:
-            raise ValueError(
-                f"max_pages_per_seq must be >= 1, got {max_pages_per_seq}"
-            )
-        if num_pages < 1 + max_pages_per_seq:
-            # the null page plus one full sequence horizon is the floor:
-            # below it the OLDEST running sequence could page-fault with
-            # nothing left to evict — the livelock the forward-progress
-            # guarantee exists to rule out
-            raise ValueError(
-                f"num_pages={num_pages} cannot hold the null page plus "
-                f"one full sequence ({max_pages_per_seq} pages) — an "
-                "undersized pool could stall its own oldest sequence; "
-                "raise num_pages or lower the decode horizon"
-            )
-        self.cfg = cfg
+        self.model = model
+        self.kinds = tuple(model.kinds)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.max_pages_per_seq = int(max_pages_per_seq)
-        self.columns: Dict[str, object] = init_paged_kv(
-            cfg, self.num_pages, self.page_size
-        )
-        self._free: collections.deque = collections.deque(
-            range(1, self.num_pages)
-        )
-        self._owned: Dict[int, List[int]] = {}
+        self.max_pages_per_seq = int(self.kinds[0].entries)
+        counts = {self.kinds[0].name: self.num_pages, **(extra_pages or {})}
+        # every kind its own free list and ownership, in kind order
+        self._pages: Dict[str, _KindPages] = {
+            k.name: _KindPages(k, counts[k.name], gauge)
+            for k, gauge in zip(self.kinds, m.page_kind_gauges(
+                [k.name for k in self.kinds]))
+        }
+        self._kv = self._pages[self.kinds[0].name]
+        # (sequence, kind) -> (pages held, the table built from them)
+        self._tables: Dict[Tuple[int, str], Tuple[int, np.ndarray]] = {}
+        self.columns: Dict[str, object] = model.init_pool(counts)
         # -- prefix-cache state (shared read-only pages, ISSUE 19) ----------
         # a sequence's table is refs (shared prefix chain) + owned
         # (exclusive pages), in position order
@@ -122,13 +144,50 @@ class PagedKVPool:
         # page -> (parent chain key, own chain key, page tokens)
         self._prefix_meta: Dict[int, Tuple[bytes, bytes, bytes]] = {}
         self._prefix_children: Dict[bytes, List[int]] = {}
-        self._closed = False
-        # the free-pages gauge aggregates by DELTA across live pools
+        # the free-pages gauges aggregate by DELTA across live pools
         # (several decode endpoints share one process-wide series; a
         # set() here would clobber the siblings)
-        from . import metrics as m
+        self._closed = True
+        self.reopen()
 
-        m.DECODE_FREE_PAGES.inc(len(self._free))
+    # the first kind's lists, under the names the sharing and swap code
+    # below has always used
+    @property
+    def _free(self) -> collections.deque:
+        return self._kv.free
+
+    @property
+    def _owned(self) -> Dict[int, List[int]]:
+        return self._kv.owned
+
+    # -- page kinds ---------------------------------------------------------
+
+    def demand(self, n_positions: int) -> Dict[str, int]:
+        """Pages of each kind a sequence of ``n_positions`` KV slots
+        holds (a ring kind never more than its entries)."""
+        return {k.name: k.pages_for(n_positions, self.page_size)
+                for k in self.kinds}
+
+    def allocatable(self, kind: str) -> int:
+        """Pages of ``kind`` an alloc can satisfy right now."""
+        if kind == self._kv.kind.name:
+            return self.num_allocatable
+        return len(self._pages[kind].free)
+
+    def held(self, seq: int, kind: str) -> int:
+        """Pages of ``kind`` sequence ``seq`` holds."""
+        seq = int(seq)
+        if kind == self._kv.kind.name:
+            return len(self._refs.get(seq, ())) \
+                + len(self._owned.get(seq, ()))
+        return len(self._pages[kind].owned.get(seq, ()))
+
+    def tables(self, seq: int) -> List[np.ndarray]:
+        """The sequence's page table of every kind, in kind order."""
+        return [self.table(seq, k.name) for k in self.kinds]
+
+    def null_tables(self) -> List[np.ndarray]:
+        return [np.zeros(k.entries, np.int32) for k in self.kinds]
 
     # -- capacity -----------------------------------------------------------
 
@@ -160,62 +219,75 @@ class PagedKVPool:
 
     # -- alloc / free -------------------------------------------------------
 
-    def alloc(self, seq: int, n: int) -> List[int]:
-        """Give ``n`` exclusive pages to sequence ``seq`` (appended to
-        its table after any shared prefix). Reclaims refcount-0 shared
-        pages LRU-first when the free list alone cannot cover ``n``;
-        raises :class:`PoolExhaustedError` when even that cannot
+    def alloc(self, seq: int, n: int,
+              kind: Optional[str] = None) -> List[int]:
+        """Give ``n`` exclusive pages of ``kind`` (default: the first)
+        to sequence ``seq``, appended to its table (in the first kind
+        after any shared prefix). Reclaims refcount-0 shared pages
+        LRU-first when the first kind's free list alone cannot cover
+        ``n``; raises :class:`PoolExhaustedError` when even that cannot
         (nothing is partially allocated)."""
-        n = int(n)
+        n, seq = int(n), int(seq)
         if n < 0:
             raise ValueError(f"alloc of {n} pages")
-        held = self._owned.setdefault(int(seq), [])
-        total = len(held) + len(self._refs.get(int(seq), ())) + n
-        if total > self.max_pages_per_seq:
+        pages = self._pages[kind] if kind is not None else self._kv
+        name = "" if pages is self._kv else pages.kind.name + " "
+        held = pages.owned.setdefault(seq, [])
+        total = len(held) + n
+        if pages is self._kv:
+            total += len(self._refs.get(seq, ()))
+        if total > pages.kind.entries:
             raise PoolAccountingError(
-                f"sequence {seq} would hold {total} pages, "
-                f"over max_pages_per_seq={self.max_pages_per_seq}"
+                f"sequence {seq} would hold {total} {name}pages, over "
+                f"the {pages.kind.entries} table entries a sequence has "
+                "(max_pages_per_seq)"
             )
-        if n > len(self._free):
-            self._reclaim_shared(n - len(self._free))
-        if n > len(self._free):
+        if pages is self._kv and n > len(pages.free):
+            self._reclaim_shared(n - len(pages.free))
+        if n > len(pages.free):
             raise PoolExhaustedError(
-                f"need {n} pages, {len(self._free)} free + "
-                f"{len(self._shared_lru)} reclaimable "
-                f"(of {self.usable_pages} usable)"
+                f"need {n} {name}pages, {len(pages.free)} free"
+                + (f" + {len(self._shared_lru)} reclaimable"
+                   if pages is self._kv else "")
+                + f" (of {pages.num_pages - 1} usable)"
             )
-        got = [self._free.popleft() for _ in range(n)]
+        got = [pages.free.popleft() for _ in range(n)]
         held.extend(got)
         if not self._closed:
-            from . import metrics as m
-
-            m.DECODE_FREE_PAGES.dec(n)
+            pages.gauge.dec(n)
         return got
 
     def free_seq(self, seq: int) -> int:
-        """Return every exclusive page owned by ``seq`` to the free list
-        and drop its references on shared prefix pages (a shared page at
-        refcount 0 stays cached until reclaimed). Returns the exclusive
-        count freed (0 for a sequence holding nothing). Double frees and
-        corrupted ownership raise :class:`PoolAccountingError`."""
-        self._release_refs(int(seq))
-        pages = self._owned.pop(int(seq), None)
-        if pages is None:
-            return 0
-        free_set = set(self._free)
-        for p in pages:
-            if p in free_set or p == 0 or p in self._shared_ref:
-                self._owned[int(seq)] = pages  # restore for postmortem
-                raise PoolAccountingError(
-                    f"double free: page {p} of sequence {seq} is "
-                    "already free, shared, or the null page"
-                )
-        self._free.extend(pages)
-        if not self._closed:
-            from . import metrics as m
-
-            m.DECODE_FREE_PAGES.inc(len(pages))
-        return len(pages)
+        """Return every exclusive page owned by ``seq``, of every kind,
+        to its free list and drop its references on shared prefix pages
+        (a shared page at refcount 0 stays cached until reclaimed).
+        Returns the count of first-kind pages freed (0 for a sequence
+        holding none). Double frees and corrupted ownership raise
+        :class:`PoolAccountingError`."""
+        seq = int(seq)
+        self._release_refs(seq)
+        freed = 0
+        for pages in self._pages.values():
+            self._tables.pop((seq, pages.kind.name), None)
+            got = pages.owned.pop(seq, None)
+            if got is None:
+                continue
+            free_set = set(pages.free)
+            for p in got:
+                if p in free_set or p == 0 or (
+                        pages is self._kv and p in self._shared_ref):
+                    pages.owned[seq] = got  # restore for postmortem
+                    raise PoolAccountingError(
+                        f"double free: {pages.kind.name} page {p} of "
+                        f"sequence {seq} is already free, shared, or the "
+                        "null page"
+                    )
+            pages.free.extend(got)
+            if not self._closed:
+                pages.gauge.inc(len(got))
+            if pages is self._kv:
+                freed = len(got)
+        return freed
 
     def owned(self, seq: int) -> List[int]:
         return list(self._owned.get(int(seq), ()))
@@ -226,13 +298,35 @@ class PagedKVPool:
         return (list(self._refs.get(int(seq), ()))
                 + list(self._owned.get(int(seq), ())))
 
-    def table(self, seq: int) -> np.ndarray:
-        """The sequence's page table as the step functions expect it:
-        int32 ``[max_pages_per_seq]``, unused tail entries = null page 0."""
-        t = np.zeros(self.max_pages_per_seq, np.int32)
-        pages = self.seq_pages(seq)
+    def table(self, seq: int, kind: Optional[str] = None) -> np.ndarray:
+        """The sequence's page table of ``kind`` (default: the first) as
+        the step functions expect it: int32 ``[the kind's entries]``,
+        unused tail entries = null page 0; the ``i``-th page a sequence
+        got at entry ``i`` (a ring kind gets one as its context enters
+        each new page, until the ring is whole)."""
+        seq = int(seq)
+        if kind is None:
+            kind = self._kv.kind.name
+        # a sequence's table changes only by growing (alloc appends; a
+        # published page keeps its place) until free_seq drops it, and
+        # the engine asks for every running sequence's table every
+        # step: the last one built is kept beside the page count it was
+        # built from, and handed out again (as a copy) while that holds
+        n = self.held(seq, kind)
+        kept = self._tables.get((seq, kind))
+        if kept is not None and kept[0] == n:
+            return kept[1].copy()
+        if kind == self._kv.kind.name:
+            t = np.zeros(self.max_pages_per_seq, np.int32)
+            pages = self.seq_pages(seq)
+        else:
+            more = self._pages[kind]
+            t = np.zeros(more.kind.entries, np.int32)
+            pages = more.owned.get(seq, ())
         t[:len(pages)] = pages
-        return t
+        if n:
+            self._tables[(seq, kind)] = (n, t)
+        return t.copy()
 
     def null_table(self) -> np.ndarray:
         """An all-null page table — what padding slots carry."""
@@ -246,7 +340,8 @@ class PagedKVPool:
             self._closed = True
             from . import metrics as m
 
-            m.DECODE_FREE_PAGES.dec(len(self._free))
+            for pages in self._pages.values():
+                pages.gauge.dec(len(pages.free))
             m.PREFIX_SHARED_PAGES.dec(len(self._shared_ref))
 
     def reopen(self) -> None:
@@ -255,7 +350,8 @@ class PagedKVPool:
             self._closed = False
             from . import metrics as m
 
-            m.DECODE_FREE_PAGES.inc(len(self._free))
+            for pages in self._pages.values():
+                pages.gauge.inc(len(pages.free))
             m.PREFIX_SHARED_PAGES.inc(len(self._shared_ref))
 
     # -- content-addressed prefix cache (ISSUE 19) --------------------------
@@ -419,7 +515,7 @@ class PagedKVPool:
 
             m.PREFIX_EVICTIONS.inc(evicted)
             m.PREFIX_SHARED_PAGES.dec(evicted)
-            m.DECODE_FREE_PAGES.inc(evicted)
+            self._kv.gauge.inc(evicted)
         return evicted
 
     # -- invariants ---------------------------------------------------------
@@ -430,6 +526,22 @@ class PagedKVPool:
         refcounts exactly matching the per-sequence references, and the
         content index bijective with the shared set. Cheap; the property
         sweep calls it after every mutation."""
+        for more in self._pages.values():
+            if more is self._kv:
+                continue  # below, with the shared pages
+            name, got = more.kind.name, list(more.free)
+            for seq, pages in more.owned.items():
+                if len(pages) > more.kind.entries:
+                    raise PoolAccountingError(
+                        f"sequence {seq} holds {len(pages)} {name} pages "
+                        f"> the kind's {more.kind.entries} entries"
+                    )
+                got.extend(pages)
+            if sorted(got) != list(range(1, more.num_pages)):
+                raise PoolAccountingError(
+                    f"{name} pages are not partitioned into free and "
+                    "owned: a page is leaked, or in two places"
+                )
         free = list(self._free)
         free_set = set(free)
         if len(free) != len(free_set):
@@ -504,6 +616,11 @@ class PagedKVPool:
         axis). Every snapshot and swap segment carries it, and a
         snapshot whose shapes differ — one written by a build with
         another pool layout — is refused, never reinterpreted."""
+        if len(self._pages) > 1:
+            raise PoolAccountingError(
+                "snapshots and swap segments hold one page kind; this "
+                f"pool has {[k.name for k in self.kinds]}"
+            )
         return {k: [int(d) for d in v.shape[1:]]
                 for k, v in self.columns.items()}
 
@@ -613,8 +730,8 @@ class PagedKVPool:
         old_free = len(self._free)
         old_shared = len(self._shared_ref)
         self.columns = new_cols
-        self._free = collections.deque(int(p) for p in snapshot["free"])
-        self._owned = {
+        self._kv.free = collections.deque(int(p) for p in snapshot["free"])
+        self._kv.owned = {
             int(s): [int(p) for p in pages]
             for s, pages in dict(snapshot["owned"]).items()
         }
@@ -642,7 +759,7 @@ class PagedKVPool:
         if not self._closed:
             from . import metrics as m
 
-            m.DECODE_FREE_PAGES.inc(len(self._free) - old_free)
+            self._kv.gauge.inc(len(self._free) - old_free)
             m.PREFIX_SHARED_PAGES.inc(len(self._shared_ref) - old_shared)
         return self.adopt_swapped(store, snapshot, swap_store)
 

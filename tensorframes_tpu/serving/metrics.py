@@ -15,10 +15,10 @@ registry (endpoints ride flight records and trace args instead).
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 from ..observability.latency import LATENCY_BUCKETS
-from ..observability.metrics import Counter
+from ..observability.metrics import Counter, Gauge
 from ..observability.metrics import counter as _counter
 from ..observability.metrics import gauge as _gauge
 from ..observability.metrics import histogram as _histogram
@@ -31,6 +31,9 @@ __all__ = [
     "DECODE_PHASES", "DECODE_TOKENS", "DECODE_STEPS", "DECODE_TTFT",
     "DECODE_ATTN_PAGES_WALKED", "DECODE_ATTN_PAGES_GRID",
     "DECODE_SLOTS", "DECODE_FREE_PAGES", "DECODE_PREEMPTIONS",
+    "DECODE_FREE_KIND_PAGES", "DECODE_PAGE_KINDS", "page_kind_gauges",
+    "DECODE_ATTN_PAGES_WALKED_BY_KIND", "DECODE_ATTN_PAGES_GRID_BY_KIND",
+    "DECODE_ATTN_PAGES_CONTEXT", "MOE_TOKENS_ROUTED", "MOE_EXPERT_LOAD_MAX",
     "DECODE_EVICTIONS",
     "KVSWAP_OUTS", "KVSWAP_RESUMES", "KVSWAP_FALLBACKS", "KVSWAP_BYTES",
     "PREFIX_HITS", "PREFIX_MISSES", "PREFIX_SHARED_PAGES",
@@ -186,6 +189,89 @@ DECODE_FREE_PAGES = _gauge(
     "tftpu_decode_free_pages",
     "Free pages across decode KV pools (the headroom preemption "
     "defends)",
+)
+#: The page kinds a served model with several may declare (closed
+#: label set, registered here at import like every other: TFL003). A
+#: model whose kinds carry other names is refused at ``register_decode``
+#: (:func:`page_kind_gauges`); a new kind is a new name in this tuple.
+DECODE_PAGE_KINDS: Tuple[str, ...] = ("full", "window")
+#: free pages of a kind that is not its model's first (the first kind,
+#: the one that fills, reads ``tftpu_decode_free_pages``)
+DECODE_FREE_KIND_PAGES: Dict[str, Gauge] = {
+    "window": _gauge(
+        "tftpu_decode_free_window_pages",
+        "Free pages of the decode KV pools' window kind (the ring a "
+        "sequence's sliding layers write: at most ceil(window / page) "
+        "+ 1 a sequence, so it fills with the sequences running, not "
+        "with their lengths); tftpu_decode_free_pages reads the kind "
+        "that fills",
+    ),
+}
+
+
+def page_kind_gauges(names: Sequence[str]) -> List[Gauge]:
+    """The free-pages gauge of each of a model's page kinds, in kind
+    order: ``tftpu_decode_free_pages`` for the first, the kind's own for
+    each further one. A model with several kinds names each from
+    :data:`DECODE_PAGE_KINDS`, once, so that its per-kind series exist;
+    anything else is refused here, where the pool is built."""
+    names = list(names)
+    bad = [n for n in names[1:] if n not in DECODE_FREE_KIND_PAGES]
+    if len(names) > 1:
+        bad += [n for n in names if n not in DECODE_PAGE_KINDS]
+    if bad or len(set(names)) != len(names):
+        raise ValueError(
+            f"page kinds {names}: a served model with several kinds "
+            f"names each of them once, from {list(DECODE_PAGE_KINDS)} "
+            f"(serving/metrics.DECODE_PAGE_KINDS: the per-kind series "
+            f"are registered at import), and only "
+            f"{sorted(DECODE_FREE_KIND_PAGES)} may follow the first"
+        )
+    return [DECODE_FREE_PAGES] + [DECODE_FREE_KIND_PAGES[n]
+                                  for n in names[1:]]
+
+
+DECODE_ATTN_PAGES_WALKED_BY_KIND: Dict[str, Counter] = {
+    k: _counter(
+        "tftpu_decode_attn_pages_walked_total",
+        "Page-table entries covered by the chunks the decode-attention "
+        "kernel folds, per page kind of a model with several (a window "
+        "kind's walk starts at the chunk that holds the window's first "
+        "position)",
+        labels={"kind": k},
+    )
+    for k in DECODE_PAGE_KINDS
+}
+DECODE_ATTN_PAGES_GRID_BY_KIND: Dict[str, Counter] = {
+    k: _counter(
+        "tftpu_decode_attn_pages_grid_total",
+        "Page-table entries of every decode step's slot bucket, per "
+        "page kind of a model with several (bucket x the kind's entries)",
+        labels={"kind": k},
+    )
+    for k in DECODE_PAGE_KINDS
+}
+DECODE_ATTN_PAGES_CONTEXT: Dict[str, Counter] = {
+    k: _counter(
+        "tftpu_decode_attn_pages_context_total",
+        "Page-table entries the running slots' contexts reach (pos // "
+        "page + 1 a slot and step, window or not), per page kind: what "
+        "a walk of the whole context costs; walked / context is the "
+        "share of it a window kind still walks",
+        labels={"kind": k},
+    )
+    for k in DECODE_PAGE_KINDS
+}
+MOE_TOKENS_ROUTED = _counter(
+    "tftpu_moe_tokens_routed_total",
+    "Token-expert pairs the decode steps' expert layers routed (live "
+    "slots x experts a token, summed over layers and steps)",
+)
+MOE_EXPERT_LOAD_MAX = _counter(
+    "tftpu_moe_expert_load_max_total",
+    "Sum over decode steps and layers of the fullest expert's tokens: "
+    "over tftpu_moe_tokens_routed_total and times the expert count it "
+    "is the fullest expert's load over the mean",
 )
 DECODE_STEP_ALIAS_BYTES = _gauge(
     "tftpu_decode_step_alias_bytes",

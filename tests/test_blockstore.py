@@ -551,9 +551,8 @@ def test_kvpool_spill_restore_bit_identical(tmp_path):
     )
 
     st = BlockStore(root=str(tmp_path / "kv"), budget_bytes=0)
-    pool = PagedKVPool(
-        gen.gpt_tiny(), num_pages=9, page_size=4, max_pages_per_seq=4
-    )
+    pool = PagedKVPool(gen.gpt_tiny().served_model(4, 4 * 4),
+                       num_pages=9, page_size=4)
     pool.alloc(1, 2)
     pool.alloc(2, 3)
     snap = pool.spill(st)
@@ -568,9 +567,8 @@ def test_kvpool_spill_restore_bit_identical(tmp_path):
     assert pool.owned(2) == snap["owned"][2]
     pool.check()
     # geometry mismatch refuses before touching anything
-    other = PagedKVPool(
-        gen.gpt_tiny(), num_pages=17, page_size=4, max_pages_per_seq=4
-    )
+    other = PagedKVPool(gen.gpt_tiny().served_model(4, 4 * 4),
+                       num_pages=17, page_size=4)
     with pytest.raises(PoolAccountingError):
         other.restore(st, snap)
     st.close()
@@ -589,7 +587,8 @@ def test_kvpool_refuses_snapshots_of_another_layout(tmp_path):
 
     cfg = gen.gpt_tiny()
     st = BlockStore(root=str(tmp_path / "kv"), budget_bytes=0)
-    pool = PagedKVPool(cfg, num_pages=9, page_size=4, max_pages_per_seq=4)
+    pool = PagedKVPool(cfg.served_model(4, 4 * 4),
+                       num_pages=9, page_size=4)
     pool.alloc(1, 2)
     snap = pool.spill(st)
     assert snap["page_shapes"] == pool.page_shapes() == {
@@ -644,9 +643,8 @@ def test_kvpool_spill_folds_swap_segments(tmp_path):
 
     st = BlockStore(root=str(tmp_path / "kv"), budget_bytes=0)
     swap = BlockStore(root=str(tmp_path / "swap"), budget_bytes=0)
-    pool = PagedKVPool(
-        gen.gpt_tiny(), num_pages=9, page_size=4, max_pages_per_seq=4
-    )
+    pool = PagedKVPool(gen.gpt_tiny().served_model(4, 4 * 4),
+                       num_pages=9, page_size=4)
     pool.alloc(1, 2)
     payload = {
         k: np.asarray(v)[1:3].copy() for k, v in pool.columns.items()

@@ -102,8 +102,8 @@ def test_kvpool_property_sweep_no_leak_no_double_free(model):
     mutation the page partition holds (free ∪ owned = all usable pages,
     nothing in two places)."""
     cfg, _ = model
-    pool = PagedKVPool(cfg, num_pages=17, page_size=4,
-                       max_pages_per_seq=4)
+    pool = PagedKVPool(cfg.served_model(4, 4 * 4),
+                       num_pages=17, page_size=4)
     rng = np.random.default_rng(7)
     live = {}
     next_seq = 0
@@ -132,8 +132,8 @@ def test_kvpool_property_sweep_no_leak_no_double_free(model):
 
 def test_kvpool_exhaustion_and_double_free_raise(model):
     cfg, _ = model
-    pool = PagedKVPool(cfg, num_pages=4, page_size=4,
-                       max_pages_per_seq=3)
+    pool = PagedKVPool(cfg.served_model(4, 4 * 3),
+                       num_pages=4, page_size=4)
     pool.alloc(0, 3)
     with pytest.raises(PoolExhaustedError):
         pool.alloc(1, 1)
@@ -152,9 +152,9 @@ def test_kvpool_floor_and_table(model):
     cfg, _ = model
     with pytest.raises(ValueError):
         # cannot hold the null page + one full sequence
-        PagedKVPool(cfg, num_pages=3, page_size=4, max_pages_per_seq=3)
-    pool = PagedKVPool(cfg, num_pages=5, page_size=4,
-                       max_pages_per_seq=3)
+        PagedKVPool(cfg.served_model(4, 4 * 3), num_pages=3, page_size=4)
+    pool = PagedKVPool(cfg.served_model(4, 4 * 3),
+                       num_pages=5, page_size=4)
     got = pool.alloc(9, 2)
     table = pool.table(9)
     assert table.shape == (3,) and table.dtype == np.int32
@@ -731,8 +731,8 @@ def test_kvpool_property_sweep_swap_and_prefix_interleaved(model, tmp_path):
 
     cfg, _ = model
     ps = 4
-    pool = PagedKVPool(cfg, num_pages=33, page_size=ps,
-                       max_pages_per_seq=6)
+    pool = PagedKVPool(cfg.served_model(ps, ps * 6),
+                       num_pages=33, page_size=ps)
     store = BlockStore(root=str(tmp_path / "swap"), budget_bytes=0)
     rng = np.random.default_rng(19)
     vocab = 40
@@ -839,7 +839,8 @@ def test_kvpool_swap_misuse_raises(model, tmp_path):
     from tensorframes_tpu.blockstore import BlockStore
 
     cfg, _ = model
-    pool = PagedKVPool(cfg, num_pages=9, page_size=4, max_pages_per_seq=4)
+    pool = PagedKVPool(cfg.served_model(4, 4 * 4),
+                       num_pages=9, page_size=4)
     store = BlockStore(root=str(tmp_path / "swap"), budget_bytes=0)
     with pytest.raises(PoolAccountingError):
         pool.swap_out_seq(store, 7, {"x": np.zeros((1, 2), np.int8)})
@@ -847,8 +848,8 @@ def test_kvpool_swap_misuse_raises(model, tmp_path):
     snap = pool.swap_out_seq(
         store, 1, {"x": np.zeros((2, 2), np.int8)}
     )
-    other = PagedKVPool(cfg, num_pages=9, page_size=8,
-                        max_pages_per_seq=4)
+    other = PagedKVPool(cfg.served_model(8, 8 * 4),
+                        num_pages=9, page_size=8)
     with pytest.raises(PoolAccountingError):
         other.swap_in_seq(store, snap, 1)  # page-size mismatch
     pages, _ = pool.swap_in_seq(store, snap, 2)

@@ -92,21 +92,22 @@ def _pool_bytes(pool) -> int:
                for c in pool.values())
 
 
-def _pool_sized_ops(text: str):
+def _pool_sized_ops(text: str, num_pages: int = NUM_PAGES):
     """``{opcode: count}`` of the compiled module's instructions whose
     result has a pool column's shape (leading dim = the page count)."""
     ops = {}
     for m in re.finditer(
-        r"^\s*(?:ROOT )?\S+ = \w+\[%d,[^\]]*\]\S* ([\w-]+)\(" % NUM_PAGES,
+        r"^\s*(?:ROOT )?\S+ = \w+\[%d,[^\]]*\]\S* ([\w-]+)\(" % num_pages,
         text, re.M,
     ):
         ops[m.group(1)] = ops.get(m.group(1), 0) + 1
     return ops
 
 
-def _assert_in_place(compiled, pool, what: str):
+def _assert_in_place(compiled, pool, what: str,
+                     num_pages: int = NUM_PAGES):
     stats = compiled.memory_analysis()
-    ops = _pool_sized_ops(compiled.as_text())
+    ops = _pool_sized_ops(compiled.as_text(), num_pages)
     copies = {k: v for k, v in ops.items() if k.startswith("copy")}
     assert not copies, f"{what}: pool-sized copies {copies}"
     # every KV write is a scatter fused in place: one per column and
@@ -168,3 +169,122 @@ def test_page_ops_write_the_pool_in_place(cell, mosaic):
         assert not copies, (what, copies)
         assert stats.alias_size_in_bytes == _pool_bytes(pool), what
         assert stats.temp_size_in_bytes < TEMP_LIMIT, what
+
+
+# gpt2-small.closed-loop-384 (PR 33): 384 slots, so the 512-row bucket,
+# over the 8,193 pages the traffic fills
+BIG_SLOTS, BIG_PAGES = 512, 8193
+
+# sha256 of str(jaxpr) of the two programs at that cell's shapes, traced
+# with the Mosaic kernel, as the commit before the seam
+# (models/served.py) traced them: the engine's first client runs what
+# it ran
+STEP_JAXPR = "e25e02962955fcbacc5790d002e8e2fe5f515fd65529f54b0f9e2f7901b88d06"
+PREFILL_JAXPR = (
+    "18b89a9b7f6ec275a2ab1f3c5fb22ea335df241c9f8177771efe39bf94c3a876")
+
+
+def _big_cell(cell):
+    cfg, params, pool, i32 = cell
+    on_chip = next(iter(pool.values())).sharding
+    big = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip),
+        jax.eval_shape(lambda: gen.init_paged_kv(cfg, BIG_PAGES, PAGE)))
+    return cfg, params, big, i32
+
+
+def test_step_at_the_512_row_bucket_writes_8193_pages_in_place(cell, mosaic):
+    """``jit_step`` as the 384-slot cell runs it: 0 pool-sized copies,
+    4.03 GB aliased, temporaries of a few MB (PERF.md section 7 left
+    this case for a PR that may touch ``tests/``)."""
+    cfg, params, pool, i32 = _big_cell(cell)
+    step = gen.paged_decode_step_fn(cfg, PAGE, MAX_PAGES)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, i32(BIG_SLOTS), i32(BIG_SLOTS),
+        i32(BIG_SLOTS, MAX_PAGES)).compile()
+    ops = _assert_in_place(compiled, pool, "jit_step[512]", BIG_PAGES)
+    assert ops.get("scatter", 0) == 4 * cfg.num_layers, ops
+    assert _pool_bytes(pool) > 4.0e9
+
+
+def test_the_seam_leaves_the_transformer_s_programs_as_they_were(
+        cell, mosaic):
+    """The engine builds its programs from the served model's
+    (``models/served.py``); for the transformer family those are the
+    functions it always traced, and their jaxprs hash as before."""
+    import hashlib
+
+    cfg, params, pool, i32 = _big_cell(cell)
+    served = cfg.served_model(PAGE, MAX_PAGES * PAGE)
+    assert [k.name for k in served.kinds] == ["kv"]
+    assert served.kinds[0].entries == MAX_PAGES and not served.kinds[0].ring
+    bare = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), (params, pool))
+    for want, fn, args in (
+            (STEP_JAXPR, served.step,
+             (i32(BIG_SLOTS), i32(BIG_SLOTS), i32(BIG_SLOTS, MAX_PAGES))),
+            (PREFILL_JAXPR, served.prefill,
+             (i32(1024), i32(), i32(MAX_PAGES)))):
+        text = str(jax.make_jaxpr(fn)(*bare, *args))
+        assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+def test_sparse_decoder_step_compiles_in_place_at_published_widths(
+        one_chip, mosaic):
+    """The sparse-expert decoder's step at the widths its cell serves
+    (one layer of each kind stands for the eight): the grouped, windowed
+    ring walk and the expert matmuls' kernel (``gmm``, three a layer,
+    in ``ragged-dot``'s place) pass Mosaic, and both page kinds are
+    written in place."""
+    from tensorframes_tpu.models import sparse_decoder as sd
+
+    cfg = sd.SparseDecoderConfig(
+        vocab_size=98304, hidden=2304,
+        layer_types=("sliding", "full"), num_heads=32, num_kv_heads=4,
+        head_dim=128, sliding_window=1024,
+        rope_full=sd.RopeSpec(500000.0, factor=16.0, original_max=8192,
+                              attention_factor=1.2772588722239782),
+        rope_sliding=sd.RopeSpec(500000.0), num_experts=64,
+        experts_per_token=8, expert_hidden=896, max_seq_len=4608)
+    served = cfg.served_model(16, 4608)
+    assert [(k.name, k.entries, k.ring) for k in served.kinds] == [
+        ("full", 288, False), ("window", 65, True)]
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    layer = {k: jax.ShapeDtypeStruct(
+        s, jnp.float32 if "norm" in k else jnp.bfloat16)
+        for k, s in sd.layer_shapes(cfg).items()}
+    params = {
+        "embed": jax.ShapeDtypeStruct((98304, 2304), jnp.bfloat16),
+        "final_norm": jax.ShapeDtypeStruct((2304,), jnp.float32),
+        "head": jax.ShapeDtypeStruct((2304, 98304), jnp.bfloat16),
+        "layers": [layer] * cfg.num_layers}
+    # pools of the cell's order of size: a pool of a few MB the compiler
+    # would prefetch whole into faster memory, which is no layout copy
+    pages = {"full": 15201, "window": 1 + 128 * 65}
+    pool = jax.eval_shape(lambda: served.init_pool(pages))
+    tree = jax.tree_util.tree_map
+    params, pool = tree(on_chip, params), tree(on_chip, pool)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = jax.jit(served.step, donate_argnums=(1,)).lower(
+        params, pool, i32(16), i32(16), i32(16, 288), i32(16, 65)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert len(re.findall(r"%gmm[.\d]* = [^\n]*tpu_custom_call", text)) \
+        == 3 * cfg.num_layers
+    assert len(re.findall(
+        r"%paged_decode_attention[.\d]* = [^\n]*tpu_custom_call", text)) \
+        == cfg.num_layers
+    for kind, n in pages.items():
+        copies = {k: v for k, v in _pool_sized_ops(text, n).items()
+                  if k.startswith("copy")}
+        assert not copies, (kind, copies)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == sum(
+        _pool_bytes(cols) for cols in pool.values())
+    assert stats.temp_size_in_bytes < TEMP_LIMIT

@@ -665,7 +665,8 @@ def test_tftpu_pallas_off_removes_kernels_everywhere(forced):
 def test_selectable_is_the_one_table(forced):
     """``kernels.selectable`` is what every call site reads: all
     kernels under the force hook, none on a CPU without it."""
-    assert set(kernels.KERNELS) == {"segment_reduce", "decode_attn"}
+    assert set(kernels.KERNELS) == {"segment_reduce", "decode_attn",
+                                    "expert_matmul"}
     assert all(kernels.selectable(k) for k in kernels.KERNELS)
     configure(pallas_force=False)
     assert not any(kernels.selectable(k) for k in kernels.KERNELS)
@@ -721,5 +722,5 @@ def test_kernels_metrics_preregistered():
         for m in REGISTRY.collect()
         if m.name == "tftpu_kernels_dispatch_total"
     }
-    assert labels == set(kernels.KERNELS) == {"segment_reduce",
-                                              "decode_attn"}
+    assert labels == set(kernels.KERNELS) == {
+        "segment_reduce", "decode_attn", "expert_matmul"}
