@@ -111,6 +111,19 @@ _GATHER_BYTES = _counter(
     "layer's select pushdown shows up as this counter NOT growing for "
     "pruned columns",
 )
+# Both grow when a hoisted entry is BUILT (once per program, feed shape
+# and placement) and never on a dispatch: growth in steady state means
+# weights are being uploaded again.
+_CONST_PLACEMENTS = _counter(
+    "tftpu_executor_const_placements_total",
+    "Times a hoisted program's constants (weights) were put on a "
+    "device set: once per hoisted entry, at its construction",
+)
+_CONST_PLACED_BYTES = _counter(
+    "tftpu_executor_const_placed_bytes_total",
+    "Bytes of hoisted constants those placements wrote to device "
+    "memory (a replicated placement counts every device's copy)",
+)
 
 
 def donation_supported() -> bool:
@@ -312,24 +325,63 @@ def _store_meta(kind: str, form: str, donate: bool, inputs,
     return meta
 
 
+def _consts_placement(shardings: Sequence):
+    """Where a hoisted program's constants live, given its feeds'
+    NON-TRIVIAL shardings: fully replicated over the feeds' own device
+    set — the feeds' mesh with an empty spec, or the one device a feed
+    is committed to. None (uncommitted on the default device, as host
+    and default-device feeds have always had them) when no feed carries
+    a placement: committing there would make a later call with feeds
+    elsewhere an error. None too when the feeds name different device
+    sets or a multi-device sharding without a mesh — jax refuses such a
+    call at ``lower()`` as it always has; nothing is guessed."""
+    if not shardings:
+        return None
+    first = shardings[0]
+    devices = first.device_set
+    if any(sh.device_set != devices for sh in shardings[1:]):
+        return None
+    if len(devices) == 1:
+        (dev,) = devices
+        return jax.sharding.SingleDeviceSharding(dev)
+    if not isinstance(first, jax.sharding.NamedSharding):
+        return None
+    return jax.sharding.NamedSharding(
+        first.mesh, jax.sharding.PartitionSpec()
+    )
+
+
 def _hoisted_for(fn, feeds: Dict[str, jnp.ndarray], name: str = "run"):
     """Build a :class:`HoistedProgram` (program.py — weights as runtime
-    arguments, device-committed once) at these feeds' shapes — and
-    placements: sharded feeds trace (and later lower) with their
-    shardings attached, so the hoisted executable is specialized to the
-    same layout the dispatch will call it with. ``name`` becomes the
-    XLA module's (``jit_<name>``)."""
+    arguments, put on their devices once, here) at these feeds' shapes
+    — and placements: sharded feeds trace (and later lower) with their
+    shardings attached and the constants replicated over the same
+    device set (:func:`_consts_placement`), so the hoisted executable
+    is specialized to the layout the dispatch will call it with and a
+    call moves no weights. ``name`` becomes the XLA module's
+    (``jit_<name>``)."""
     from ..program import HoistedProgram
 
     abstract = {}
+    shardings = []
     for k, v in feeds.items():
         sh = _feed_sharding(v)
-        abstract[k] = (
-            jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=sh)
-            if sh is not None
-            else jax.ShapeDtypeStruct(np.shape(v), v.dtype)
+        if sh is not None:
+            shardings.append(sh)
+            abstract[k] = jax.ShapeDtypeStruct(
+                np.shape(v), v.dtype, sharding=sh
+            )
+        else:
+            abstract[k] = jax.ShapeDtypeStruct(np.shape(v), v.dtype)
+    placement = _consts_placement(shardings)
+    entry = HoistedProgram(fn, abstract, name=name, placement=placement)
+    nbytes = entry.const_bytes()
+    if nbytes:
+        _CONST_PLACEMENTS.inc()
+        _CONST_PLACED_BYTES.inc(
+            nbytes * (1 if placement is None else len(placement.device_set))
         )
-    return HoistedProgram(fn, abstract, name=name)
+    return entry
 
 
 class CompiledProgram:
@@ -441,11 +493,20 @@ class CompiledProgram:
             else:
                 closed = jax.make_jaxpr(self._kind_fn(kind))(abstract)
                 hoisted = False
+            # the name is baked into the stored executable
+            extra = {"module": self.module_name(kind)}
+            consts = _sharding_token(entry.placement) if entry else None
+            if consts is not None:
+                # so is the constants' layout: an entry compiled from
+                # uncommitted constants left that layout to the
+                # compiler (sharding propagation to parameters) and
+                # must not be served for buffers replicated up front.
+                # On the default device alone there is one layout, and
+                # the key stays what it was
+                extra["consts"] = consts
             return fingerprint_from_closed(
                 closed, avals, outs, kind=kind, donate=donate,
-                hoisted=hoisted, shardings=shardings,
-                # the name is baked into the stored executable
-                extra={"module": self.module_name(kind)},
+                hoisted=hoisted, shardings=shardings, extra=extra,
             )
         except Exception as e:
             from ..compilecache.store import note_unfingerprintable
@@ -487,10 +548,14 @@ class CompiledProgram:
         multiprocess = jax.process_count() > 1
         t0 = time.perf_counter()
         # multi-process fleets keep the plain (closure-capture) form:
-        # hoisted consts are committed to THIS rank's local device, so
-        # a hoisted executable bakes a per-rank device assignment into
-        # its input layout and could never be shared across the fleet's
-        # store — baked consts compile identically on every rank
+        # hoisted consts live on THIS rank's devices (uncommitted on
+        # its default device, or replicated over the feeds' device
+        # set), so a hoisted executable bakes a per-rank device
+        # assignment into its input layout and could never be shared
+        # across the fleet's store — baked consts compile identically
+        # on every rank. On one process the entry's constants are in
+        # place before lower(), so the compiled input layout and the
+        # buffers _wrap_executable closes over agree.
         entry = (
             self._entry(base, self._kind_fn(kind), feeds)
             if self.hoist and not multiprocess else None
@@ -631,10 +696,14 @@ class CompiledProgram:
             akey = key + ("donate",) if donate else key
             fresh = self._note_dispatch(key, donate)
             call = self._aot.peek(akey)
+            placed = None  # the hoisted entry THIS dispatch built
             if call is None:
+                had_entry = key in self._hoisted
                 built = self._build_aot(kind, akey, feeds, donate)
                 if built is not None:
                     call = built[0]
+                if tracing and not had_entry:
+                    placed = self._hoisted.get(key)
             deadline = _fleet.dispatch_deadline_s()
             if deadline and call is None and fresh:
                 # last-resort jit fallback, first dispatch at this
@@ -703,9 +772,16 @@ class CompiledProgram:
             which = "block" if kind == "block" else "rows"
             # entry to dispatch: the flight summary, the feeds'
             # asarray, the keys and the executable's lookup or build
+            prep = {"kind": which, "compiled": fresh}
+            if placed and (nbytes := placed.const_bytes()):
+                # this dispatch put the program's constants on devices
+                prep["const_bytes"] = nbytes
+                prep["placement"] = (
+                    _sharding_token(placed.placement) or "default"
+                )
             _events.TRACER.emit_complete(
                 "executor.prepare", t_in, t0 - t_in,
-                args={"kind": which, "compiled": fresh}, cat="executor",
+                args=prep, cat="executor",
             )
             # synced: deadline mode blocked on the result inside the
             # span; otherwise it times the dispatch alone

@@ -56,16 +56,24 @@ class HoistedProgram:
     as runtime parameters: int8 stays ``s8`` in the executable and the
     compiler never sees a literal to fold.
 
-    Constants are ``jax.device_put`` once at construction so repeated
-    calls reuse the committed device buffers instead of re-uploading
-    weights per call."""
+    Constants are ``jax.device_put`` once at construction, where the
+    calls will want them. With no ``placement`` they sit *uncommitted*
+    on the default device: a one-device executable reuses those buffers
+    call after call, and jax may still move them beside inputs that
+    live elsewhere. An executable compiled for a mesh, though, would
+    copy uncommitted constants to every device of the mesh on every
+    call, so for inputs that carry a placement the executor passes
+    ``placement`` — the constants fully replicated over the inputs' own
+    device set — and they are *committed* there, once; ``lower()`` sees
+    them with that sharding and each call is an enqueue."""
 
     __slots__ = (
         "jitted", "consts", "in_tree", "_flat_abstract", "_run",
-        "_jitted_donate", "closed", "out_tree",
+        "_jitted_donate", "closed", "out_tree", "placement",
     )
 
-    def __init__(self, fn: Callable, abstract_inputs, name: str = "run"):
+    def __init__(self, fn: Callable, abstract_inputs, name: str = "run",
+                 placement=None):
         from jax.core import eval_jaxpr
 
         closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(
@@ -83,7 +91,8 @@ class HoistedProgram:
         # (n_consts, input count, out_tree)
         self.closed = closed
         self.out_tree = out_tree
-        self.consts = jax.device_put(closed.consts)
+        self.placement = placement
+        self.consts = jax.device_put(closed.consts, placement)
 
         def run(consts, flat_ins):
             outs = eval_jaxpr(jaxpr, consts, *flat_ins)

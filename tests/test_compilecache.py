@@ -595,6 +595,97 @@ def test_sharded_dispatch_roundtrip_bit_identical(store_dir):
     np.testing.assert_array_equal(got_warm, want)
 
 
+@pytest.mark.parametrize("n_devices, keyed", [(4, True), (1, False)])
+def test_fingerprint_constants_placement_in_key(n_devices, keyed,
+                                                monkeypatch):
+    """Constants replicated over a mesh key apart from the same program
+    lowered from uncommitted constants; on the default device alone
+    there is one layout and the key is what it always was."""
+    import jax
+
+    from tensorframes_tpu.ops import executor
+    from tensorframes_tpu.parallel.mesh import batch_sharding, make_mesh
+
+    _mesh_or_skip()
+    w = np.linspace(1.0, 2.0, 4, dtype=np.float32)
+    df = tfs.frame_from_arrays({"x": np.zeros((8, 4), np.float32)})
+    compiled = tfs.compile_program(lambda x: {"y": x * w}, df).compiled()
+    sharding = batch_sharding(
+        make_mesh(devices=jax.devices()[:n_devices]), 2)
+    abstract = {"x": jax.ShapeDtypeStruct((8, 4), np.float32,
+                                          sharding=sharding)}
+
+    def fingerprint():
+        entry = executor._hoisted_for(compiled.program.fn, abstract)
+        return entry, compiled._fingerprint("block", abstract, False, entry)
+
+    placed, fp_placed = fingerprint()
+    assert placed.consts[0].committed
+    monkeypatch.setattr(executor, "_consts_placement", lambda shardings: None)
+    unplaced, fp_unplaced = fingerprint()
+    assert not unplaced.consts[0].committed
+    assert fp_placed and fp_unplaced
+    assert (fp_placed != fp_unplaced) == keyed
+
+
+def test_entry_from_unplaced_constants_is_not_served(store_dir, monkeypatch):
+    """A store entry published while hoisted constants were left
+    uncommitted (the compiler then chose their layout) is not served
+    once they are replicated over the feeds' mesh up front: the
+    constants' placement is in the fingerprint, so the old entry misses,
+    the program compiles ONCE and publishes beside it, and a fresh
+    instance loads that and runs with no weight moving between devices
+    on any call."""
+    import jax
+
+    from tensorframes_tpu.ops import executor
+
+    _mesh_or_skip()
+    w = np.linspace(1.0, 2.0, 4, dtype=np.float32)
+
+    def build():
+        df = tfs.frame_from_arrays(
+            {"x": np.arange(512.0, dtype=np.float32).reshape(128, 4)}
+        ).to_device()
+        return df, tfs.compile_program(lambda x: {"y": x * w}, df)
+
+    def compiles():
+        return _hist_count("tftpu_executor_compile_seconds")
+
+    df, p = build()
+    with monkeypatch.context() as m:    # as the store's writer once was
+        m.setattr(executor, "_consts_placement", lambda shardings: None)
+        want = np.asarray(tfs.map_blocks(p, df).column_values("y"))
+    (entry,) = p.compiled()._hoisted.values()
+    assert not entry.consts[0].committed
+    old = _entries(store_dir)
+    assert len(old) == 1
+
+    df, p = build()
+    c0 = compiles()
+    m0 = _counter_val("tftpu_compilecache_misses_total")
+    got = np.asarray(tfs.map_blocks(p, df).column_values("y"))
+    assert _counter_val("tftpu_compilecache_misses_total") == m0 + 1
+    assert compiles() == c0 + 1
+    assert len(_entries(store_dir)) == 2 and set(old) < set(_entries(store_dir))
+    np.testing.assert_array_equal(got, want)
+
+    df, p = build()
+    h0 = _counter_val("tftpu_compilecache_hits_total")
+    n0 = _counter_val("tftpu_executor_const_placements_total")
+    with jax.transfer_guard_device_to_device("disallow"):
+        for _ in range(2):
+            mapped = tfs.map_blocks(p, df)
+            np.testing.assert_array_equal(
+                np.asarray(mapped.column_values("y")), want)
+    assert _counter_val("tftpu_compilecache_hits_total") == h0 + 1
+    assert compiles() == c0 + 1
+    assert _counter_val("tftpu_executor_const_placements_total") == n0 + 1
+    (entry,) = p.compiled()._hoisted.values()
+    assert entry.consts[0].committed
+    assert entry.consts[0].sharding.is_fully_replicated
+
+
 def test_warm_sharded_key_makes_first_dispatch_a_hit(store_dir):
     """warm() with sharding-annotated abstract feeds precompiles the
     SHARDED placement's key: the first real sharded dispatch is a

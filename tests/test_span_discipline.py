@@ -245,9 +245,11 @@ def test_first_token_time_reaches_the_caller(model, engine):
 
 # -- the frame pass -------------------------------------------------------
 
-def _frame_pass(devices=None, blocks=3, rows=8):
+def _frame_pass(devices=None, blocks=3, rows=8, weight=None):
     """map_blocks then reduce_blocks over a frame of ``blocks`` blocks,
-    resident (and, given devices, sharded) as the benchmark's is."""
+    resident (and, given devices, sharded) as the benchmark's is. With
+    a ``weight`` the map program closes over it, as a model over its
+    parameters."""
     from tensorframes_tpu import dtypes as dt
     from tensorframes_tpu.frame import TensorFrame
     from tensorframes_tpu.parallel.mesh import batch_sharding, make_mesh
@@ -263,12 +265,14 @@ def _frame_pass(devices=None, blocks=3, rows=8):
     frame = TensorFrame(
         data, Schema([ColumnInfo("x", dt.float32, Shape((-1, 4)))]))
     frame._mesh, frame._axis = mesh, mesh.axis_names[0]
-    score = tfs.compile_program(lambda x: {"y": x * 2.0 + 1.0}, frame)
+    if weight is None:
+        weight = 2.0
+    score = tfs.compile_program(lambda x: {"y": x * weight + 1.0}, frame)
     total = tfs.compile_program(
         lambda y_input: {"y": y_input.sum(axis=0)},
         tfs.map_blocks(score, frame), reduce_mode="blocks")
     out = tfs.reduce_blocks(total, tfs.map_blocks(score, frame))
-    want = sum((np.asarray(b["x"]) * 2.0 + 1.0).sum(axis=0) for b in data)
+    want = sum((np.asarray(b["x"]) * weight + 1.0).sum(axis=0) for b in data)
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
     return frame, score, total
 
@@ -318,6 +322,141 @@ def test_sharded_frame_pass_spans_nest(tracing):
     for g, p, r, f in zip(by["plan.reduce.gather"], by["executor.prepare"],
                           by["executor.run_block"], by["plan.reduce.fetch"]):
         assert g["ts"] <= p["ts"] <= r["ts"] <= f["ts"]
+
+
+# -- where a hoisted program's constants live (ISSUE 35) --------------------
+
+WEIGHT = np.linspace(0.5, 2.0, 4, dtype=np.float32)
+
+
+def _counter(name):
+    return sum(d["value"] for d in REGISTRY.snapshot() if d["name"] == name)
+
+
+def _placed():
+    return (_counter("tftpu_executor_const_placements_total"),
+            _counter("tftpu_executor_const_placed_bytes_total"))
+
+
+def _entries_with_consts(*programs):
+    """Every hoisted entry that holds constants, of these programs and
+    of the fused programs the plan built over them."""
+    from tensorframes_tpu.plan import lower
+
+    compiled = [p.compiled() for p in programs]
+    compiled += [fused.compiled()
+                 for fused, pinned in lower._FUSED_CACHE.values()
+                 if any(p in pinned for p in programs)]
+    return [e for c in compiled for e in c._hoisted.values()
+            if e and jax.tree_util.tree_leaves(e.consts)]
+
+
+def _assert_replicated_over(entries, devices):
+    assert entries
+    for entry in entries:
+        for leaf in jax.tree_util.tree_leaves(entry.consts):
+            assert leaf.committed
+            assert leaf.sharding.is_fully_replicated
+            assert leaf.sharding.device_set == set(devices)
+
+
+def test_sharded_pass_places_constants_on_the_mesh_once(tracing):
+    devices = jax.devices()[:4]
+    n0, b0 = _placed()
+    frame, score, total = _frame_pass(devices, blocks=2, weight=WEIGHT)
+    entries = _entries_with_consts(score, total)
+    _assert_replicated_over(entries, devices)
+    n1, b1 = _placed()
+    # the fused map_reduce program holds the weight; the reduce none
+    assert n1 - n0 == len(entries) >= 1
+    assert b1 - b0 == len(entries) * WEIGHT.nbytes * 4
+    built = [e["args"] for e in _events()
+             if e["name"] == "executor.prepare"
+             and "const_bytes" in e["args"]]
+    assert [a["const_bytes"] for a in built] == [WEIGHT.nbytes] * len(entries)
+    for a in built:
+        placement = json.loads(a["placement"])
+        assert placement["spec"] == []
+        assert placement["mesh"]["devices"] == [d.id for d in devices]
+    # the same programs again: no weight moves between devices, the
+    # buffers are the ones placed at construction, nothing is re-placed
+    buffers = [id(leaf) for e in entries
+               for leaf in jax.tree_util.tree_leaves(e.consts)]
+    with jax.transfer_guard_device_to_device("disallow"):
+        out = tfs.reduce_blocks(total, tfs.map_blocks(score, frame))
+    one, one_score, one_total = _frame_pass(blocks=2, weight=WEIGHT)
+    np.testing.assert_allclose(          # the unsharded pass's result
+        np.asarray(out),
+        np.asarray(tfs.reduce_blocks(one_total,
+                                     tfs.map_blocks(one_score, one))),
+        rtol=1e-6)
+    assert _placed() == (n1 + 1, b1 + WEIGHT.nbytes)   # that pass's own
+    assert buffers == [id(leaf) for e in entries
+                       for leaf in jax.tree_util.tree_leaves(e.consts)]
+
+
+def _weighted_program():
+    from tensorframes_tpu import dtypes as dt
+    from tensorframes_tpu.shape import Shape
+
+    return Program(lambda feeds: {"y": feeds["x"] * WEIGHT + 1.0},
+                   [TensorSpec("x", dt.float32, Shape((-1, 4)))])
+
+
+@pytest.mark.parametrize("case", ["sharded", "single_device", "trivial",
+                                  "donating"])
+def test_constants_follow_the_feeds(case, monkeypatch):
+    """One dispatch path, told apart by what the feeds carry: constants
+    replicated over a mesh, committed to the one device a feed sits on,
+    or uncommitted on the default device as ever."""
+    from tensorframes_tpu.ops import executor
+    from tensorframes_tpu.parallel.mesh import batch_sharding, make_mesh
+
+    x = np.arange(32, dtype=np.float32).reshape(8, 4)
+    want = x * WEIGHT + 1.0
+    compiled = _weighted_program().compiled()
+    donate = case == "donating"
+    if donate:       # XLA:CPU ignores the donation; the variant is built
+        monkeypatch.setattr(executor, "donation_supported", lambda: True)
+    if case == "trivial":
+        feed, devices = x, None
+    elif case == "single_device":
+        devices = [jax.devices()[2]]
+        feed = jax.device_put(x, devices[0])
+    else:
+        devices = jax.devices()[:4]
+        feed = jax.device_put(x, batch_sharding(make_mesh(devices=devices), 2))
+
+    def run(v):
+        return compiled.run_block({"x": v}, to_numpy=False, donate=donate)["y"]
+
+    n0, _ = _placed()
+    np.testing.assert_allclose(np.asarray(run(feed)), want, rtol=1e-6)
+    (entry,) = compiled._hoisted.values()
+    leaves = jax.tree_util.tree_leaves(entry.consts)
+    if devices is None:
+        assert entry.placement is None
+        assert not any(leaf.committed for leaf in leaves)
+        # feeds that turn up elsewhere get an entry of their own there
+        other = jax.devices()[3]
+        np.testing.assert_allclose(
+            np.asarray(run(jax.device_put(x, other))), want, rtol=1e-6)
+        _assert_replicated_over(
+            [e for e in compiled._hoisted.values() if e is not entry],
+            [other])
+        np.testing.assert_allclose(np.asarray(run(x)), want, rtol=1e-6)
+        assert _placed()[0] - n0 == 2
+        return
+    _assert_replicated_over([entry], devices)
+    assert _placed()[0] - n0 == 1
+    again = jax.device_put(x, feed.sharding)
+    with jax.transfer_guard_device_to_device("disallow"):
+        got = run(again)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    assert not any(leaf.is_deleted() for leaf in leaves)
+    assert _placed()[0] - n0 == 1
+    assert [id(leaf) for leaf in leaves] == [
+        id(leaf) for leaf in jax.tree_util.tree_leaves(entry.consts)]
 
 
 def _module_names(program):
