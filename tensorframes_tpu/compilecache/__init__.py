@@ -29,6 +29,7 @@ from .warmup import (  # noqa: F401
     WarmupReport,
     decode_slot_buckets,
     decode_warmup_grid,
+    packed_prefill_buckets,
     partitioner_row_counts,
     serving_row_buckets,
     warm_program,
@@ -42,6 +43,7 @@ __all__ = [
     "active_store",
     "decode_slot_buckets",
     "decode_warmup_grid",
+    "packed_prefill_buckets",
     "partitioner_row_counts",
     "program_fingerprint",
     "serving_row_buckets",

@@ -42,6 +42,7 @@ logger = get_logger(__name__)
 __all__ = [
     "WarmupReport", "warmup", "warm_program", "partitioner_row_counts",
     "serving_row_buckets", "decode_slot_buckets", "decode_warmup_grid",
+    "packed_prefill_buckets",
 ]
 
 
@@ -200,17 +201,44 @@ def decode_slot_buckets(max_slots: int) -> List[int]:
     return buckets
 
 
-def decode_warmup_grid(max_slots: int,
-                       max_prompt_len: int) -> Dict[str, List[int]]:
+def packed_prefill_buckets(max_prompt_len: int, block: int) -> List[int]:
+    """The total-token ladder of a packed prefill (several prompts in one
+    call, each starting on a ``block`` edge): 1, 1.5, 2, 3 and 4 times
+    the largest prompt's rows rounded up to a power of two, each a whole
+    number of blocks, and never more buckets than the one-sequence
+    ladder :func:`serving_row_buckets` has. Half-octave steps pad a call
+    by at most a third (a sixth on average) where a power-of-two ladder
+    pads it by up to a half; the top bucket holds four of the largest
+    prompts. Each bucket is a program to load and warm at start, a large
+    one (a packed prefill folds every layer's attention in a loop), so
+    the ladder is short. For ``max_prompt_len`` 896 and 64-row blocks:
+    1,024, 1,536, 2,048, 3,072, 4,096."""
+    block = int(block)
+    if block < 1:
+        raise ValueError(f"block must be >= 1, got {block}")
+    largest = -(-int(max_prompt_len) // block) * block
+    unit = 1 << (largest - 1).bit_length()
+    ladder = sorted({-(-(unit * k // 2) // block) * block
+                     for k in (2, 3, 4, 6, 8)})
+    return ladder[:len(serving_row_buckets(max_prompt_len))]
+
+
+def decode_warmup_grid(max_slots: int, max_prompt_len: int,
+                       pack_block: Optional[int] = None
+                       ) -> Dict[str, List[int]]:
     """The slot-count × phase bucket grid a decode engine must warm for
     zero steady-state compiles: one decode-step executable per slot
     bucket, one prefill executable per prompt-length bucket (prompt
     lengths pad through the SAME ladder — a prefill chunk's token dim
-    is a vmapped lead dim too). The engine's ``start()`` walks exactly
-    this grid; tests assert no dispatch ever lands off it."""
+    is a vmapped lead dim too), or, for an engine that packs prompts
+    into one prefill (``pack_block`` given), one per bucket of
+    :func:`packed_prefill_buckets` in that ladder's place. The engine's
+    ``start()`` walks exactly this grid; tests assert no dispatch ever
+    lands off it."""
     return {
         "decode": decode_slot_buckets(max_slots),
-        "prefill": serving_row_buckets(max_prompt_len),
+        "prefill": (serving_row_buckets(max_prompt_len) if pack_block is None
+                    else packed_prefill_buckets(max_prompt_len, pack_block)),
     }
 
 

@@ -16,6 +16,7 @@ out.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -361,6 +362,18 @@ def _write_kv(pool, li: int, pg, off, k_rows, v_rows) -> None:
             pg, li, off].set(srows)
 
 
+def _write_kv_pages(pool, li: int, pg, page_size: int, k_rows,
+                    v_rows) -> None:
+    """:func:`_write_kv` a whole page at a time: the rows of
+    ``k_rows``/``v_rows`` in runs of ``page_size``, run ``i`` to page
+    ``pg[i]`` — one scatter update a page where :func:`_write_kv` makes
+    one a row."""
+    for name, (rows, srows) in (("k", k_rows), ("v", v_rows)):
+        for col, x in ((name, rows), (name + "_scale", srows)):
+            pool[col] = pool[col].at[pg, li].set(
+                x.reshape(-1, page_size, x.shape[-1]))
+
+
 def paged_kv_nbytes(pool: Dict[str, jnp.ndarray]) -> int:
     """Pool HBM footprint in bytes (the budget eviction exists to honor)."""
     return sum(int(np.prod(a.shape)) * a.dtype.itemsize
@@ -443,6 +456,99 @@ def paged_prefill_fn(cfg: TransformerConfig, page_size: int,
         return pool, first
 
     return prefill
+
+
+#: rows of the packed prefill's attention block: every packed prompt
+#: starts on a multiple of it
+PACK_BLOCK = 64
+
+
+def paged_packed_prefill_fn(cfg: TransformerConfig, page_size: int,
+                            max_pages: int, block: int = PACK_BLOCK):
+    """Build the prefill of several prompts at once: ``fn(params, pool,
+    tokens[T], seg_start[B], seg_len[B], tables[B, max_pages]) -> (pool,
+    first_tokens[B])``.
+
+    Segment ``b`` is one prompt at rows ``[seg_start[b], seg_start[b] +
+    seg_len[b])`` of ``tokens``; every ``seg_start`` is a multiple of
+    ``block`` and ``T`` is too. A segment's positions count from its own
+    start: they index the position embedding, place its KV writes
+    through ``tables[b]`` (the same int8 quantization and scatter as
+    :func:`paged_prefill_fn`) and order its causal attention, which
+    folds the segment's own blocks only
+    (``ops.attention.blockwise_attention`` with ``first_block``). Rows
+    outside every segment and empty segments (``seg_len`` 0) write the
+    null page. ``first_tokens[b]`` is the greedy argmax at the segment's
+    last row; the head runs on those ``B`` rows alone. A prompt gives
+    the same bits wherever it sits and whatever is packed beside it.
+    """
+
+    def packed_prefill(params, pool, tokens, seg_start, seg_len, tables):
+        from ..ops.attention import blockwise_attention
+        from ..ops.quantize import matmul as _mm
+
+        (T,) = tokens.shape
+        if T % block or block % page_size:
+            raise ValueError(f"{T} packed rows are no whole number of "
+                             f"{block}-row blocks of {page_size}-row pages")
+        h, nh, hd = cfg.hidden, cfg.num_heads, cfg.head_dim
+        rows = jnp.arange(T)
+        seg_end = seg_start + seg_len
+        inside = ((rows[:, None] >= seg_start[None, :])
+                  & (rows[:, None] < seg_end[None, :]))     # [T, B]
+        valid = inside.any(axis=1)
+        seg = jnp.argmax(inside, axis=1)
+        rel = jnp.where(valid, rows - seg_start[seg], 0)    # segment position
+        with jax.named_scope("embed"):
+            x = params["embed"]["tok"][tokens].astype(cfg.dtype)
+            x = x + params["embed"]["pos"][rel].astype(cfg.dtype)
+        # KV goes to the pool a page at a time: a segment starts on a
+        # block edge and a block is whole pages, so each run of page_size
+        # rows is one page of one segment (past the prompt its rows hold
+        # padding until decode steps write them); a run of no segment
+        # writes the null page
+        run = rows[::page_size]
+        pg = jnp.where(valid[run], tables[
+            seg[run], jnp.minimum(rel[run] // page_size, max_pages - 1)], 0)
+        # a block folds from its segment's first block; a block of no
+        # segment folds itself alone
+        heads = rows[::block]
+        first_block = jnp.where(valid[heads], seg_start[seg[heads]] // block,
+                                heads // block)
+        pool = dict(pool)
+        for li, p in enumerate(params["layers"]):
+            with jax.named_scope(f"layer_{li}/attn"):
+                y = _layer_norm(x, **p["ln1"])
+                qkv = _mm(y, p["attn"]["qkv"]).reshape(T, 3, nh, hd)
+                q = qkv[:, 0].transpose(1, 0, 2)        # [nh, T, hd]
+                k = qkv[:, 1].transpose(1, 0, 2)
+                v = qkv[:, 2].transpose(1, 0, 2)
+            with jax.named_scope(f"layer_{li}/kv_write"):
+                kq, ks = _quantize_slots(k[None])       # [1, nh, T, hd]
+                vq, vs = _quantize_slots(v[None])
+                _write_kv_pages(
+                    pool, li, pg, page_size,
+                    _pool_rows(kq[0].transpose(1, 0, 2), ks[0, ..., 0].T),
+                    _pool_rows(vq[0].transpose(1, 0, 2), vs[0, ..., 0].T),
+                )
+            with jax.named_scope(f"layer_{li}/attn"):
+                ctx = blockwise_attention(
+                    q[None], kq, vq, causal=True, block_size=block,
+                    k_scale=ks[..., 0], v_scale=vs[..., 0],
+                    first_block=first_block,
+                )[0].transpose(1, 0, 2).reshape(T, h)
+                x = x + _mm(ctx, p["attn"]["out"])
+            with jax.named_scope(f"layer_{li}/mlp"):
+                x = x + _mlp(p["mlp"], _layer_norm(x, **p["ln2"]))
+        with jax.named_scope("head"):
+            last = x[jnp.clip(seg_end - 1, 0, T - 1)]   # [B, h]
+            hs = _layer_norm(last, **params["final_ln"])
+            first = jnp.argmax(
+                _logits(cfg, params, hs), axis=-1
+            ).astype(jnp.int32)
+        return pool, first
+
+    return packed_prefill
 
 
 def paged_suffix_prefill_fn(cfg: TransformerConfig, page_size: int,
@@ -652,10 +758,13 @@ def paged_decode_step_fn(cfg: TransformerConfig, page_size: int,
 def served_model(cfg: TransformerConfig, page_size: int, horizon: int):
     """The transformer family as the decode engine takes it
     (``models/served.py``): one page kind holding every layer, a page
-    per ``page_size`` positions of context, and all five programs."""
+    per ``page_size`` positions of context, and every optional program
+    (the packed prefill's prompts start on 64-row blocks)."""
     from .served import PageKind, ServedModel
 
     max_pages = -(-int(horizon) // int(page_size))
+    # a packed prompt starts on a block edge that is a page edge too
+    block = math.lcm(PACK_BLOCK, int(page_size))
     return ServedModel(
         vocab_size=int(cfg.vocab_size),
         max_seq_len=int(cfg.max_seq_len),
@@ -666,6 +775,9 @@ def served_model(cfg: TransformerConfig, page_size: int, horizon: int):
         step=paged_decode_step_fn(cfg, page_size, max_pages),
         suffix_prefill=paged_suffix_prefill_fn(cfg, page_size, max_pages),
         page_ops=paged_page_ops_fns(max_pages),
+        packed_prefill=paged_packed_prefill_fn(cfg, page_size, max_pages,
+                                               block),
+        pack_block=block,
     )
 
 

@@ -31,6 +31,20 @@ itself. The engine asks nothing else of a model:
   **page_ops** (``extract, restore, copy_page``: host swap and
   copy-on-extend). Without them ``DecodeConfig(prefix_cache=True)`` /
   ``kv_swap=True`` are refused at ``register_decode``.
+* optionally **packed_prefill** ``(params, pool, tokens[T],
+  seg_start[B], seg_len[B], tables[B, entries]) -> (pool,
+  first_tokens[B])``, for a model with one page kind: several prompts
+  in one call, prompt ``b`` at rows ``[seg_start[b], seg_start[b] +
+  seg_len[b])`` with positions counted from its own start, written
+  through ``tables[b]``, attending only itself; every ``seg_start`` and
+  ``T`` a multiple of ``pack_block``; empty segments (``seg_len`` 0) and
+  rows outside every segment write the null page; ``first_tokens[b]``
+  the greedy token after prompt ``b``. A prompt's first token and KV
+  bytes must not depend on where it sits or what is packed beside it
+  (a preempted request's re-prefill replays them). Where the model gives
+  it and no prefix cache is armed, the engine prefills the cold joins of
+  one admission poll together through it, and warms its total-token
+  ladder in the one-sequence prefill's place.
 """
 
 from __future__ import annotations
@@ -72,6 +86,17 @@ class ServedModel:
     #: the kernels (``kernels.KERNELS``) the step traces where the
     #: backend runs them: the engine counts a dispatch of each a step
     kernels: Tuple[str, ...] = ("decode_attn",)
+    packed_prefill: Optional[Callable] = None
+    #: rows a packed prompt's start and the packed call's length are
+    #: multiples of
+    pack_block: int = 64
+
+    def __post_init__(self):
+        if self.packed_prefill is not None and len(self.kinds) != 1:
+            raise ValueError(
+                "packed_prefill takes one page table a prompt: a model "
+                f"with {len(self.kinds)} page kinds cannot give it"
+            )
 
     def init_pool(self, num_pages: Dict[str, int]):
         """The pool columns: one kind's dict, or a dict of them."""

@@ -78,6 +78,7 @@ def blockwise_attention(
     window: Optional[int] = None,
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
+    first_block: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Memory-efficient attention: lax.scan over k/v chunks with an online
     softmax. Exact (not an approximation); peak memory O(sq · block_size)
@@ -86,18 +87,22 @@ def blockwise_attention(
     A causal call may also give a ``window`` (position ``i`` attends
     ``i - (window - 1) .. i``: only the blocks of that band are
     computed), fewer KV heads than query heads (a whole multiple: query
-    head ``j`` reads KV head ``j // group``) and per-position scales
+    head ``j`` reads KV head ``j // group``), per-position scales
     ``[batch, kv_heads, seq]`` of quantized ``k`` / ``v`` (the key's
     scale multiplies the scores, the value's the softmax weights, so no
-    dequantized copy is made): :func:`_band_attention`."""
+    dequantized copy is made) and ``first_block`` ``[seq // block_size]``
+    int32, each query block's lowest key block (several sequences packed
+    along ``seq``, each starting at a multiple of ``block_size``: a
+    block attends its own sequence only): :func:`_band_attention`."""
     if window is not None or k_scale is not None or v_scale is not None \
-            or q.shape[1] != k.shape[1]:
+            or first_block is not None or q.shape[1] != k.shape[1]:
         if not causal or q.shape[2] != k.shape[2]:
             raise ValueError(
-                "a window, grouped heads or KV scales need causal=True "
-                "and queries and keys over the same positions"
+                "a window, grouped heads, KV scales or block bounds need "
+                "causal=True and queries and keys over the same positions"
             )
-        return _band_attention(q, k, v, block_size, window, k_scale, v_scale)
+        return _band_attention(q, k, v, block_size, window, k_scale, v_scale,
+                               first_block)
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_size = min(block_size, sk)
@@ -137,13 +142,19 @@ def blockwise_attention(
     return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
 
 
-def _band_attention(q, k, v, block_size, window, k_scale, v_scale):
+def _band_attention(q, k, v, block_size, window, k_scale, v_scale,
+                    first_block=None):
     """Causal self-attention a query block at a time, each against the
     KV blocks its band reaches: block ``i`` folds blocks ``first(i) ..
     i``, ``first`` 0 without a window, so a windowed layer's cost grows
     with ``seq · window`` and no ``seq × seq`` array exists. Online
     softmax in float32; scores and weights of one block pair at a time
-    (``[batch, kv_heads, group, block, block]``)."""
+    (``[batch, kv_heads, group, block, block]``).
+
+    Given ``first_block`` (packed sequences, each starting on a block
+    edge), ``first(i)`` is ``first_block[i]``, the first block of
+    ``i``'s own sequence: a sequence's rows see the same keys in the same
+    order wherever it sits and whatever lies beside it."""
     b, hq, s, d = q.shape
     hk = k.shape[1]
     if hq % hk:
@@ -152,6 +163,10 @@ def _band_attention(q, k, v, block_size, window, k_scale, v_scale):
     blk = min(int(block_size), s)
     nb = -(-s // blk)
     pad = nb * blk - s
+    if first_block is not None and (pad or window is not None):
+        raise ValueError(
+            "block bounds need a whole number of blocks and no window"
+        )
     f32 = jnp.float32
     ones = jnp.ones((b, hk, s), f32)
     ks = ones if k_scale is None else k_scale.astype(f32)
@@ -196,8 +211,11 @@ def _band_attention(q, k, v, block_size, window, k_scale, v_scale):
                 "bkgqs,bksd->bkgqd", w, vj, preferred_element_type=f32)
             return o, m_new, l * corr + p.sum(axis=-1)
 
-        first = 0 if window is None else jnp.maximum(
-            i * blk - (window - 1), 0) // blk
+        if first_block is not None:
+            first = first_block[i].astype(i.dtype)
+        else:
+            first = 0 if window is None else jnp.maximum(
+                i * blk - (window - 1), 0) // blk
         o, _, l = lax.fori_loop(first, i + 1, fold, (
             jnp.zeros((b, hk, g, blk, d), f32),
             jnp.full((b, hk, g, blk), NEG_INF, f32),

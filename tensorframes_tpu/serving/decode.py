@@ -17,7 +17,12 @@ Scheduling shape, per loop iteration:
    expirer thread covers requests waiting for a free slot, so a full
    pool can never hold one past its deadline) while slots and prompt
    pages are free; each join runs one **prefill** step (the prompt
-   chunk, padded to a ladder bucket) producing the first token.
+   chunk, padded to a ladder bucket) producing the first token. Where
+   the model offers a packed prefill (``models/served.py``) and no
+   prefix cache is armed, the cold joins of one poll share one call:
+   their prompts packed on block edges, up to ``PACK_SEGMENTS`` a call
+   and the packed ladder's top bucket, one dispatch and one fetch of
+   their first tokens.
 2. **decode** — one batched single-token step over every running slot,
    padded to the slot-count bucket ladder. A slot that needs a new KV
    page and finds the pool empty triggers **preemption**: the
@@ -35,7 +40,8 @@ Zero-steady-state-compile contract: both phases dispatch through
 ``aot_jit`` at shapes drawn from ONE ladder —
 ``compilecache.decode_warmup_grid`` (slot-count buckets for decode,
 prompt-length buckets for prefill, both delegating to
-``serving_row_buckets``) — and ``start()`` warms every point of that
+``serving_row_buckets``, or the packed call's total-token ladder where
+the engine packs) — and ``start()`` warms every point of that
 grid, so a warmed engine sustains any join/leave mix without touching
 XLA. Decode is greedy (argmax inside the step executable): determinism
 is what makes preemption-replay and the batched-vs-solo bit-identity
@@ -90,6 +96,10 @@ from .kvpool import PagedKVPool, PoolExhaustedError
 logger = get_logger(__name__)
 
 __all__ = ["DecodeConfig", "DecodeEngine", "prefix_cache_events"]
+
+#: prompts one packed prefill call holds at most (a poll of the cell's
+#: traffic joins about seven)
+PACK_SEGMENTS = 16
 
 # Prefix-cache ineligibility evidence for lint_plan's TFG113 rule: one
 # entry per (endpoint, reason) the first time it arises, bounded. The
@@ -239,9 +249,18 @@ class DecodeEngine:
             extra_pages={k.name: 1 + cfg.max_slots * k.entries
                          for k in self._kinds[1:]},
         )
-        grid = decode_warmup_grid(cfg.max_slots, cfg.max_prompt_len)
+        # a poll's cold joins share one packed prefill where the model
+        # offers the program and no prefix cache routes joins one by one
+        self._pack = model.packed_prefill is not None \
+            and not cfg.prefix_cache
+        grid = decode_warmup_grid(
+            cfg.max_slots, cfg.max_prompt_len,
+            pack_block=model.pack_block if self._pack else None,
+        )
         self._slot_buckets = grid["decode"]
+        # the packed call's total-token ladder where the engine packs
         self._prefill_buckets = grid["prefill"]
+        self._pack_segments = min(PACK_SEGMENTS, cfg.max_slots)
         # Every program that returns the pool takes it DONATED
         # (argument 1 of the model steps, 0 of the page ops): the KV
         # write happens in the resident buffers, and the columns handed
@@ -253,6 +272,10 @@ class DecodeEngine:
             model.prefill,
             label=f"decode.prefill[{name}]", donate_argnums=(1,),
         )
+        self._packed_prefill = aot_jit(
+            model.packed_prefill,
+            label=f"decode.packed_prefill[{name}]", donate_argnums=(1,),
+        ) if self._pack else None
         self._step = aot_jit(
             model.step,
             label=f"decode.step[{name}]", donate_argnums=(1,),
@@ -499,7 +522,16 @@ class DecodeEngine:
         pool = self._pool
         null = pool.null_table()
         maxp = pool.max_pages_per_seq
+        segs = self._pack_segments
         for tb in self._prefill_buckets:
+            if self._pack:
+                # every segment empty: all rows write the null page
+                pool.columns, _ = self._packed_prefill(
+                    self.params, pool.columns, np.zeros(tb, np.int32),
+                    np.zeros(segs, np.int32), np.zeros(segs, np.int32),
+                    np.zeros((segs, maxp), np.int32),
+                )
+                continue
             pool.columns, _ = self._prefill(
                 self.params, pool.columns, np.zeros(tb, np.int32),
                 np.int32(1), *pool.null_tables(),
@@ -814,8 +846,11 @@ class DecodeEngine:
             if _events.TRACER.enabled:
                 self._phase("decode.admit", polled=len(polled))
             self._in_hand = polled
-            for req in polled:
-                self._join(req)
+            if self._pack:
+                self._join_packed(polled)
+            else:
+                for req in polled:
+                    self._join(req)
             self._in_hand = ()
             if any(s is not None for s in self._slots):
                 self._decode_step()
@@ -909,6 +944,18 @@ class DecodeEngine:
             "page_size": int(self.config.page_size),
         })
 
+    def _note_repeat(self, prompt: np.ndarray, plen: int) -> None:
+        # evidence only on an OBSERVED repeat: a fresh prompt whose first
+        # page was already prefilled by an earlier fresh request is work
+        # the prefix cache would have shared — an engine that never sees
+        # overlap has nothing to gain and records nothing
+        if plen > self.config.page_size:
+            fp = prompt[:self.config.page_size].tobytes()
+            if fp in self._seen_first_pages:
+                self._note_prefix_ineligible("store_unarmed", plen)
+            elif len(self._seen_first_pages) < 512:
+                self._seen_first_pages.add(fp)
+
     def _prefill_seq(self, seq: int, prompt: np.ndarray, plen: int,
                      resumed: bool) -> Tuple[int, int, str]:
         """Write the prompt's KV for a fresh sequence and produce its
@@ -921,16 +968,8 @@ class DecodeEngine:
         covered = 0
         cow = None
         if not self._prefix_cache:
-            # evidence only on an OBSERVED repeat: a prompt whose first
-            # page was already prefilled by an earlier fresh request is
-            # work the cache would have shared — an engine that never
-            # sees overlap has nothing to gain and records nothing
-            if not resumed and plen > self.config.page_size:
-                fp = prompt[:self.config.page_size].tobytes()
-                if fp in self._seen_first_pages:
-                    self._note_prefix_ineligible("store_unarmed", plen)
-                elif len(self._seen_first_pages) < 512:
-                    self._seen_first_pages.add(fp)
+            if not resumed:
+                self._note_repeat(prompt, plen)
         elif resumed:
             # a replay-resumed join must reproduce its recorded tokens
             # against the page state that existed at first admission;
@@ -1020,6 +1059,7 @@ class DecodeEngine:
                 cat="serving",
             )
         m.DECODE_STEPS["prefill"].inc()
+        m.DECODE_PREFILL_SEGMENTS.inc()
         if self._prefix_cache and not resumed:
             # publish this prompt's freshly written FULL pages so later
             # requests can share them (no-op on total overlap; stops at
@@ -1034,10 +1074,10 @@ class DecodeEngine:
             )
         return first, len(hit_pages), path
 
-    def _join(self, req: _Request) -> None:
-        # the deadline and the wait read the clock, traced or not; the
-        # traced span starts at _t_mark, where the phase before it ended
-        now = time.perf_counter()
+    def _joinable(self, req: _Request, now: float) -> bool:
+        """False where the request is answered or seated already: its
+        deadline passed between the poll and here, or its swapped pages
+        came back (a swap-in is a join of its own)."""
         if req.deadline is not None and req.deadline <= now:
             # lost the race with the expirer between poll and here
             m.DEADLINE_EXPIRED.inc()
@@ -1047,12 +1087,20 @@ class DecodeEngine:
             ))
             self._resume.pop(req, None)
             self._drop_swap(req)
-            return
+            return False
         if self._swap_store is not None and req in self._swap:
             if self._swap_in(req, self._swap.pop(req), now):
-                return
+                return False
             # counted fallback: the replay data kept alongside the
-            # snapshot resumes it through the recompute path below
+            # snapshot resumes it through the recompute path
+        return True
+
+    def _join(self, req: _Request) -> None:
+        # the deadline and the wait read the clock, traced or not; the
+        # traced span starts at _t_mark, where the phase before it ended
+        now = time.perf_counter()
+        if not self._joinable(req, now):
+            return
         prompt = req.feeds["prompt"]
         plen = int(prompt.shape[0])
         seq = self._next_seq
@@ -1061,6 +1109,101 @@ class DecodeEngine:
         first, prefix_pages, path = self._prefill_seq(
             seq, prompt, plen, resumed=bool(replay)
         )
+        self._seat(req, seq, first, replay, now, prefix_pages)
+        if _events.TRACER.enabled:
+            args = {"seq": seq, "prompt_len": plen,
+                    "resumed": bool(replay), "path": path,
+                    "waited_s": round(now - req.t_submit, 6)}
+            if req.trace_id:
+                args["request_id"] = req.trace_id
+            self._phase("decode.join", **args)
+
+    def _join_packed(self, polled: Sequence[_Request]) -> None:
+        """Join one poll's requests with their prompts packed into as few
+        prefill calls as fit: each prompt's pages allocated and its rows
+        placed on the next block edge, in arrival order, until a call
+        holds ``PACK_SEGMENTS`` prompts or the next would pass the
+        ladder's top bucket; then one dispatch and one fetch a call."""
+        now = time.perf_counter()
+        block = self.model.pack_block
+        top = self._prefill_buckets[-1]
+        calls: List[List[tuple]] = [[]]
+        rows = 0
+        for req in polled:
+            if not self._joinable(req, now):
+                continue
+            prompt = req.feeds["prompt"]
+            plen = int(prompt.shape[0])
+            replay = self._resume.pop(req, None)
+            if not replay:
+                self._note_repeat(prompt, plen)
+            seq = self._next_seq
+            self._next_seq += 1
+            for kind, n in self._pool.demand(plen).items():
+                self._pool.alloc(seq, n, kind)
+            span = -(-plen // block) * block
+            if calls[-1] and (len(calls[-1]) == self._pack_segments
+                              or rows + span > top):
+                calls.append([])
+                rows = 0
+            calls[-1].append((req, seq, prompt, replay, rows))
+            rows += span
+        for call in calls:
+            if call:
+                self._prefill_packed(call, now)
+
+    def _prefill_packed(self, call: List[tuple], now: float) -> None:
+        """One packed prefill call over ``call``'s ``(request, seq,
+        prompt, replay, first row)`` entries, then each request seated."""
+        segs = self._pack_segments
+        _req, _seq, prompt, _replay, at = call[-1]
+        tb = next(b for b in self._prefill_buckets if b >= at + len(prompt))
+        tokens = np.zeros(tb, np.int32)
+        start = np.zeros(segs, np.int32)
+        length = np.zeros(segs, np.int32)
+        tables = np.zeros((segs, self._pool.max_pages_per_seq), np.int32)
+        for b, (_req, seq, prompt, _replay, at) in enumerate(call):
+            tokens[at:at + len(prompt)] = prompt
+            start[b], length[b] = at, len(prompt)
+            tables[b] = self._pool.table(seq)
+        tracing = _events.TRACER.enabled
+        t_disp = time.perf_counter() if tracing else 0.0
+        cols, fd = self._packed_prefill(
+            self.params, self._pool.columns, tokens, start, length, tables,
+        )
+        self._pool.columns = cols
+        firsts = np.asarray(fd)
+        if tracing:
+            # the program's dispatch through the first tokens' arrival on
+            # the host: the device's share of decode.join
+            _events.TRACER.emit_complete(
+                "decode.prefill", t_disp, time.perf_counter() - t_disp,
+                args={"endpoint": self.name, "path": "packed",
+                      "bucket": tb, "segments": len(call)},
+                cat="serving",
+            )
+        m.DECODE_STEPS["prefill"].inc()
+        m.DECODE_PREFILL_SEGMENTS.inc(len(call))
+        for b, (req, seq, _prompt, replay, _at) in enumerate(call):
+            self._seat(req, seq, int(firsts[b]), replay, now, 0)
+        if tracing:
+            used = int(length.sum())
+            args = {"joins": len(call), "tokens": used, "bucket": tb,
+                    "padded": tb - used, "path": "packed",
+                    "waited_s": round(max(now - e[0].t_submit
+                                          for e in call), 6)}
+            rids = [e[0].trace_id for e in call if e[0].trace_id]
+            if rids:
+                args["request_ids"] = rids
+            self._phase("decode.join", **args)
+
+    def _seat(self, req: _Request, seq: int, first: int,
+              replay: Optional[List[int]], now: float,
+              prefix_pages: int) -> None:
+        """Put a prefilled sequence into a free slot with its first token
+        (a replay checks it against the recorded one instead of counting
+        it), and finish it at once if it wanted one token."""
+        prompt = req.feeds["prompt"]
         self._join_counter += 1
         s = _Seq(req, seq, prompt, int(req.feeds["new"]),
                  self._join_counter, now - req.t_submit)
@@ -1086,19 +1229,12 @@ class DecodeEngine:
         m.DECODE_SLOTS.inc()
         _flight.record(
             "serving.decode.join", endpoint=self.name, seq=seq,
-            prompt_len=plen, new_tokens=s.want,
+            prompt_len=int(prompt.shape[0]), new_tokens=s.want,
             resumed=bool(replay), prefix_pages=prefix_pages,
             waited_s=round(s.waited, 6),
         )
         if len(s.generated) >= s.want:
             self._finish(s)
-        if _events.TRACER.enabled:
-            args = {"seq": seq, "prompt_len": plen,
-                    "resumed": bool(replay), "path": path,
-                    "waited_s": round(s.waited, 6)}
-            if req.trace_id:
-                args["request_id"] = req.trace_id
-            self._phase("decode.join", **args)
 
     def _swap_in(self, req: _Request, snap: Dict[str, object],
                  now: float) -> bool:

@@ -29,6 +29,7 @@ __all__ = [
     "REQUEST_LATENCY", "QUEUE_WAIT", "DISPATCH_SECONDS",
     "DEADLINE_EXPIRED", "DISPATCH_ERRORS", "rejected",
     "DECODE_PHASES", "DECODE_TOKENS", "DECODE_STEPS", "DECODE_TTFT",
+    "DECODE_PREFILL_SEGMENTS",
     "DECODE_ATTN_PAGES_WALKED", "DECODE_ATTN_PAGES_GRID",
     "DECODE_SLOTS", "DECODE_FREE_PAGES", "DECODE_PREEMPTIONS",
     "DECODE_FREE_KIND_PAGES", "DECODE_PAGE_KINDS", "page_kind_gauges",
@@ -155,13 +156,20 @@ DECODE_TOKENS = _counter(
 DECODE_STEPS: Dict[str, Counter] = {
     p: _counter(
         "tftpu_decode_steps_total",
-        "Engine step dispatches by phase (prefill = one sequence's "
-        "prompt chunk, decode = one batched token step over the "
-        "running slots)",
+        "Engine step dispatches by phase (prefill = one prefill "
+        "program's call, one prompt or a packed call of several, "
+        "decode = one batched token step over the running slots)",
         labels={"phase": p},
     )
     for p in DECODE_PHASES
 }
+DECODE_PREFILL_SEGMENTS = _counter(
+    "tftpu_decode_prefill_segments_total",
+    "Prompts the prefill dispatches carried (one a dispatch, or each "
+    "prompt of a packed prefill): over tftpu_decode_steps_total"
+    "{phase=prefill}, the prompts a prefill dispatch packs",
+    labels={"phase": "prefill"},
+)
 DECODE_ATTN_PAGES_WALKED = _counter(
     "tftpu_decode_attn_pages_walked_total",
     "Page-table entries covered by the chunks the decode-attention "

@@ -316,3 +316,53 @@ def test_flash_kernel_failure_surfaces_on_tpu(monkeypatch):
     # an ineligible shape is blockwise by choice, not by rescue
     q = jnp.zeros((1, 2, 100, 64), jnp.float32)
     assert att.flash_attention(q, q, q).shape == q.shape
+
+
+@pytest.mark.parametrize("blk,lengths,seq", [
+    (8, (13, 5, 8, 1), 48),       # a prompt over two blocks, a one-row one
+    (16, (40, 16, 3), 96),        # three blocks long; padding blocks after
+    (4, (4, 4, 4, 4), 16),        # every block its own segment, no padding
+])
+def test_blockwise_block_bounds_equal_block_diagonal_dense(blk, lengths, seq):
+    """Packed sequences, each starting on a block edge: with each query
+    block's first key block given, every row attends causally within its
+    own sequence's blocks (a block outside every sequence within itself),
+    as a dense causal reference masked to that block-diagonal does, for
+    grouped heads and int8 keys and values with per-position scales."""
+    rng = np.random.default_rng(blk)
+    hq, hk, d = 4, 2, 8
+    q = jnp.asarray(rng.normal(size=(1, hq, seq, d)), jnp.float32)
+    k = jnp.asarray(rng.integers(-127, 128, (1, hk, seq, d)), jnp.int8)
+    v = jnp.asarray(rng.integers(-127, 128, (1, hk, seq, d)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.01, 0.02, (1, hk, seq)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.01, 0.02, (1, hk, seq)), jnp.float32)
+    group = np.arange(seq) // blk              # padding: its own block
+    first = np.arange(seq // blk)
+    at = 0
+    for n in lengths:
+        span = -(-n // blk) * blk
+        group[at:at + span] = seq + at
+        first[at // blk:(at + span) // blk] = at // blk
+        at += span
+    got = att.blockwise_attention(
+        q, k, v, causal=True, block_size=blk, k_scale=ks, v_scale=vs,
+        first_block=jnp.asarray(first, jnp.int32))
+    kk = jnp.repeat(k.astype(jnp.float32) * ks[..., None], hq // hk, axis=1)
+    vv = jnp.repeat(v.astype(jnp.float32) * vs[..., None], hq // hk, axis=1)
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, kk) / np.sqrt(d)
+    i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    mask = (j <= i) & (group[:, None] == group[None, :])
+    want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(
+        jnp.where(mask, sc, -1e30), axis=-1), vv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=0)
+
+
+def test_blockwise_block_bounds_need_whole_blocks_and_no_window():
+    q = jnp.zeros((1, 2, 12, 4), jnp.float32)
+    with pytest.raises(ValueError, match="whole number of blocks"):
+        att.blockwise_attention(q, q, q, causal=True, block_size=8,
+                                first_block=jnp.zeros(2, jnp.int32))
+    with pytest.raises(ValueError, match="causal"):
+        att.blockwise_attention(q, q, q, causal=False, block_size=4,
+                                first_block=jnp.zeros(3, jnp.int32))

@@ -455,7 +455,9 @@ def test_join_failure_answers_the_request_in_hand(model):
     def broken(*args):
         raise RuntimeError("prefill broke (test)")
 
-    eng._prefill = broken
+    # the transformer's joins go through the packed prefill
+    assert eng._packed_prefill is not None
+    eng._prefill = eng._packed_prefill = broken
     eng.start()
     try:
         # one request: the failure closes admission, so a second submit
@@ -1133,3 +1135,196 @@ def test_kvswap_prefix_metrics_preregistered():
         "tftpu_prefix_cache_evictions_total",
     ):
         assert want in names, f"{want} not pre-registered"
+
+
+# ---------------------------------------------------------------------------
+# Packed prefill: a poll's cold joins share one call
+# ---------------------------------------------------------------------------
+
+PACK_PAGE = 8
+
+
+def _packed(model, T, placed, segs=4):
+    """Run the packed prefill on a fresh pool: ``placed`` is a list of
+    ``(prompt, first row, pages)``. Returns (pool, first tokens)."""
+    import jax
+
+    cfg, params = model
+    served = cfg.served_model(PACK_PAGE, 24)
+    maxp = served.kinds[0].entries
+    tokens = np.zeros(T, np.int32)
+    start = np.zeros(segs, np.int32)
+    length = np.zeros(segs, np.int32)
+    tables = np.zeros((segs, maxp), np.int32)
+    for b, (prompt, at, pages) in enumerate(placed):
+        tokens[at:at + len(prompt)] = prompt
+        start[b], length[b] = at, len(prompt)
+        tables[b, :len(pages)] = pages
+    pool = gen.init_paged_kv(cfg, 32, PACK_PAGE)
+    pool, first = jax.jit(served.packed_prefill)(
+        params, pool, tokens, start, length, tables)
+    return {k: np.asarray(v) for k, v in pool.items()}, np.asarray(first)
+
+
+@pytest.mark.parametrize("T,at", [(256, 64), (192, 128), (64, 0)])
+def test_packed_prefill_is_the_same_wherever_a_prompt_sits(model, T, at):
+    """A prompt packed alone at row 0 and the same prompt among
+    neighbours at another block edge, in the same bucket or another, give
+    bit for bit the same first token and the same bytes on its pages."""
+    cfg, _ = model
+    a, p, c = _prompts(3, 5, 16, seed=71, vocab=cfg.vocab_size)
+    block = cfg.served_model(PACK_PAGE, 24).pack_block
+    mine = [3, 9]                               # p's pages
+    alone_pool, alone = _packed(model, 64, [(p, 0, mine)])
+    among = [(p, at, mine)]
+    if at:
+        among.insert(0, (a, at - block, [4]))
+    if at + block < T:
+        among.append((c, at + block, [11, 12]))
+    pool, firsts = _packed(model, T, among)
+    assert firsts[[s[1] for s in among].index(at)] == alone[0]
+    for name in pool:
+        assert np.array_equal(pool[name][mine], alone_pool[name][mine]), name
+
+
+def test_packed_first_tokens_match_the_one_sequence_prefill(model):
+    """The packed program's first tokens are the one-sequence prefill's,
+    and the KV it writes on the prompts' positions agrees to the int8
+    rounding (codes within one step, scales to float tolerance)."""
+    import jax
+
+    cfg, params = model
+    served = cfg.served_model(PACK_PAGE, 24)
+    maxp = served.kinds[0].entries
+    prompts = _prompts(4, 1, 16, seed=72, vocab=cfg.vocab_size)
+    placed, pages = [], 1
+    for b, p in enumerate(prompts):
+        n = -(-len(p) // PACK_PAGE)
+        placed.append((p, b * served.pack_block,
+                       list(range(pages, pages + n))))
+        pages += n
+    pool, firsts = _packed(model, 256, placed)
+    one = jax.jit(served.prefill)
+    ref = gen.init_paged_kv(cfg, 32, PACK_PAGE)
+    for b, (p, _at, pg) in enumerate(placed):
+        table = np.zeros(maxp, np.int32)
+        table[:len(pg)] = pg
+        tokens = np.zeros(16, np.int32)
+        tokens[:len(p)] = p
+        ref, first = one(params, ref, tokens, np.int32(len(p)), table)
+        assert int(first) == int(firsts[b])
+        rows = np.arange(len(p))
+        page, off = np.asarray(pg)[rows // PACK_PAGE], rows % PACK_PAGE
+        for name in ("k", "v"):
+            diff = (pool[name][page, :, off].astype(int)
+                    - np.asarray(ref[name])[page, :, off].astype(int))
+            assert np.abs(diff).max() <= 1, name
+            np.testing.assert_allclose(
+                pool[name + "_scale"][page, :, off],
+                np.asarray(ref[name + "_scale"])[page, :, off], rtol=1e-5)
+
+
+@pytest.mark.parametrize("segments,n,calls", [
+    (16, 4, 2),     # a 128-row top bucket: two 64-row blocks a call
+    (16, 2, 1),
+    (1, 3, 3),      # one prompt a call
+])
+def test_a_poll_of_cold_joins_is_prefilled_in_few_calls(
+        model, monkeypatch, segments, n, calls):
+    """An engine whose poll returns ``n`` cold requests makes as many
+    prefill dispatches as its calls need: ``PACK_SEGMENTS`` prompts, and
+    the packed ladder's top bucket, a call. Read off the two counters;
+    each request's first token is the dense oracle's."""
+    from tensorframes_tpu.serving import decode as dec
+
+    monkeypatch.setattr(dec, "PACK_SEGMENTS", segments)
+    cfg, params = model
+    eng = DecodeEngine("t_packed_poll", cfg, params, DecodeConfig(
+        max_slots=4, page_size=PACK_PAGE, max_prompt_len=16,
+        max_new_tokens=4, warmup=False,
+    ))
+    assert eng._prefill_buckets == [64, 128]
+    prompts = _prompts(n, 3, 16, seed=73, vocab=cfg.vocab_size)
+    eng._admission.start()
+    try:
+        for p in prompts:
+            eng._admission.offer(eng.validate_feeds({"prompt": p}), 1, None)
+        polled = eng._admission.poll(4, can_take=eng._admit_budget())
+        assert len(polled) == n
+        steps0 = sm.DECODE_STEPS["prefill"].value
+        segs0 = sm.DECODE_PREFILL_SEGMENTS.value
+        eng._join_packed(polled)
+        assert sm.DECODE_STEPS["prefill"].value - steps0 == calls
+        assert sm.DECODE_PREFILL_SEGMENTS.value - segs0 == n
+        seated = [s for s in eng._slots if s is not None]
+        assert [s.prompt.tolist() for s in seated] == [
+            p.tolist() for p in prompts]
+        for s, p in zip(seated, prompts):
+            assert s.generated == [int(_reference(model, p, 1)[0, 0])]
+    finally:
+        eng._admission.stop(drain=False, timeout=10)
+        eng.stop()
+
+
+def test_preempted_requests_replay_through_the_packed_prefill(model):
+    """Under an undersized pool, preempted requests rejoin through the
+    packed prefill with their recorded tokens: the engine checks each
+    replayed token (a divergence would fail the request), and every
+    answer is the never-preempted oracle's."""
+    from tensorframes_tpu.observability import flight
+
+    cfg, params = model
+    eng = DecodeEngine("t_packed_replay", cfg, params, DecodeConfig(
+        max_slots=4, page_size=8, num_pages=5,
+        max_prompt_len=16, max_new_tokens=8,
+    ))
+    assert eng._packed_prefill is not None
+    eng.start()
+    try:
+        pre0 = sm.DECODE_PREEMPTIONS.value
+        err0 = sm.DISPATCH_ERRORS.value
+        segs0 = sm.DECODE_PREFILL_SEGMENTS.value
+        prompts = _prompts(6, 10, 16, seed=74, vocab=cfg.vocab_size)
+        futs = [eng.submit({"prompt": p}) for p in prompts]
+        outs = [f.result(600)["tokens"] for f in futs]
+        preempted = sm.DECODE_PREEMPTIONS.value - pre0
+        assert preempted > 0
+        # every join, first or replayed, went through the packed program
+        assert sm.DECODE_PREFILL_SEGMENTS.value - segs0 == 6 + preempted
+        assert sm.DISPATCH_ERRORS.value == err0
+        for p, o in zip(prompts, outs):
+            assert np.array_equal(o, _reference(model, p, 8))
+    finally:
+        eng.stop(drain=True, timeout=300)
+    assert not [r for r in flight.RECORDER.records()
+                if r.get("kind") == "serving.decode.replay_divergence"
+                and r.get("endpoint") == "t_packed_replay"]
+    eng.pool.check()
+
+
+def test_a_prefix_cache_engine_joins_one_prompt_a_call(model):
+    """An armed prefix cache routes joins one by one (hits, suffixes,
+    copy-on-extend): that engine does not pack and warms the
+    one-sequence ladder; the segments counter still grows by one a
+    dispatch."""
+    from tensorframes_tpu.compilecache import serving_row_buckets
+
+    cfg, params = model
+    eng = DecodeEngine("t_prefix_nopack", cfg, params, DecodeConfig(
+        max_slots=2, page_size=8, max_prompt_len=16, max_new_tokens=4,
+        prefix_cache=True,
+    ))
+    assert eng._packed_prefill is None
+    assert eng._prefill_buckets == serving_row_buckets(16)
+    eng.start()
+    try:
+        steps0 = sm.DECODE_STEPS["prefill"].value
+        segs0 = sm.DECODE_PREFILL_SEGMENTS.value
+        p = _prompts(1, 9, 15, seed=75, vocab=cfg.vocab_size)[0]
+        for _ in range(2):
+            out = eng.call({"prompt": p}, timeout=300)["tokens"]
+            np.testing.assert_array_equal(out, _reference(model, p, 4))
+        assert sm.DECODE_STEPS["prefill"].value - steps0 == 2
+        assert sm.DECODE_PREFILL_SEGMENTS.value - segs0 == 2
+    finally:
+        eng.stop(drain=True, timeout=120)

@@ -229,6 +229,99 @@ def test_the_seam_leaves_the_transformer_s_programs_as_they_were(
         assert hashlib.sha256(text.encode()).hexdigest() == want
 
 
+@pytest.mark.parametrize("bucket", [1024, 4096])
+def test_packed_prefill_writes_the_pool_in_place(cell, mosaic, bucket):
+    """The packed prefill at the ends of the 384-slot cell's ladder, 16
+    segments, over the 8,193 pages: no pool-sized copy, every column
+    aliased, temporaries far under one pool."""
+    cfg, params, pool, i32 = _big_cell(cell)
+    served = cfg.served_model(PAGE, MAX_PAGES * PAGE)
+    compiled = jax.jit(served.packed_prefill, donate_argnums=(1,)).lower(
+        params, pool, i32(bucket), i32(16), i32(16), i32(16, MAX_PAGES)
+    ).compile()
+    _assert_in_place(compiled, pool, f"jit_packed_prefill[{bucket}]",
+                     BIG_PAGES)
+
+
+def test_the_packing_engine_warms_the_packed_ladder_in_place_of_the_one():
+    """The 384-slot cell's engine packs: its grid's prefill ladder is the
+    packed total-token ladder, shorter than the one-sequence ladder it
+    replaces (5 programs for 8), on 64-row block edges, its top bucket
+    four of the largest prompt's block-rounded rows and more."""
+    from tensorframes_tpu.compilecache import (
+        decode_warmup_grid,
+        packed_prefill_buckets,
+        serving_row_buckets,
+    )
+
+    one = decode_warmup_grid(BIG_SLOTS - 128, 896)
+    packed = decode_warmup_grid(BIG_SLOTS - 128, 896, pack_block=64)
+    assert one["prefill"] == serving_row_buckets(896) == [
+        8, 16, 32, 64, 128, 256, 512, 1024]
+    assert packed["prefill"] == packed_prefill_buckets(896, 64) == [
+        1024, 1536, 2048, 3072, 4096]
+    assert packed["decode"] == one["decode"]
+    assert len(packed["prefill"]) <= len(one["prefill"])
+    assert all(b % 64 == 0 for b in packed["prefill"])
+    assert packed["prefill"][-1] >= 4 * 896
+
+
+# sha256 of str(jaxpr) of the sparse-expert decoder's prefill (1,024-row
+# bucket) and step (16 slots) at published widths, traced with the
+# kernels, as the commit before the packed prefill traced them: the
+# attention it shares with the packed program traces as it did
+SPARSE_PREFILL_JAXPR = (
+    "06a1a9f7f62d30e1e9b32f8c980a31739c77495053f6fcd1aa824c36e4670d0c")
+SPARSE_STEP_JAXPR = (
+    "dfa388a9953ab7f4aa96f17f2851f2eff76401dc7fb576ea1b2e707df071ff64")
+
+
+def _sparse_cell():
+    from tensorframes_tpu.models import sparse_decoder as sd
+
+    cfg = sd.SparseDecoderConfig(
+        vocab_size=98304, hidden=2304,
+        layer_types=("sliding", "full"), num_heads=32, num_kv_heads=4,
+        head_dim=128, sliding_window=1024,
+        rope_full=sd.RopeSpec(500000.0, factor=16.0, original_max=8192,
+                              attention_factor=1.2772588722239782),
+        rope_sliding=sd.RopeSpec(500000.0), num_experts=64,
+        experts_per_token=8, expert_hidden=896, max_seq_len=4608)
+    layer = {k: jax.ShapeDtypeStruct(
+        s, jnp.float32 if "norm" in k else jnp.bfloat16)
+        for k, s in sd.layer_shapes(cfg).items()}
+    params = {
+        "embed": jax.ShapeDtypeStruct((98304, 2304), jnp.bfloat16),
+        "final_norm": jax.ShapeDtypeStruct((2304,), jnp.float32),
+        "head": jax.ShapeDtypeStruct((2304, 98304), jnp.bfloat16),
+        "layers": [layer] * cfg.num_layers}
+    return cfg, params
+
+
+def test_the_sparse_decoder_s_programs_trace_as_they_did(mosaic):
+    """The sparse-expert decoder offers no packed prefill: its engine
+    joins one prompt a dispatch, and its prefill and step jaxprs hash as
+    before the packed program shared its attention."""
+    import hashlib
+
+    cfg, params = _sparse_cell()
+    served = cfg.served_model(16, 4608)
+    assert served.packed_prefill is None
+    pool = jax.eval_shape(lambda: served.init_pool(
+        {"full": 15201, "window": 1 + 128 * 65}))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    for want, fn, args in (
+            (SPARSE_PREFILL_JAXPR, served.prefill,
+             (i32(1024), i32(), i32(288), i32(65))),
+            (SPARSE_STEP_JAXPR, served.step,
+             (i32(16), i32(16), i32(16, 288), i32(16, 65)))):
+        text = str(jax.make_jaxpr(fn)(params, pool, *args))
+        assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
 def test_sparse_decoder_step_compiles_in_place_at_published_widths(
         one_chip, mosaic):
     """The sparse-expert decoder's step at the widths its cell serves

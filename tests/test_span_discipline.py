@@ -152,12 +152,18 @@ def test_engine_thread_spans_nest_and_cover_the_loop(model, engine, tracing):
             assert inside(e, joins + commits)
         elif e["name"] == "decode.step.enqueue":
             assert inside(e, steps)
-    assert len(prefills) == 12
+    # the transformer packs a poll's joins: one join and one prefill a
+    # packed call, the twelve prompts counted on both
+    assert len(prefills) == len(joins) <= 12
+    assert sum(e["args"]["segments"] for e in prefills) == 12
+    assert sum(e["args"]["joins"] for e in joins) == 12
     assert len(steps) == sum(
         e["name"] == "decode.step.enqueue" for e in spans)
     assert any(e["name"] == "decode.finish" and inside(e, joins)
                for e in spans), "the one-token request finishes in its join"
-    assert all(e["args"]["path"] == "cold" and "waited_s" in e["args"]
+    assert all(e["args"]["path"] == "packed"
+               and e["args"]["bucket"] == e["args"]["tokens"]
+               + e["args"]["padded"] and e["args"]["waited_s"] >= 0
                for e in joins)
     assert all({"slots", "bucket"} <= set(e["args"]) for e in steps)
     prepares = [e for e in spans if e["name"] == "decode.prepare"]

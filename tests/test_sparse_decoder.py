@@ -24,6 +24,7 @@ warmed engine compiles nothing; the expert matmuls' kernel
 the engine counts its dispatches.
 """
 
+import dataclasses
 import math
 import os
 import sys
@@ -593,6 +594,31 @@ def test_window_pages_never_exceed_the_ring_and_preemption_replays(model):
     eng.pool.check()
 
 
+def test_the_engine_prefills_one_prompt_a_dispatch(model):
+    """The block offers no packed prefill: its engine warms the
+    one-sequence ladder and makes one prefill dispatch a join, so the
+    segments counter grows by one a dispatch."""
+    from tensorframes_tpu.compilecache import serving_row_buckets
+
+    cfg, params = model
+    eng = DecodeEngine("sparse_solo", cfg, params, DecodeConfig(
+        max_slots=4, page_size=PAGE, max_prompt_len=24, max_new_tokens=4))
+    assert eng.model.packed_prefill is None and eng._packed_prefill is None
+    assert eng._prefill_buckets == serving_row_buckets(24)
+    eng.start()
+    try:
+        steps0 = sm.DECODE_STEPS["prefill"].value
+        segs0 = sm.DECODE_PREFILL_SEGMENTS.value
+        futs = [eng.submit({"prompt": p, "max_new_tokens": 4})
+                for p in _prompts(6, 3, 24, seed=13)]
+        for f in futs:
+            f.result(300)
+        assert sm.DECODE_STEPS["prefill"].value - steps0 == 6
+        assert sm.DECODE_PREFILL_SEGMENTS.value - segs0 == 6
+    finally:
+        eng.stop(drain=True, timeout=120)
+
+
 def test_register_decode_refuses_tiers_the_model_has_no_programs_for(model):
     cfg, params = model
     srv = Server()
@@ -614,6 +640,9 @@ def test_register_decode_refuses_tiers_the_model_has_no_programs_for(model):
     eng.stop()
     with pytest.raises(TypeError, match="served_model"):
         DecodeEngine("d", object(), params, DecodeConfig())
+    # a packed prefill takes one table a prompt: two kinds cannot give it
+    with pytest.raises(ValueError, match="packed_prefill"):
+        dataclasses.replace(served, packed_prefill=served.prefill)
 
 
 def test_new_decode_metrics_preregistered():
