@@ -35,11 +35,18 @@ span END, so nesting is reconstructed by time containment per thread;
 a span that never exits (crash mid-body) leaves no partial event. An
 async pair is recorded whole at its end too: a full ring drops both
 halves (counted), never one.
+
+While a ``jax.profiler`` capture runs, a span opened through
+:meth:`Tracer.mirrored` (and whatever a site opens with :func:`native`)
+is also a profiler ``TraceMe`` of the same name: the capture's
+``/host:CPU`` plane then carries it on the clock of the device planes,
+so one file lines up the host's spans with the chip's programs.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import os
@@ -61,6 +68,8 @@ __all__ = [
     "active",
     "clear",
     "span",
+    "native",
+    "close_native",
     "instant",
     "to_chrome_trace",
     "save",
@@ -233,6 +242,16 @@ class Tracer:
             "ts": _us(t0_perf + dur_s), "pid": pid, "tid": tid,
         })
 
+    def mirrored(self, name: str, cat: str = "tftpu", **args: Any):
+        """Trace the body as one complete event, and, while a
+        ``jax.profiler`` capture runs, as a profiler ``TraceMe`` of the
+        same name on the capture's own clock (:func:`native`). Entering
+        gives the span object, whose ``args`` may still be filled in
+        the body; disabled, it gives ``None`` and reads no clock."""
+        if not self.enabled:
+            return _NOT_MIRRORED
+        return _Mirrored(self, name, cat, args)
+
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "tftpu", **args: Any) -> Iterator[None]:
         """Trace the body as one complete event (no-op when disabled)."""
@@ -316,6 +335,59 @@ class Tracer:
             f"trace_{_context.run_id()}_p{_context.process_index()}.json",
         )
         return self.save(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _traceme_class():
+    try:
+        from jax._src.lib import _profiler
+    except Exception:  # pragma: no cover - a jaxlib without the profiler
+        return None
+    return getattr(_profiler, "TraceMe", None)
+
+
+def native(name: str):
+    """An entered profiler ``TraceMe`` named ``name`` while a
+    ``jax.profiler`` capture runs, else ``None``. The capture records it
+    on its ``/host:CPU`` plane, on the clock of the device planes beside
+    it, when the caller passes it to :func:`close_native` on the same
+    thread. Callers make one only where the tracer is enabled."""
+    cls = _traceme_class()
+    if cls is None or not cls.is_enabled():
+        return None
+    tm = cls(name)
+    tm.__enter__()
+    return tm
+
+
+def close_native(tm) -> None:
+    """End what :func:`native` opened (``None`` is a no-op)."""
+    if tm is not None:
+        tm.__exit__(None, None, None)
+
+
+class _Mirrored:
+    """The span :meth:`Tracer.mirrored` gives while tracing."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_native")
+
+    def __init__(self, tracer: Tracer, name: str, cat: str,
+                 args: Dict[str, Any]):
+        self._tracer, self.name, self.cat, self.args = tracer, name, cat, args
+
+    def __enter__(self) -> "_Mirrored":
+        self._native = native(self.name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        close_native(self._native)
+        self._tracer.emit_complete(self.name, self._t0, t1 - self._t0,
+                                   args=self.args or None, cat=self.cat)
+
+
+_NOT_MIRRORED = contextlib.nullcontext()
 
 
 #: Process-wide default tracer; the module-level helpers below and every

@@ -363,24 +363,50 @@ class DecodeEngine:
         self._next_seq = 0
         self._join_counter = 0
         # engine-thread trace state: the last phase boundary
-        # (perf_counter seconds; None while not tracing or idle)
+        # (perf_counter seconds; None while not tracing or idle), the
+        # thread's CPU time there, and the profiler TraceMe of the phase
+        # that runs from it (None unless a jax.profiler capture runs)
         self._t_mark: Optional[float] = None
+        self._cpu_mark = 0.0
+        self._native = None
+        # the thread's CPU time at the top of the last loop iteration
+        # (tftpu_decode_loop_cpu_seconds_total counts its growth)
+        self._cpu_loop = 0.0
 
-    def _phase(self, name: str, **args) -> None:
+    def _phase(self, name: str, then: Optional[str] = None,
+               **args) -> None:
         """Close the engine thread's current phase as the span ``name``:
         it runs from the last boundary to now, and now is the next
         phase's start, so the loop's phases are contiguous by
-        construction (one clock read per boundary). The first boundary
-        after tracing came on only sets the mark. Call sites sit behind
-        ``if _events.TRACER.enabled``."""
+        construction (one clock read per boundary, and one of the
+        thread's CPU time, for ``cpu_ms``). The phase's profiler
+        ``TraceMe`` closes here too, and ``then``'s opens where the next
+        phase is known at the boundary; a phase whose name is known
+        only later opens its own (:meth:`_open_phase`). The first
+        boundary after tracing came on only sets the mark. Call sites
+        sit behind ``if _events.TRACER.enabled``."""
         now = time.perf_counter()
+        cpu = time.thread_time()
+        _events.close_native(self._native)
+        # the next phase's TraceMe opens before this span is written, so
+        # that it starts as near the boundary as the clock read
+        self._native = _events.native(then) if then is not None else None
         if self._t_mark is not None:
             args["endpoint"] = self.name
+            args["cpu_ms"] = round((cpu - self._cpu_mark) * 1e3, 4)
             _events.TRACER.emit_complete(
                 name, self._t_mark, now - self._t_mark, args=args,
                 cat="serving",
             )
-        self._t_mark = now
+        self._t_mark, self._cpu_mark = now, cpu
+
+    def _open_phase(self, name: str) -> None:
+        """Open the profiler ``TraceMe`` of the phase that began at the
+        last boundary, where the loop learns which phase it is only
+        after that boundary (a join, or the prepare after the joins)."""
+        if self._native is None and self._t_mark is not None \
+                and _events.TRACER.enabled:
+            self._native = _events.native(name)
 
     def _run_step(self, *args):
         """Dispatch one batched decode step and count its kernel
@@ -391,19 +417,14 @@ class DecodeEngine:
         the caller rebinds ``self._pool.columns`` to the returned ones.
         A failure raises — the step is never rebuilt on another
         lowering."""
-        t_step = time.perf_counter()
-        out = self._step(*args)
-        dt = time.perf_counter() - t_step
+        # the host's enqueue of the step program (key walk, argument
+        # transfer, launch): a leaf inside decode.step, whose rest is
+        # the fetch of its tokens and their counting
+        with _events.TRACER.mirrored("decode.step.enqueue", cat="serving",
+                                     endpoint=self.name):
+            out = self._step(*args)
         if args[2].shape[0] > self._step_memory_bucket:
             self._note_step_memory(args)
-        if _events.TRACER.enabled:
-            # the host's enqueue of the step program (key walk, argument
-            # transfer, launch): a leaf inside decode.step, whose rest
-            # is the wait for the device
-            _events.TRACER.emit_complete(
-                "decode.step.enqueue", t_step, dt,
-                args={"endpoint": self.name}, cat="serving",
-            )
         for kernel in self._step_kernels:
             _kernels.note_dispatch(kernel, self._kernels_interpreted)
         return out
@@ -824,7 +845,7 @@ class DecodeEngine:
             ))
 
     def _loop_body(self) -> None:
-        cfg = self.config
+        self._cpu_loop = time.thread_time()
         while True:
             with self._lock:
                 stopping, drain = self._stopping, self._drain
@@ -834,10 +855,18 @@ class DecodeEngine:
                     "drain; running sequences abandoned"
                 ))
                 return
+            # the thread's CPU time: one read a turn, traced or not
+            cpu = time.thread_time()
+            m.DECODE_LOOP_CPU.inc(cpu - self._cpu_loop)
+            self._cpu_loop = cpu
             if not _events.TRACER.enabled:
                 self._t_mark = None
+                if self._native is not None:  # tracing went off mid-turn
+                    _events.close_native(self._native)
+                    self._native = None
             elif self._t_mark is None:
-                self._t_mark = time.perf_counter()
+                self._t_mark, self._cpu_mark = time.perf_counter(), cpu
+                self._native = _events.native("decode.admit")
             self._purge_resume()
             free = [i for i, s in enumerate(self._slots) if s is None]
             polled = self._admission.poll(
@@ -857,6 +886,8 @@ class DecodeEngine:
                 continue
             # idle: the nap below belongs to no phase
             self._t_mark = None
+            _events.close_native(self._native)
+            self._native = None
             if stopping and self._admission.queued_rows == 0:
                 return
             if self._admission.queued_rows > 0:
@@ -956,14 +987,15 @@ class DecodeEngine:
             elif len(self._seen_first_pages) < 512:
                 self._seen_first_pages.add(fp)
 
-    def _prefill_seq(self, seq: int, prompt: np.ndarray, plen: int,
-                     resumed: bool) -> Tuple[int, int, str]:
-        """Write the prompt's KV for a fresh sequence and produce its
-        first token through the cheapest eligible path: shared-prefix
-        suffix prefill, copy-on-extend, or cold full prefill. Returns
-        ``(first_token, shared_pages_referenced, path)``, ``path`` one of
-        ``cold`` / ``suffix`` / ``cow``."""
-        tracing = _events.TRACER.enabled
+    def _plan_prefill(self, seq: int, prompt: np.ndarray, plen: int,
+                      resumed: bool) -> Dict[str, object]:
+        """The host's build of a fresh sequence's prefill through the
+        cheapest eligible path: shared-prefix suffix prefill,
+        copy-on-extend, or cold full prefill. Allocates the sequence's
+        pages and returns what :meth:`_run_prefill` dispatches:
+        ``path`` (``cold`` / ``suffix`` / ``cow``), ``bucket``, the
+        program's host ``operands``, and the prefix hit (``hit_pages``,
+        ``covered``, ``cow``)."""
         hit_pages: List[int] = []
         covered = 0
         cow = None
@@ -990,31 +1022,24 @@ class DecodeEngine:
         if hit_pages or cow is not None:
             m.PREFIX_HITS.inc()
             self._prefix_hits += 1
+        plan: Dict[str, object] = {
+            "hit_pages": hit_pages, "covered": covered, "cow": cow}
         if cow is not None:
             # the whole remaining tail is resident in a published page:
             # copy it (never write a shared page), then teacher-force
             # only the final prompt token through the solo decode step
             # — it rewrites KV the copy already holds (deterministic,
             # identical) and yields the first-token logits
-            path, bucket = "cow", self._slot_buckets[0]
             dst = self._pool.copy_on_extend(seq, cow)
-            t_disp = time.perf_counter() if tracing else 0.0
-            self._pool.columns = self._copy_page(
-                self._pool.columns, np.int32(cow), np.int32(dst)
-            )
             sb = self._slot_buckets[0]
-            maxp = self._pool.max_pages_per_seq
             tokens = np.zeros(sb, np.int32)
             pos = np.zeros(sb, np.int32)
-            tables = np.zeros((sb, maxp), np.int32)
+            tables = np.zeros((sb, self._pool.max_pages_per_seq), np.int32)
             tokens[0] = int(prompt[plen - 1])
             pos[0] = plen - 1
             tables[0] = self._pool.table(seq)
-            cols, nxt = self._run_step(
-                self.params, self._pool.columns, tokens, pos, tables
-            )[:2]
-            self._pool.columns = cols
-            first = int(np.asarray(nxt)[0])
+            plan.update(path="cow", bucket=sb, dst=dst,
+                        operands=(tokens, pos, tables))
         elif hit_pages:
             # matched pages cover [0, covered); prefill only the suffix
             # through the gather-attending executable (its rows see the
@@ -1026,53 +1051,70 @@ class DecodeEngine:
             tb = self._prefill_bucket(tlen)
             padded = np.zeros(tb, np.int32)
             padded[:tlen] = prompt[covered:]
-            path, bucket = "suffix", tb
-            t_disp = time.perf_counter() if tracing else 0.0
-            cols, fd = self._suffix_prefill(
-                self.params, self._pool.columns, padded,
-                np.int32(covered), np.int32(tlen),
-                self._pool.table(seq),
-            )
-            self._pool.columns = cols
-            first = int(fd)
+            plan.update(path="suffix", bucket=tb, operands=(
+                padded, np.int32(covered), np.int32(tlen),
+                self._pool.table(seq)))
         else:
             for kind, n in self._pool.demand(plen).items():
                 self._pool.alloc(seq, n, kind)
             tb = self._prefill_bucket(plen)
             padded = np.zeros(tb, np.int32)
             padded[:plen] = prompt
-            path, bucket = "cold", tb
-            t_disp = time.perf_counter() if tracing else 0.0
-            cols, fd = self._prefill(
-                self.params, self._pool.columns, padded,
-                np.int32(plen), *self._pool.tables(seq),
-            )
+            plan.update(path="cold", bucket=tb, operands=(
+                padded, np.int32(plen), *self._pool.tables(seq)))
+        return plan
+
+    def _run_prefill(self, seq: int, plan: Dict[str, object]) -> int:
+        """Dispatch the planned prefill and fetch its first token."""
+        path, operands = plan["path"], plan["operands"]
+        tracer = _events.TRACER
+        # the program's dispatch through the first token's arrival on
+        # the host: the device's share of decode.join
+        with tracer.mirrored("decode.prefill", cat="serving",
+                             endpoint=self.name, seq=seq, path=path,
+                             bucket=plan["bucket"]):
+            with tracer.mirrored("decode.prefill.enqueue", cat="serving",
+                                 endpoint=self.name):
+                if path == "cow":
+                    self._pool.columns = self._copy_page(
+                        self._pool.columns, np.int32(plan["cow"]),
+                        np.int32(plan["dst"]),
+                    )
+                else:
+                    program = (self._suffix_prefill if path == "suffix"
+                               else self._prefill)
+                    cols, fd = program(
+                        self.params, self._pool.columns, *operands)
+            if path == "cow":
+                # the solo step (its own decode.step.enqueue)
+                cols, fd = self._run_step(
+                    self.params, self._pool.columns, *operands)[:2]
             self._pool.columns = cols
-            first = int(fd)
-        if tracing:
-            # the program's dispatch through the first token's arrival
-            # on the host: the device's share of decode.join
-            _events.TRACER.emit_complete(
-                "decode.prefill", t_disp, time.perf_counter() - t_disp,
-                args={"endpoint": self.name, "seq": seq, "path": path,
-                      "bucket": bucket},
-                cat="serving",
-            )
+            with tracer.mirrored("decode.prefill.fetch", cat="serving",
+                                 endpoint=self.name):
+                first = int(np.asarray(fd)[0]) if path == "cow" \
+                    else int(fd)
         m.DECODE_STEPS["prefill"].inc()
         m.DECODE_PREFILL_SEGMENTS.inc()
+        return first
+
+    def _publish(self, seq: int, prompt: np.ndarray, plen: int,
+                 resumed: bool, plan: Dict[str, object]) -> None:
+        """After a one-sequence prefill: share its prompt pages and
+        record a prefix hit."""
         if self._prefix_cache and not resumed:
             # publish this prompt's freshly written FULL pages so later
             # requests can share them (no-op on total overlap; stops at
             # chain-key collisions with another lineage)
             self._pool.publish_prefix(seq, prompt)
+        hit_pages, cow = plan["hit_pages"], plan["cow"]
         if hit_pages or cow is not None:
             _flight.record(
                 "serving.decode.prefix_hit", endpoint=self.name,
                 seq=seq, prompt_len=plen,
-                shared_pages=len(hit_pages), covered_tokens=covered,
+                shared_pages=len(hit_pages), covered_tokens=plan["covered"],
                 copy_on_extend=cow is not None,
             )
-        return first, len(hit_pages), path
 
     def _joinable(self, req: _Request, now: float) -> bool:
         """False where the request is answered or seated already: its
@@ -1101,18 +1143,26 @@ class DecodeEngine:
         now = time.perf_counter()
         if not self._joinable(req, now):
             return
+        self._open_phase("decode.join")
+        tracer = _events.TRACER
         prompt = req.feeds["prompt"]
         plen = int(prompt.shape[0])
         seq = self._next_seq
         self._next_seq += 1
         replay = self._resume.pop(req, None)
-        first, prefix_pages, path = self._prefill_seq(
-            seq, prompt, plen, resumed=bool(replay)
-        )
-        self._seat(req, seq, first, replay, now, prefix_pages)
-        if _events.TRACER.enabled:
+        with tracer.mirrored("decode.join.build", cat="serving",
+                             endpoint=self.name):
+            plan = self._plan_prefill(seq, prompt, plen,
+                                      resumed=bool(replay))
+        first = self._run_prefill(seq, plan)
+        with tracer.mirrored("decode.join.seat", cat="serving",
+                             endpoint=self.name):
+            self._publish(seq, prompt, plen, bool(replay), plan)
+            self._seat(req, seq, first, replay, now,
+                       len(plan["hit_pages"]))
+        if tracer.enabled:
             args = {"seq": seq, "prompt_len": plen,
-                    "resumed": bool(replay), "path": path,
+                    "resumed": bool(replay), "path": plan["path"],
                     "waited_s": round(now - req.t_submit, 6)}
             if req.trace_id:
                 args["request_id"] = req.trace_id
@@ -1120,41 +1170,47 @@ class DecodeEngine:
 
     def _join_packed(self, polled: Sequence[_Request]) -> None:
         """Join one poll's requests with their prompts packed into as few
-        prefill calls as fit: each prompt's pages allocated and its rows
-        placed on the next block edge, in arrival order, until a call
-        holds ``PACK_SEGMENTS`` prompts or the next would pass the
-        ladder's top bucket; then one dispatch and one fetch a call."""
+        prefill calls as fit: a call takes the next requests in arrival
+        order, each one's pages allocated and its rows placed on the
+        next block edge, until it holds ``PACK_SEGMENTS`` prompts or the
+        next would pass the ladder's top bucket; then one dispatch and
+        one fetch a call, and its requests seated."""
         now = time.perf_counter()
+        # a swapped-out request's pages coming back is a join of its own
+        joins = [req for req in polled if self._joinable(req, now)]
         block = self.model.pack_block
         top = self._prefill_buckets[-1]
-        calls: List[List[tuple]] = [[]]
-        rows = 0
-        for req in polled:
-            if not self._joinable(req, now):
-                continue
-            prompt = req.feeds["prompt"]
-            plen = int(prompt.shape[0])
-            replay = self._resume.pop(req, None)
-            if not replay:
-                self._note_repeat(prompt, plen)
-            seq = self._next_seq
-            self._next_seq += 1
-            for kind, n in self._pool.demand(plen).items():
-                self._pool.alloc(seq, n, kind)
-            span = -(-plen // block) * block
-            if calls[-1] and (len(calls[-1]) == self._pack_segments
-                              or rows + span > top):
-                calls.append([])
+        at = 0
+        while at < len(joins):
+            self._open_phase("decode.join")
+            with _events.TRACER.mirrored("decode.join.build",
+                                         cat="serving", endpoint=self.name):
+                call: List[tuple] = []
                 rows = 0
-            calls[-1].append((req, seq, prompt, replay, rows))
-            rows += span
-        for call in calls:
-            if call:
-                self._prefill_packed(call, now)
+                while at < len(joins) and len(call) < self._pack_segments:
+                    req = joins[at]
+                    prompt = req.feeds["prompt"]
+                    plen = int(prompt.shape[0])
+                    span = -(-plen // block) * block
+                    if call and rows + span > top:
+                        break
+                    replay = self._resume.pop(req, None)
+                    if not replay:
+                        self._note_repeat(prompt, plen)
+                    seq = self._next_seq
+                    self._next_seq += 1
+                    for kind, n in self._pool.demand(plen).items():
+                        self._pool.alloc(seq, n, kind)
+                    call.append((req, seq, prompt, replay, rows))
+                    rows += span
+                    at += 1
+                operands = self._pack_operands(call)
+            self._prefill_packed(call, operands, now)
 
-    def _prefill_packed(self, call: List[tuple], now: float) -> None:
-        """One packed prefill call over ``call``'s ``(request, seq,
-        prompt, replay, first row)`` entries, then each request seated."""
+    def _pack_operands(self, call: List[tuple]) -> Tuple[np.ndarray, ...]:
+        """The packed program's host operands for ``call``'s ``(request,
+        seq, prompt, replay, first row)`` entries: the tokens in the
+        call's bucket, each prompt's start and length, its page table."""
         segs = self._pack_segments
         _req, _seq, prompt, _replay, at = call[-1]
         tb = next(b for b in self._prefill_buckets if b >= at + len(prompt))
@@ -1166,27 +1222,35 @@ class DecodeEngine:
             tokens[at:at + len(prompt)] = prompt
             start[b], length[b] = at, len(prompt)
             tables[b] = self._pool.table(seq)
-        tracing = _events.TRACER.enabled
-        t_disp = time.perf_counter() if tracing else 0.0
-        cols, fd = self._packed_prefill(
-            self.params, self._pool.columns, tokens, start, length, tables,
-        )
-        self._pool.columns = cols
-        firsts = np.asarray(fd)
-        if tracing:
-            # the program's dispatch through the first tokens' arrival on
-            # the host: the device's share of decode.join
-            _events.TRACER.emit_complete(
-                "decode.prefill", t_disp, time.perf_counter() - t_disp,
-                args={"endpoint": self.name, "path": "packed",
-                      "bucket": tb, "segments": len(call)},
-                cat="serving",
-            )
+        return tokens, start, length, tables
+
+    def _prefill_packed(self, call: List[tuple],
+                        operands: Tuple[np.ndarray, ...], now: float) -> None:
+        """One packed prefill call over ``call``'s entries, then each
+        request seated."""
+        tokens, _start, length, _tables = operands
+        tb = len(tokens)
+        tracer = _events.TRACER
+        # the program's dispatch through the first tokens' arrival on the
+        # host: the device's share of decode.join
+        with tracer.mirrored("decode.prefill", cat="serving",
+                             endpoint=self.name, path="packed", bucket=tb,
+                             segments=len(call)):
+            with tracer.mirrored("decode.prefill.enqueue", cat="serving",
+                                 endpoint=self.name):
+                cols, fd = self._packed_prefill(
+                    self.params, self._pool.columns, *operands)
+            self._pool.columns = cols
+            with tracer.mirrored("decode.prefill.fetch", cat="serving",
+                                 endpoint=self.name):
+                firsts = np.asarray(fd)
         m.DECODE_STEPS["prefill"].inc()
         m.DECODE_PREFILL_SEGMENTS.inc(len(call))
-        for b, (req, seq, _prompt, replay, _at) in enumerate(call):
-            self._seat(req, seq, int(firsts[b]), replay, now, 0)
-        if tracing:
+        with tracer.mirrored("decode.join.seat", cat="serving",
+                             endpoint=self.name):
+            for b, (req, seq, _prompt, replay, _at) in enumerate(call):
+                self._seat(req, seq, int(firsts[b]), replay, now, 0)
+        if tracer.enabled:
             used = int(length.sum())
             args = {"joins": len(call), "tokens": used, "bucket": tb,
                     "padded": tb - used, "path": "packed",
@@ -1243,6 +1307,7 @@ class DecodeEngine:
         recompute. Returns False on ANY store or pool problem (counted
         as ``tftpu_kvswap_fallback_total``; the caller's replay path
         still resumes the request — a swap problem never loses one)."""
+        self._open_phase("decode.join")
         seq = self._next_seq
         self._next_seq += 1
         try:
@@ -1305,7 +1370,7 @@ class DecodeEngine:
         if len(s.generated) >= s.want:
             self._finish(s)
         if _events.TRACER.enabled:
-            args = {"seq": seq, "swap_resumed": True, "path": "swap",
+            args = {"seq": seq, "path": "swap",
                     "waited_s": round(s.waited, 6)}
             if req.trace_id:
                 args["request_id"] = req.trace_id
@@ -1323,6 +1388,7 @@ class DecodeEngine:
         # — the oldest is never evicted, and the pool floor (one full
         # horizon) guarantees it can always finish: forward progress is
         # structural, preemption cannot livelock.
+        self._open_phase("decode.prepare")
         allocated = preempted = 0
         for s in sorted(self._active(), key=lambda x: x.joined):
             for kind in self._kinds:
@@ -1350,9 +1416,10 @@ class DecodeEngine:
                     self._preempt(s)
                     preempted += 1
         active = self._active()
+        tracer = _events.TRACER
         if not active:
-            if _events.TRACER.enabled:
-                self._phase("decode.prepare", slots=0,
+            if tracer.enabled:
+                self._phase("decode.prepare", "decode.admit", slots=0,
                             pages_allocated=allocated,
                             preempted=preempted)
             return
@@ -1367,26 +1434,33 @@ class DecodeEngine:
             pos[row] = s.pos
             for table, kind in zip(tables, self._kinds):
                 table[row] = self._pool.table(s.seq, kind.name)
-        if _events.TRACER.enabled:
+        if tracer.enabled:
             # page faults, preemption and the host-side build above
-            self._phase("decode.prepare", slots=n,
+            self._phase("decode.prepare", "decode.step", slots=n,
                         pages_allocated=allocated, preempted=preempted)
         out = self._run_step(
             self.params, self._pool.columns, tokens, pos, *tables
         )
         self._pool.columns = out[0]
-        nxt = np.asarray(out[1])
-        m.DECODE_STEPS["decode"].inc()
-        span = self._count_walk(pos, sb)
-        if len(out) > 2:
-            span.update(self._count_experts(out[2]))
-        if _events.TRACER.enabled:
+        # the wait for the device until the step's tokens are on the host
+        with tracer.mirrored("decode.step.fetch", cat="serving",
+                             endpoint=self.name):
+            nxt = np.asarray(out[1])
+        with tracer.mirrored("decode.step.count", cat="serving",
+                             endpoint=self.name) as leaf:
+            m.DECODE_STEPS["decode"].inc()
+            span = self._count_walk(pos, sb)
+            if len(out) > 2:
+                span.update(self._count_experts(out[2]))
+            if leaf is not None:
+                leaf.args.update(span)
+        if tracer.enabled:
             args = {"slots": n, "bucket": sb, **span}
             rids = [s.req.trace_id for s in active if s.req.trace_id]
             if rids:
                 args["request_ids"] = rids[:16]
-            self._phase("decode.step", **args)
-        finished = 0
+            self._phase("decode.step", "decode.commit", **args)
+        finished = fresh = 0
         for row, s in enumerate(active):
             s.pos += 1
             tok = int(nxt[row])
@@ -1399,14 +1473,16 @@ class DecodeEngine:
                     s.replay = None
                 tok = expect
             else:
-                m.DECODE_TOKENS.inc()
+                fresh += 1
             s.generated.append(tok)
             if len(s.generated) >= s.want:
                 self._finish(s)
                 finished += 1
-        if _events.TRACER.enabled:
+        # one increment a step (the replayed tokens are not progress)
+        m.DECODE_TOKENS.inc(fresh)
+        if tracer.enabled:
             # token bookkeeping, replay checks and the finishes above
-            self._phase("decode.commit", finished=finished)
+            self._phase("decode.commit", "decode.admit", finished=finished)
 
     def _count_walk(self, pos: np.ndarray, sb: int) -> Dict[str, int]:
         """Count the page-table entries one step's attention walked, and
@@ -1517,32 +1593,27 @@ class DecodeEngine:
             self._drop_swap(s.req)
 
     def _finish(self, s: _Seq) -> None:
-        tracing = _events.TRACER.enabled
-        t_fin = time.perf_counter() if tracing else 0.0
-        self._slots[self._slot_of(s)] = None
-        m.DECODE_SLOTS.dec()
-        self._pool.free_seq(s.seq)
-        out = np.asarray(s.generated[:s.want], np.int32)[None, :]
-        done = time.perf_counter()
-        m.REQUEST_LATENCY.observe(done - s.req.t_submit)
-        s.req.future._set({"tokens": out})
-        _flight.record(
-            "serving.decode.finish", endpoint=self.name, seq=s.seq,
-            tokens=int(out.shape[1]),
-            seconds=round(done - s.req.t_submit, 6),
-        )
-        if tracing:
-            req = s.req
-            args = {"endpoint": self.name, "seq": s.seq,
-                    "tokens": int(out.shape[1])}
-            if req.trace_id:
-                args["request_id"] = req.trace_id
-            # the finish work itself: a leaf inside decode.commit (or
-            # decode.join, for a request that wanted one token)
-            _events.TRACER.emit_complete(
-                "decode.finish", t_fin, time.perf_counter() - t_fin,
-                args=args, cat="serving",
+        req = s.req
+        # the finish work itself: a leaf inside decode.commit (or
+        # decode.join.seat, for a request that wanted one token)
+        with _events.TRACER.mirrored(
+                "decode.finish", cat="serving", endpoint=self.name,
+                seq=s.seq, tokens=min(len(s.generated), s.want)) as leaf:
+            if leaf is not None and req.trace_id:
+                leaf.args["request_id"] = req.trace_id
+            self._slots[self._slot_of(s)] = None
+            m.DECODE_SLOTS.dec()
+            self._pool.free_seq(s.seq)
+            out = np.asarray(s.generated[:s.want], np.int32)[None, :]
+            done = time.perf_counter()
+            m.REQUEST_LATENCY.observe(done - req.t_submit)
+            req.future._set({"tokens": out})
+            _flight.record(
+                "serving.decode.finish", endpoint=self.name, seq=s.seq,
+                tokens=int(out.shape[1]),
+                seconds=round(done - req.t_submit, 6),
             )
+        if leaf is not None:
             # the request's whole life overlaps every other request's:
             # an async pair, off the span timeline
             t_first = req.future.t_first_token
@@ -1550,7 +1621,7 @@ class DecodeEngine:
                 "decode.request", req.trace_id, req.t_submit,
                 done - req.t_submit,
                 args=dict(
-                    args, prompt_len=int(s.prompt.shape[0]),
+                    leaf.args, prompt_len=int(s.prompt.shape[0]),
                     waited_s=round(s.waited, 6),
                     ttft_s=(None if t_first is None
                             else round(t_first - req.t_submit, 6)),

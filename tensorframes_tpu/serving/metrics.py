@@ -29,6 +29,7 @@ __all__ = [
     "REQUEST_LATENCY", "QUEUE_WAIT", "DISPATCH_SECONDS",
     "DEADLINE_EXPIRED", "DISPATCH_ERRORS", "rejected",
     "DECODE_PHASES", "DECODE_TOKENS", "DECODE_STEPS", "DECODE_TTFT",
+    "DECODE_LOOP_CPU",
     "DECODE_PREFILL_SEGMENTS",
     "DECODE_ATTN_PAGES_WALKED", "DECODE_ATTN_PAGES_GRID",
     "DECODE_SLOTS", "DECODE_FREE_PAGES", "DECODE_PREEMPTIONS",
@@ -152,6 +153,14 @@ DECODE_TOKENS = _counter(
     "Newly generated tokens across all decode endpoints (replayed "
     "tokens of a preempted sequence's resume are NOT counted — they "
     "are recompute, not progress); rate = decode tokens/sec",
+)
+DECODE_LOOP_CPU = _counter(
+    "tftpu_decode_loop_cpu_seconds_total",
+    "CPU time of the decode engines' loop threads (time.thread_time, "
+    "read once a loop iteration): over tftpu_decode_tokens_total, the "
+    "host CPU a generated token costs; against wall time, the share of "
+    "the loop spent off the CPU (waiting on the device, the GIL or the "
+    "scheduler)",
 )
 DECODE_STEPS: Dict[str, Counter] = {
     p: _counter(

@@ -10,7 +10,13 @@ pair, never an "X" span.
 * the frame pass — a sharded ``reduce_blocks(map_blocks(...))`` over
   four virtual devices emits ``executor.prepare``, ``plan.reduce.*``
   with the stated nesting;
-* tracing off — the same runs append nothing;
+* the serving loop's leaves — inside a join its build, prefill (its
+  enqueue and fetch) and seating, inside a step its enqueue, fetch and
+  count, in that order; each phase's CPU time; the loop's CPU counter;
+  a ``jax.profiler`` capture holding the phases and leaves under their
+  names;
+* tracing off — the same runs append nothing, make no profiler
+  ``TraceMe`` and read the thread's CPU clock once a turn;
 * first-token time — ``ResultFuture.t_submit <= t_first_token <=
   t_done`` and ``t_first_token`` is what ``DECODE_TTFT`` observed;
 * names — scope names in the lowered text of the decode step and of
@@ -21,6 +27,7 @@ pair, never an "X" span.
 import json
 import re
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +45,12 @@ from tensorframes_tpu.serving import DecodeConfig, DecodeEngine
 
 PHASES = ("decode.admit", "decode.join", "decode.prepare", "decode.step",
           "decode.commit")
+# what a packed join and a step hold, in the order it runs: every
+# instant of them lies under one of these or their leaves
+JOIN_PARTS = ("decode.join.build", "decode.prefill", "decode.join.seat")
+PREFILL_LEAVES = ("decode.prefill.enqueue", "decode.prefill.fetch")
+STEP_LEAVES = ("decode.step.enqueue", "decode.step.fetch",
+               "decode.step.count")
 
 
 @pytest.fixture(scope="module")
@@ -172,6 +185,200 @@ def test_engine_thread_spans_nest_and_cover_the_loop(model, engine, tracing):
     assert sum(e["args"]["finished"] for e in commits) == 11
     assert sum(e["args"]["polled"] for e in spans
                if e["name"] == "decode.admit") == 12
+
+
+def _inside(child, parent, slack_us=0.5):
+    return (parent["ts"] - slack_us <= child["ts"]
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + slack_us)
+
+
+def _children(parent, spans, names):
+    return [e for e in spans if e["name"] in names and e is not parent
+            and _inside(e, parent)]
+
+
+def test_every_host_instant_of_a_turn_lies_under_a_leaf(model, engine,
+                                                        tracing):
+    _saturate(engine, model[0].vocab_size, seed=3)
+    events.disable()
+    spans = _engine_spans(_events(), engine)
+    _assert_stack_discipline(spans)
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    for name in JOIN_PARTS + STEP_LEAVES + PREFILL_LEAVES:
+        assert by.get(name), name
+
+    def ordered(parent, names):
+        kids = sorted(_children(parent, spans, names), key=lambda e: e["ts"])
+        # in the stated order, one after the other, none overlapping
+        for a, b in zip(kids, kids[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 0.5, (a["name"], b["name"])
+        return [e["name"] for e in kids]
+
+    for join in by["decode.join"]:
+        assert ordered(join, JOIN_PARTS) == list(JOIN_PARTS)
+        (prefill,) = _children(join, spans, {"decode.prefill"})
+        assert ordered(prefill, PREFILL_LEAVES) == list(PREFILL_LEAVES)
+        # the finish of a one-token request sits in its seating
+        (seat,) = _children(join, spans, {"decode.join.seat"})
+        for fin in _children(join, spans, {"decode.finish"}):
+            assert _inside(fin, seat)
+    for step in by["decode.step"]:
+        assert ordered(step, STEP_LEAVES) == list(STEP_LEAVES)
+    for leaf in JOIN_PARTS:
+        assert all(any(_inside(e, j) for j in by["decode.join"])
+                   for e in by[leaf]), leaf
+    for leaf in STEP_LEAVES:
+        assert all(any(_inside(e, s) for s in by["decode.step"])
+                   for e in by[leaf]), leaf
+    assert len(by["decode.prefill.enqueue"]) == len(by["decode.prefill"])
+    assert len(by["decode.prefill.fetch"]) == len(by["decode.prefill"])
+    assert len(by["decode.step.count"]) == len(by["decode.step"])
+    # the counts the step carries ride its count leaf too
+    for step in by["decode.step"]:
+        (count,) = _children(step, spans, {"decode.step.count"})
+        assert count["args"]["pages_walked"] == step["args"]["pages_walked"]
+        assert count["args"]["pages_grid"] == step["args"]["pages_grid"]
+
+    # what a join or a step does outside its leaves is the few
+    # microseconds between their boundaries
+    for name, leaves in (("decode.join", JOIN_PARTS),
+                         ("decode.step", STEP_LEAVES)):
+        rest = sorted(
+            e["dur"] - sum(c["dur"] for c in _children(e, spans, leaves))
+            for e in by[name])
+        assert rest[len(rest) // 2] <= 200.0, (name, rest)
+
+    # every phase says how much of it the thread spent on the CPU
+    for name in PHASES:
+        for e in by[name]:
+            assert 0.0 <= e["args"]["cpu_ms"] <= e["dur"] * 1e-3 + 0.5, e
+
+
+def test_the_loop_counts_its_cpu_time(model, engine):
+    def cpu_total():
+        return sum(d["value"] for d in REGISTRY.snapshot()
+                   if d["name"] == "tftpu_decode_loop_cpu_seconds_total")
+
+    t0 = time.perf_counter()
+    before = cpu_total()
+    _saturate(engine, model[0].vocab_size, seed=4)
+    after = cpu_total()
+    wall = time.perf_counter() - t0
+    # the first increment of the run may hold the CPU of the idle loop's
+    # last nap before it: a few microseconds
+    assert 0.0 < after - before <= wall + 0.005
+
+
+def _decode_steps():
+    return sum(d["value"] for d in REGISTRY.snapshot()
+               if d["name"] == "tftpu_decode_steps_total"
+               and dict(d["labels"]).get("phase") == "decode")
+
+
+class _CountingTraceMe:
+    """Stands in for the profiler's TraceMe as if a capture ran."""
+    made = []
+    exited = 0
+
+    def __init__(self, name):
+        type(self).made.append(name)
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        type(self).exited += 1
+
+
+def test_tracer_off_makes_no_traceme_and_reads_cpu_once_a_turn(
+        model, engine, monkeypatch):
+    was = events.TRACER.enabled
+    events.disable()
+    _CountingTraceMe.made, _CountingTraceMe.exited = [], 0
+    monkeypatch.setattr(events, "_traceme_class", lambda: _CountingTraceMe)
+    tid = engine._thread.ident
+    reads, turns = [0], [0]
+    thread_time = time.thread_time
+    purge = engine._purge_resume
+
+    def counted_thread_time():
+        if threading.get_ident() == tid:
+            reads[0] += 1
+        return thread_time()
+
+    def counted_purge():        # called once at the top of every turn
+        turns[0] += 1
+        purge()
+
+    steps0 = _decode_steps()
+    monkeypatch.setattr(time, "thread_time", counted_thread_time)
+    monkeypatch.setattr(engine, "_purge_resume", counted_purge)
+    try:
+        _saturate(engine, model[0].vocab_size, n=6, seed=5)
+        got_reads, got_turns = reads[0], turns[0]
+        assert _CountingTraceMe.made == []
+        assert got_turns >= _decode_steps() - steps0 > 0
+        assert abs(got_reads - got_turns) <= 1, (got_reads, got_turns)
+        # the same engine with the tracer on mirrors every span it writes
+        events.clear()
+        events.enable()
+        _saturate(engine, model[0].vocab_size, n=6, seed=6)
+        events.disable()
+        made = set(_CountingTraceMe.made)
+        assert set(PHASES) | set(JOIN_PARTS) | set(STEP_LEAVES) \
+            | set(PREFILL_LEAVES) <= made
+        # what opened is closed, but for the turn in hand
+        time.sleep(0.1)
+        assert 0 <= len(_CountingTraceMe.made) - _CountingTraceMe.exited <= 1
+    finally:
+        events.clear()
+        if was:
+            events.enable()
+
+
+def test_a_profiler_capture_holds_the_engine_phases(model, engine, tmp_path):
+    from jax.profiler import ProfileData
+
+    names = ("decode.admit", "decode.join", "decode.prefill", "decode.step",
+             "decode.step.enqueue", "decode.step.fetch", "decode.step.count",
+             "decode.commit")
+    was = events.TRACER.enabled
+    events.disable()
+    events.clear()
+    try:
+        # the tracer runs wholly inside the capture: its every span lies
+        # inside it, and the edge is where tracing goes off
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # as the benchmark captures
+        options.host_tracer_level = 1
+        with jax.profiler.trace(str(tmp_path), profiler_options=options):
+            events.enable()
+            _saturate(engine, model[0].vocab_size, seed=7)
+            events.disable()
+        traced = _engine_spans(_events(), engine)
+    finally:
+        events.clear()
+        if was:
+            events.enable()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    native = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    native[e.name] = native.get(e.name, 0) + 1
+    for name in names:
+        want = sum(e["name"] == name for e in traced)
+        assert want > 0, name
+        assert abs(native.get(name, 0) - want) <= 1, (name, native.get(name),
+                                                      want)
 
 
 def test_decode_request_is_an_async_pair_and_survives_merge(
