@@ -615,6 +615,41 @@ def child_main(args) -> int:
         return {"pairs": t * k, "tiling": "x".join(map(str, em.tiling(d, f))),
                 "gmm_err": float(f"{err:.3g}"), "scale": round(scale, 3)}
 
+    # -- the packed prefill's attention: kernel against the XLA band fold ----
+    @leg("packed_attention")
+    def _():
+        from tensorframes_tpu.kernels import packed_attention as kpa
+        from tensorframes_tpu.ops import attention as att
+
+        # GPT-2's heads (two a lane tile) over the 1,024-row bucket on the
+        # chip, tiny ones here: three prompts, then padding blocks
+        nh, hd, T, dt = (4, 8, 256, jnp.float32) if rehearsal \
+            else (12, 64, 1024, jnp.bfloat16)
+        blk = 64
+        rng = np.random.default_rng(6)
+        q = jnp.asarray(rng.standard_normal((1, nh, T, hd)), dt)
+        kq, vq = (jnp.asarray(rng.integers(-127, 128, (1, nh, T, hd)),
+                              jnp.int8) for _ in range(2))
+        ks, vs = (jnp.asarray(rng.uniform(.002, .02, (1, nh, T)),
+                              jnp.float32) for _ in range(2))
+        first = np.arange(T // blk, dtype=np.int32)
+        first[1:3], first[3:T // blk - 1] = 1, 3
+        first = jnp.asarray(first)
+        want = np.asarray(att._band_attention(
+            q, kq, vq, blk, None, ks, vs, first), np.float32)
+        if "packed_attn" in selectable:
+            got = np.asarray(kpa.packed_attention(
+                q, kq, vq, ks, vs, first, blk), np.float32)
+            kernels.note_dispatch("packed_attn", kernels.interpret_mode())
+        else:
+            got = want
+        err = float(np.abs(got - want).max())
+        scale = float(np.abs(want).max())
+        # bfloat16 outputs: the two folds round the same sums apart
+        assert np.isfinite(got).all() and err <= 1e-2 * scale, (err, scale)
+        return {"rows": T, "heads": nh, "attn_err": float(f"{err:.3g}"),
+                "scale": round(scale, 3)}
+
     # -- no hidden fallback: read the registry -------------------------------
     @leg("no_hidden_fallback")
     def _():

@@ -13,6 +13,10 @@
   rows sorted by expert against each held expert's matrix, a row tile
   a grid step (``megablox.gmm`` with tiles for the expert widths), in
   ``lax.ragged_dot``'s place.
+* :mod:`.packed_attention` — the packed prefill's causal attention over
+  int8 KV: one call a layer, a grid step a 64-row query block folding
+  its own prompt's key blocks, in place of ``_band_attention``'s XLA
+  loop of one block pair an iteration.
 
 **One rule decides whether a kernel runs: what the process can observe
 about its backend, and the call's own operands.** :func:`selectable`
@@ -23,8 +27,9 @@ backend is a TPU (Mosaic compiles every registered kernel; one it
 refuses raises at the call site, nothing falls back) or the test hook
 ``TFTPU_PALLAS_FORCE=1`` puts them on the CPU pallas interpreter. The
 second half is the kernel's own ``eligible`` / shape check. The call
-sites ask here — ``ops.attention.paged_decode_attention`` and
-``models/moe.routed_experts`` at trace time,
+sites ask here — ``ops.attention.paged_decode_attention``,
+``ops.attention.blockwise_attention`` and ``models/moe.routed_experts``
+at trace time,
 ``plan/rules.decide_segment_reduce`` per reduction — and
 ``chip_smoke.py`` checks the dispatch counters against the same table.
 Nothing is timed to choose a kernel and nothing about the choice is
@@ -38,7 +43,10 @@ the XLA/host reference: exactly where that is structural (min/max,
 integer sums), to float tolerance for the decode attention's online
 softmax. The expert matmul is a library kernel (``megablox.gmm``) with
 this package's tiles: it is gated against ``lax.ragged_dot`` to float32
-rounding, and row for row against itself (batched equals solo).
+rounding, and row for row against itself (batched equals solo). The
+packed attention is gated against ``ops/attention._band_attention``, its
+XLA lowering, to float rounding, and bitwise against itself (a prompt
+alone equals the prompt among neighbours).
 """
 
 from __future__ import annotations
@@ -64,7 +72,7 @@ __all__ = [
 #: The registered kernel names — one counted dispatch series each.
 #: Every one compiles under Mosaic (v5e, jax 0.9.0 / libtpu 0.0.34);
 #: ``chip_smoke.py`` requires a non-zero dispatch count for each.
-KERNELS = ("segment_reduce", "decode_attn", "expert_matmul")
+KERNELS = ("segment_reduce", "decode_attn", "expert_matmul", "packed_attn")
 
 # Pre-registered at import (the `# kernels |` bench summary and the
 # exposition must always carry the family — a process that never

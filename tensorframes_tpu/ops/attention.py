@@ -23,6 +23,9 @@ Three implementations, one contract (``[batch, heads, seq, head_dim]``):
 Serving decode adds a fourth: :func:`paged_decode_attention` — the
 fused paged int8-KV kernel (``kernels/decode_attention.py``) where the
 backend runs it (``kernels.selectable``), the XLA gather chain elsewhere.
+Likewise :func:`blockwise_attention`'s block-bounded fold of packed
+prompts (the packed prefill's) runs ``kernels/packed_attention.py``
+where the backend runs it, :func:`_band_attention` elsewhere.
 """
 
 from __future__ import annotations
@@ -93,7 +96,12 @@ def blockwise_attention(
     dequantized copy is made) and ``first_block`` ``[seq // block_size]``
     int32, each query block's lowest key block (several sequences packed
     along ``seq``, each starting at a multiple of ``block_size``: a
-    block attends its own sequence only): :func:`_band_attention`."""
+    block attends its own sequence only): :func:`_band_attention`.
+
+    Block bounds over quantized KV with its scales and no window run the
+    packed-attention kernel (``kernels/packed_attention.py``) where
+    ``kernels.selectable("packed_attn")`` holds and the heads fit it,
+    asked where the call is traced; :func:`_band_attention` elsewhere."""
     if window is not None or k_scale is not None or v_scale is not None \
             or first_block is not None or q.shape[1] != k.shape[1]:
         if not causal or q.shape[2] != k.shape[2]:
@@ -101,6 +109,16 @@ def blockwise_attention(
                 "a window, grouped heads, KV scales or block bounds need "
                 "causal=True and queries and keys over the same positions"
             )
+        if first_block is not None and window is None \
+                and k_scale is not None and v_scale is not None \
+                and q.shape == k.shape:
+            from .. import kernels as _kernels
+            from ..kernels import packed_attention as _kpa
+
+            if _kernels.selectable("packed_attn") \
+                    and _kpa.fits(q.shape[1], q.shape[3]):
+                return _kpa.packed_attention(q, k, v, k_scale, v_scale,
+                                             first_block, block_size)
         return _band_attention(q, k, v, block_size, window, k_scale, v_scale,
                                first_block)
     b, h, sq, d = q.shape

@@ -1144,6 +1144,26 @@ def test_kvswap_prefix_metrics_preregistered():
 PACK_PAGE = 8
 
 
+@pytest.fixture(params=["band", "kernel"])
+def lowering(request):
+    """The packed prefill's attention as each lowering gives it: the XLA
+    band fold (this CPU's own) and the packed-attention kernel on the
+    pallas interpreter. The program picks one where it is traced."""
+    from tensorframes_tpu import kernels
+    from tensorframes_tpu.config import get_config
+
+    cfg = get_config()
+    was = cfg.pallas_force, cfg.pallas_kernels
+    tfs.configure(pallas_force=request.param == "kernel",
+                  pallas_kernels=True)
+    try:
+        assert kernels.selectable("packed_attn") is (
+            request.param == "kernel")
+        yield request.param
+    finally:
+        tfs.configure(pallas_force=was[0], pallas_kernels=was[1])
+
+
 def _packed(model, T, placed, segs=4):
     """Run the packed prefill on a fresh pool: ``placed`` is a list of
     ``(prompt, first row, pages)``. Returns (pool, first tokens)."""
@@ -1167,7 +1187,8 @@ def _packed(model, T, placed, segs=4):
 
 
 @pytest.mark.parametrize("T,at", [(256, 64), (192, 128), (64, 0)])
-def test_packed_prefill_is_the_same_wherever_a_prompt_sits(model, T, at):
+def test_packed_prefill_is_the_same_wherever_a_prompt_sits(model, T, at,
+                                                           lowering):
     """A prompt packed alone at row 0 and the same prompt among
     neighbours at another block edge, in the same bucket or another, give
     bit for bit the same first token and the same bytes on its pages."""
@@ -1187,7 +1208,8 @@ def test_packed_prefill_is_the_same_wherever_a_prompt_sits(model, T, at):
         assert np.array_equal(pool[name][mine], alone_pool[name][mine]), name
 
 
-def test_packed_first_tokens_match_the_one_sequence_prefill(model):
+def test_packed_first_tokens_match_the_one_sequence_prefill(model,
+                                                            lowering):
     """The packed program's first tokens are the one-sequence prefill's,
     and the KV it writes on the prompts' positions agrees to the int8
     rounding (codes within one step, scales to float tolerance)."""

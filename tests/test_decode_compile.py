@@ -232,13 +232,17 @@ def test_the_seam_leaves_the_transformer_s_programs_as_they_were(
 @pytest.mark.parametrize("bucket", [1024, 4096])
 def test_packed_prefill_writes_the_pool_in_place(cell, mosaic, bucket):
     """The packed prefill at the ends of the 384-slot cell's ladder, 16
-    segments, over the 8,193 pages: no pool-sized copy, every column
-    aliased, temporaries far under one pool."""
+    segments, over the 8,193 pages: its attention is the packed kernel,
+    one Mosaic call a layer; no pool-sized copy, every column aliased,
+    temporaries far under one pool."""
     cfg, params, pool, i32 = _big_cell(cell)
     served = cfg.served_model(PAGE, MAX_PAGES * PAGE)
     compiled = jax.jit(served.packed_prefill, donate_argnums=(1,)).lower(
         params, pool, i32(bucket), i32(16), i32(16), i32(16, MAX_PAGES)
     ).compile()
+    assert len(re.findall(
+        r"%packed_attention[.\d]* = [^\n]*tpu_custom_call",
+        compiled.as_text())) == cfg.num_layers
     _assert_in_place(compiled, pool, f"jit_packed_prefill[{bucket}]",
                      BIG_PAGES)
 

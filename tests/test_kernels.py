@@ -9,7 +9,9 @@ same-tiling plain-jnp emulation is bit-identical by construction, and
 the order-free op classes (min/max, integer sums) are bit-identical to
 the XLA scatter. The decode-attention kernel folds pages through an
 online softmax, so it matches the whole-horizon XLA chain to float
-tolerance and its own per-page emulation bitwise.
+tolerance and its own per-page emulation bitwise. The packed prefill's
+attention kernel matches the XLA band fold to float tolerance and gives
+a prompt the same bits wherever it sits in the pack.
 """
 
 import numpy as np
@@ -573,6 +575,108 @@ def test_decode_engine_mosaic_failure_surfaces(forced):
 
 
 # ---------------------------------------------------------------------------
+# packed prefill attention
+# ---------------------------------------------------------------------------
+
+PACK = 64
+
+
+def _packed_case(rng, nh, hd, T, segments, dtype=jnp.float32):
+    """q, int8 k/v with their scales over ``T`` packed rows, and
+    ``first_block``: ``segments`` lists each prompt's blocks from row 0
+    on; the blocks after the last prompt are padding (each its own
+    first)."""
+    q = jnp.asarray(rng.standard_normal((1, nh, T, hd)), dtype)
+    kq, vq = (jnp.asarray(rng.integers(-127, 128, (1, nh, T, hd)),
+                          jnp.int8) for _ in range(2))
+    ks, vs = (jnp.asarray(rng.uniform(.002, .02, (1, nh, T)), jnp.float32)
+              for _ in range(2))
+    first = np.arange(T // PACK, dtype=np.int32)
+    at = 0
+    for n in segments:
+        first[at:at + n] = at
+        at += n
+    assert at <= T // PACK
+    return q, kq, vq, ks, vs, jnp.asarray(first)
+
+
+@pytest.mark.parametrize("nh,hd,T,segments,dtype", [
+    (4, 8, 64, [1], jnp.float32),                  # one 64-row prompt
+    (4, 8, 512, [8], jnp.float32),                 # a prompt of 8 blocks
+    (4, 8, 512, [3, 2], jnp.float32),              # 3 padding blocks
+    (4, 8, 1024, [1] * 16, jnp.float32),           # 16 segments
+    (4, 8, 1024, [5, 3, 1, 6], jnp.float32),       # the 1,024 bucket
+    (4, 8, 4096, [20, 30, 6, 1], jnp.float32),     # the 4,096 bucket
+    (12, 64, 256, [3, 1], jnp.bfloat16),           # GPT-2: two heads a tile
+    (2, 128, 192, [2], jnp.bfloat16),              # one head a tile
+])
+def test_packed_attention_matches_the_band_fold(nh, hd, T, segments, dtype):
+    """The kernel (interpreted) against ``_band_attention`` with the same
+    ``first_block``, its XLA lowering and oracle: the same math, so the
+    two agree to float rounding in float32 and to an ulp of the output
+    in bfloat16."""
+    from tensorframes_tpu.kernels import packed_attention as kpa
+    from tensorframes_tpu.ops.attention import _band_attention
+
+    rng = np.random.default_rng(T + len(segments))
+    q, kq, vq, ks, vs, first = _packed_case(rng, nh, hd, T, segments,
+                                            dtype)
+    got = np.asarray(kpa.packed_attention(q, kq, vq, ks, vs, first, PACK,
+                                          interpret=True), np.float32)
+    ref = np.asarray(_band_attention(q, kq, vq, PACK, None, ks, vs, first),
+                     np.float32)
+    assert got.shape == ref.shape == q.shape
+    tol = 2e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(got, ref, rtol=0,
+                               atol=tol * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("nh,hd,dtype", [(4, 8, jnp.float32),
+                                         (12, 64, jnp.bfloat16)])
+def test_packed_attention_gives_a_prompt_the_same_bits_anywhere(
+        nh, hd, dtype):
+    """A prompt's rows alone at row 0 and among neighbours at another
+    block edge (other prompts before and after it, padding at the end)
+    are bit for bit the same."""
+    from tensorframes_tpu.kernels import packed_attention as kpa
+
+    rng = np.random.default_rng(41)
+    q, kq, vq, ks, vs, first = _packed_case(rng, nh, hd, 512, [2, 3, 1],
+                                            dtype)
+    mine = slice(2 * PACK, 5 * PACK)                # the second prompt
+    args = [x[..., mine, :] if x.ndim == 4 else x[..., mine]
+            for x in (q, kq, vq, ks, vs)]
+    alone = kpa.packed_attention(*args, jnp.zeros(3, jnp.int32), PACK,
+                                 interpret=True)
+    among = kpa.packed_attention(q, kq, vq, ks, vs, first, PACK,
+                                 interpret=True)
+    _assert_eq(np.asarray(among[:, :, mine]), np.asarray(alone),
+               "alone vs among neighbours")
+
+
+def test_blockwise_attention_takes_the_kernel_for_block_bounds(forced):
+    """``blockwise_attention`` with block bounds over int8 KV traces the
+    kernel where ``packed_attn`` is selectable and the band fold where
+    it is not; a windowed call never takes it."""
+    from tensorframes_tpu.ops.attention import blockwise_attention
+
+    q, kq, vq, ks, vs, first = _packed_case(
+        np.random.default_rng(0), 4, 8, 256, [2, 1])
+
+    def traced(**kw):
+        return "pallas_call" in str(jax.make_jaxpr(
+            lambda *a: blockwise_attention(*a, causal=True, block_size=PACK,
+                                           k_scale=ks, v_scale=vs, **kw)
+        )(q, kq, vq))
+
+    assert kernels.selectable("packed_attn")
+    assert traced(first_block=first)
+    assert not traced(window=128)
+    configure(pallas_force=False)
+    assert not traced(first_block=first)
+
+
+# ---------------------------------------------------------------------------
 # selection, registry, and switches
 # ---------------------------------------------------------------------------
 
@@ -666,7 +770,7 @@ def test_selectable_is_the_one_table(forced):
     """``kernels.selectable`` is what every call site reads: all
     kernels under the force hook, none on a CPU without it."""
     assert set(kernels.KERNELS) == {"segment_reduce", "decode_attn",
-                                    "expert_matmul"}
+                                    "expert_matmul", "packed_attn"}
     assert all(kernels.selectable(k) for k in kernels.KERNELS)
     configure(pallas_force=False)
     assert not any(kernels.selectable(k) for k in kernels.KERNELS)
@@ -723,4 +827,4 @@ def test_kernels_metrics_preregistered():
         if m.name == "tftpu_kernels_dispatch_total"
     }
     assert labels == set(kernels.KERNELS) == {
-        "segment_reduce", "decode_attn", "expert_matmul"}
+        "segment_reduce", "decode_attn", "expert_matmul", "packed_attn"}
